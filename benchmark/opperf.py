@@ -72,8 +72,8 @@ def resnet_cases(batch=64):
     — the dtype the headline bench actually computes in (2x fewer HBM
     bytes and the native MXU path; f32 numbers here would be evidence
     about the wrong configuration).  Per-op TPU latency evidence between
-    macro-bench rounds (VERDICT r4 item 8; reference benchmark/opperf/
-    runs the same op/shape matrix)."""
+    macro-bench rounds (asked for in the round-4 review; reference
+    benchmark/opperf/ runs the same op/shape matrix)."""
     import ml_dtypes
 
     r = np.random.RandomState(0)

@@ -1,0 +1,576 @@
+#!/usr/bin/env python
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, one chip, the entry points a user calls: ``vision.resnet50_v1``
+-> ``make_train_step`` -> ``aot_compile`` -> steps, then ``ServeEngine`` +
+``ContinuousBatcher`` under open-loop traffic.  Weights and data come from
+``--seed``.  No number printed here is a result: times are information for
+whoever looks next, the checks are what the run is for.
+
+    python chip_smoke.py              # one chip: device, train, kernels, serve
+    python chip_smoke.py --multichip  # four chips: dp=4 ZeRO-1 step vs one chip
+
+Contract with the driver: the last line of stdout is
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}`` and
+the exit code 0 when every phase passed.  Anything else — no accelerator, a
+refused compile, a failed check — exits non-zero and prints no such line.
+There is no CPU continuation, no retry and no watchdog.
+
+Each phase is a plain function of its sizes, so ``tests/test_chip_smoke.py``
+runs the same code at tiny sizes on the CPU; ``main()`` fixes the real sizes
+and is the only place that looks at the device.
+"""
+import argparse
+import gc
+import importlib.metadata
+import json
+import statistics
+import sys
+import time
+
+T0 = time.time()
+
+#: what jax reports for every XLA program it builds or loads from its cache
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+#: bf16 tolerance of the ``kernels`` phase: relative L2 error per output.
+#: bf16 keeps 8 significant bits, so two independently rounded results of
+#: the same f32 arithmetic differ by about 1.6e-3 in this norm; a dropped
+#: or misrouted term shows as 1e-1 or more.  L2 and not max-norm: at 2e8
+#: elements a handful of ReLU masks flip on f32 rounding of a pre-activation
+#: next to zero, which moves single elements by a whole cotangent.
+KERNEL_REL_L2 = 1e-2
+
+#: first-step loss of the dp=4 step against the one-chip step.  The step is
+#: one GSPMD program over the GLOBAL batch, so BatchNorm reduces its
+#: statistics across the four shards (an all-reduce) and the two steps
+#: compute the same function — in float32 they agree to 1e-4 at any size.
+#: Under bf16 compute what differs is rounding: the loss itself is a bf16
+#: number (one ulp at 6.9 is 0.45 %), and a 64-image shard and a 256-image
+#: batch get different conv tilings and reduction orders.  At toy sizes
+#: (BatchNorm over 8 values) that noise reaches 7 %; at the real size a
+#: few ulps:
+MULTICHIP_LOSS_RTOL = 3e-2
+
+
+class SmokeFailure(AssertionError):
+    """A check of a phase did not hold."""
+
+
+def log(msg):
+    print("[smoke %6.1fs] %s" % (time.time() - T0, msg), flush=True)
+
+
+def check(ok, what):
+    if not ok:
+        raise SmokeFailure(what)
+
+
+class _CompileCounter:
+    """Counts the XLA programs built (or loaded from the persistent cache)
+    while it is armed — jax's own monitoring event, so a silent retrace
+    behind an AOT executable is seen too."""
+
+    def __init__(self):
+        import jax
+
+        self.count = 0
+        self.armed = False
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+
+    def _on_event(self, name, _secs, **_kw):
+        if self.armed and name == _COMPILE_EVENT:
+            self.count += 1
+
+
+_COUNTER = None
+
+
+def _compile_counter():
+    global _COUNTER
+    if _COUNTER is None:
+        _COUNTER = _CompileCounter()
+    return _COUNTER
+
+
+# ---------------------------------------------------------------------------
+# phase 1: device
+# ---------------------------------------------------------------------------
+
+def device(platform, count):
+    """``jax.devices()`` must be at least ``count`` devices of ``platform``;
+    anything else ends the run.  Returns the contract's device record."""
+    import jax
+    import jaxlib
+
+    devs = jax.devices()
+    check(devs[0].platform == platform,
+          "jax found platform %r, this run needs %r: %r"
+          % (devs[0].platform, platform, devs))
+    check(len(devs) >= count,
+          "this run needs %d %s device(s), jax found %d: %r"
+          % (count, platform, len(devs), devs))
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = "not installed"
+    from incubator_mxnet_tpu import _backend
+
+    cache = _backend.use_compile_cache()
+    log("device: %s x%d (%s)  jax %s  jaxlib %s  libtpu %s  python %s"
+        % (devs[0].device_kind, len(devs), platform, jax.__version__,
+           jaxlib.__version__, libtpu, sys.version.split()[0]))
+    log("device: compile cache at %s" % cache)
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+# ---------------------------------------------------------------------------
+# phase 2: train
+# ---------------------------------------------------------------------------
+
+def _batch(batch, image_size, classes, seed):
+    import numpy as np
+
+    from incubator_mxnet_tpu import nd
+
+    rng = np.random.RandomState(seed)
+    x = rng.uniform(size=(batch, 3, image_size, image_size))
+    y = rng.randint(0, classes, batch)
+    return nd.array(x.astype(np.float32)), nd.array(y.astype(np.float32))
+
+
+def _state_arrays(step):
+    import jax
+
+    params = [p.data()._data for p in
+              step.net.collect_params().values()]
+    return params, jax.tree.leaves(step.opt_state)
+
+
+def _check_placed(arrays, platform, what):
+    """Every array lives on ``platform`` devices and nowhere else — the
+    check a silent ``ctx=mx.tpu()``-on-a-cpu-host run cannot get through."""
+    off = [a for a in arrays
+           if {d.platform for d in a.devices()} != {platform}]
+    check(not off, "%s: %d of %d arrays are not on a %s device (first: %r)"
+          % (what, len(off), len(arrays), platform,
+             off[0].devices() if off else None))
+
+
+def _run_steps(step, x, y, n):
+    """n steps, each timed to the loss being ready on the host."""
+    losses, ms = [], []
+    for _ in range(n):
+        t = time.time()
+        loss = step(x, y)
+        loss.wait_to_read()
+        ms.append(1e3 * (time.time() - t))
+        losses.append(float(loss.asscalar()))
+    return losses, ms
+
+
+def _site_plan(site):
+    """(plan as ``fused_bn.plan_describe`` gives it, one-line name)."""
+    import jax.numpy as jnp
+
+    from incubator_mxnet_tpu.parallel import fused_bn
+
+    shape, dtype, group, has_res, donate, dual = site
+    d = fused_bn.plan_describe(*shape, jnp.dtype(dtype).itemsize, group,
+                               has_res, dual)
+    return d, "%s res=%d donate=%d dual=%d fwd=%s bwd=%s" % (
+        "x".join(map(str, shape)), has_res, donate, dual, d["variant"],
+        d["bwd"])
+
+
+def _on_pallas(plan):
+    return plan["variant"] != "jnp" or plan["bwd"] != "jnp"
+
+
+def _check_losses(losses, what):
+    import math
+
+    check(all(math.isfinite(v) for v in losses),
+          "%s: a loss is not finite: %r" % (what, losses))
+    check(min(losses[-3:]) < losses[0],
+          "%s: the loss did not fall: first %r, last three %r"
+          % (what, losses[0], losses[-3:]))
+
+
+def train(batch, image_size, steps, platform, ghost_bn=None, passes=None,
+          classes=1000, seed=0, **step_kwargs):
+    """The train step ``bench.py`` runs with no flags (``ghost_bn`` /
+    ``passes`` None = its defaults), AOT-compiled, 1 warm-up + ``steps``
+    steps on one fixed batch.  Returns what it measured and the BN sites
+    the trace went through.  ``step_kwargs`` reach
+    ``bench.build_train_step`` (a toy-sized run needs a smaller
+    ``learning_rate`` than the recipe's to see its loss fall)."""
+    import jax
+
+    import bench
+    from incubator_mxnet_tpu.parallel import fused_bn
+
+    ghost_bn = bench.DEFAULT_GHOST_BN if ghost_bn is None else ghost_bn
+    passes = bench.DEFAULT_PASSES if passes is None else passes
+    log("train: resnet50_v1 classes=%d batch=%d %dpx bf16, ghost_bn=%d "
+        "passes=%r" % (classes, batch, image_size, ghost_bn, passes))
+    _, step = bench.build_train_step(image_size=image_size, classes=classes,
+                                     ghost_bn=ghost_bn, passes=passes,
+                                     seed=seed, **step_kwargs)
+    x, y = _batch(batch, image_size, classes, seed)
+    with fused_bn.record_sites() as sites:
+        times = step.aot_compile(x, y)
+    log("train: trace %.1fs, compile %.1fs" % (times["trace"],
+                                               times["compile"]))
+
+    n_pallas = 0
+    for site in sites:
+        plan, name = _site_plan(site)
+        n_pallas += _on_pallas(plan)
+        log("train: BN site %s" % name)
+    n_calls = step.compiled.as_text().count("tpu_custom_call")
+    mem = step.compiled.memory_analysis()
+    log("train: %d BN site(s), %d planned on Pallas, %d tpu_custom_call in "
+        "the compiled step; the compiler counts %.2f GB of arguments + "
+        "%.2f GB of temporaries"
+        % (len(sites), n_pallas, n_calls, mem.argument_size_in_bytes / 1e9,
+           mem.temp_size_in_bytes / 1e9))
+    if platform == "tpu":
+        # (on the cpu the same kernels run through the interpreter and
+        # leave no custom call behind)
+        check(n_calls > 0 or n_pallas == 0,
+              "train: %d site(s) planned on Pallas but the compiled step "
+              "has no tpu_custom_call" % n_pallas)
+
+    counter = _compile_counter()
+    first, warm_ms = _run_steps(step, x, y, 1)
+    counter.count, counter.armed = 0, True
+    try:
+        losses, ms = _run_steps(step, x, y, steps)
+    finally:
+        counter.armed = False
+    losses = first + losses
+    log("train: warm-up step %.1f ms; losses %s"
+        % (warm_ms[0], " ".join("%.4f" % v for v in losses)))
+
+    params, state = _state_arrays(step)
+    _check_placed(params + state, platform,
+                  "train: parameters and optimizer state")
+    _check_losses(losses, "train")
+    check(counter.count == 0,
+          "train: %d XLA program(s) built after the warm-up" % counter.count)
+    stats = jax.devices()[0].memory_stats() or {}
+    peak = stats.get("peak_bytes_in_use")
+    med = statistics.median(ms)
+    # (the allocator's peak leaves out a compiled program's temporaries:
+    # how near a step is to HBM is the compiler's count above)
+    log("train: median step %.2f ms (%.1f img/s) over %d steps on %s; "
+        "%d params + %d state arrays all on %s; allocator "
+        "peak_bytes_in_use %s"
+        % (med, 1e3 * batch / med, steps, jax.devices()[0].device_kind,
+           len(params), len(state), platform,
+           "not reported" if peak is None else "%.2f GB" % (peak / 1e9)))
+    return {"sites": sites, "losses": losses, "step_ms": med,
+            "trace_s": times["trace"], "compile_s": times["compile"],
+            "custom_calls": n_calls, "peak_bytes": peak}
+
+
+# ---------------------------------------------------------------------------
+# phase 3: kernels
+# ---------------------------------------------------------------------------
+
+def _rel_l2(a, b):
+    import jax.numpy as jnp
+
+    a = a.astype(jnp.float32).ravel()
+    b = b.astype(jnp.float32).ravel()
+    return jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b), 1e-30)
+
+
+def kernels(sites, seed=0, eps=1e-5):
+    """Every BN site whose plan says Pallas, in either direction: the
+    kernel's outputs and gradients against the jnp formulation
+    (``fused_bn._gbn_ref``) on the same inputs, within KERNEL_REL_L2.
+    Sites planned wholly on jnp are not kernels and are passed over;
+    with no site at all (the stock-BatchNorm default) the phase has
+    nothing to do, by construction.  ``sites`` is what ``train`` returns:
+    ``kernels(train(..., ghost_bn=16)["sites"])`` checks the ghost-BN
+    kernels from a scratch driver.
+    Per site, one program makes the inputs from the seed on the device and
+    a second runs both formulations on them and reduces to seven error
+    norms.  Two programs, because both paths must read the SAME rounded
+    bf16 values: inside one program XLA may keep the f32 draw for the jnp
+    path (excess precision) while the kernel's operand is rounded."""
+    import jax
+    import jax.numpy as jnp
+
+    from incubator_mxnet_tpu.parallel import fused_bn
+
+    bad, n_checked = [], 0
+    for i, site in enumerate(sites):
+        shape, dtype, group, has_res, donate, dual = site
+        d, name = _site_plan(site)
+        if not _on_pallas(d):
+            log("kernels: %s — jnp by plan, no kernel to check" % name)
+            continue
+
+        def make_inputs(key):
+            ks = jax.random.split(key, 6)
+
+            def draw(k):
+                return jax.random.normal(k, shape, jnp.float32).astype(dtype)
+
+            gamma = jax.random.uniform(ks[2], shape[1:2], jnp.float32,
+                                       0.5, 1.5)
+            beta = 0.2 * jax.random.normal(ks[3], shape[1:2], jnp.float32)
+            # w1, w2: fixed cotangents, one per output position of a
+            # dual exit
+            return (draw(ks[0]), gamma, beta, draw(ks[1]) if has_res else
+                    None, draw(ks[4]), draw(ks[5]) if dual else None)
+
+        def site_errors(x, gamma, beta, res, w1, w2):
+            def weighted(y, w):
+                return (y.astype(jnp.float32) * w.astype(jnp.float32)).sum()
+
+            def via_kernel(x, gamma, beta, res):
+                out = fused_bn.ghost_bn_act(x, gamma, beta, res, eps, "relu",
+                                            group, donate_residual=donate,
+                                            dual_out=dual)
+                loss = weighted(out[0], w1)
+                if dual:
+                    loss = loss + weighted(out[1], w2)
+                return loss, (out[0], out[-2], out[-1])
+
+            def via_jnp(x, gamma, beta, res):
+                y, m, v = fused_bn._gbn_ref(x, gamma, beta, res, eps, "relu",
+                                            d["group"])
+                loss = weighted(y, w1 if w2 is None else
+                                w1.astype(jnp.float32)
+                                + w2.astype(jnp.float32))
+                return loss, (y, m, v)
+
+            argnums = (0, 1, 2, 3) if has_res else (0, 1, 2)
+            (_, aux_g), grads_g = jax.value_and_grad(
+                via_kernel, argnums, has_aux=True)(x, gamma, beta, res)
+            (_, aux_w), grads_w = jax.value_and_grad(
+                via_jnp, argnums, has_aux=True)(x, gamma, beta, res)
+            names = ["y", "mean", "var", "dx", "dgamma", "dbeta", "dres"]
+            return {k: _rel_l2(a, b) for k, a, b in
+                    zip(names, aux_g + grads_g, aux_w + grads_w)}
+
+        inputs = jax.jit(make_inputs)(jax.random.PRNGKey(seed + i))
+        errs = {k: float(v)
+                for k, v in jax.jit(site_errors)(*inputs).items()}
+        worst = max(errs, key=errs.get)
+        n_checked += 1
+        ok = all(e <= KERNEL_REL_L2 for e in errs.values())
+        log("kernels: %s — %s (worst %s %.2e)"
+            % (name, "ok" if ok else "MISMATCH", worst, errs[worst]))
+        if not ok:
+            bad.append((name, errs))
+    log("kernels: %d site(s) checked against the jnp reference at rel-L2 "
+        "<= %g, %d mismatched" % (n_checked, KERNEL_REL_L2, len(bad)))
+    check(not bad, "kernels: %r" % (bad,))
+    return n_checked
+
+
+# ---------------------------------------------------------------------------
+# phase 4: serve
+# ---------------------------------------------------------------------------
+
+class _KeepFutures:
+    """A ContinuousBatcher that remembers the futures it handed out, so the
+    answers of a load test can be read back (``poisson_loadtest`` only
+    counts them)."""
+
+    def __init__(self, batcher):
+        self._batcher = batcher
+        self.futures = []
+
+    def submit(self, *args, **kwargs):
+        fut = self._batcher.submit(*args, **kwargs)
+        self.futures.append(fut)
+        return fut
+
+    def __getattr__(self, name):
+        return getattr(self._batcher, name)
+
+
+def serve(buckets, image_size, n_requests, qps, n_check, classes=1000,
+          seed=0):
+    """ResNet-50 through ``ServeEngine`` + ``ContinuousBatcher`` under
+    open-loop Poisson traffic; ``n_check`` of the answers against a float32
+    forward of the same net on the host's cpu device."""
+    import jax
+    import numpy as np
+
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import nd
+    from incubator_mxnet_tpu.gluon.model_zoo import vision
+    from incubator_mxnet_tpu.serve import (ContinuousBatcher, ServeEngine,
+                                           poisson_loadtest)
+
+    mx.random.seed(seed)
+    net = vision.resnet50_v1(classes=classes)
+    net.initialize(init=mx.init.Xavier())
+    net.shape_init((1, 3, image_size, image_size))
+    eng = ServeEngine(net, buckets=buckets, lint="error")
+    t = eng.warmup(np.zeros((3, image_size, image_size), np.float32))
+    log("serve: buckets %s warm: trace %.1fs + compile %.1fs"
+        % (list(buckets), t["trace"], t["compile"]))
+    pool = np.random.RandomState(seed).rand(
+        n_check, 3, image_size, image_size).astype(np.float32)
+    batcher = _KeepFutures(ContinuousBatcher(eng, max_delay=0.010))
+    try:
+        rep = poisson_loadtest(batcher, lambda i, rng: pool[i % n_check],
+                               qps=qps, n_requests=n_requests, seed=seed)
+        answers = [np.asarray(f.result(timeout=30.0))
+                   for f in batcher.futures[:n_check]]
+    finally:
+        batcher.close()
+    log("serve: " + rep.format())
+    check(rep.ok == n_requests and len(batcher.futures) == n_requests,
+          "serve: %d of %d requests answered" % (rep.ok, n_requests))
+    check(rep.errors == 0 and rep.shed == 0 and rep.hung == 0,
+          "serve: %d errors, %d shed, %d hung"
+          % (rep.errors, rep.shed, rep.hung))
+    check(rep.recompiles == 0, "serve: %d recompiles after the warm-up"
+          % rep.recompiles)
+
+    # the reference: the same weights moved to the host's cpu device and
+    # run there op by op in float32 (true f32 products — the chip's
+    # default matmul precision is lower, hence cosine and not allclose)
+    cpu = jax.devices("cpu")[0]
+    for p in net.collect_params().values():
+        p.set_data(jax.device_put(p.data().asnumpy(), cpu))
+    with jax.default_device(cpu):
+        ref = net(nd.array(jax.device_put(pool, cpu))).asnumpy()
+    got = np.stack(answers).reshape(ref.shape)
+    check(np.isfinite(got).all(), "serve: an answer is not finite")
+    cos = [float(a @ b / (np.linalg.norm(a) * np.linalg.norm(b)))
+           for a, b in zip(got, ref)]
+    top_got, top_ref = got.argmax(1), ref.argmax(1)
+    log("serve: %d answers vs float32 on %s: cosine min %.6f, top-1 %s vs "
+        "%s" % (n_check, cpu, min(cos), top_got.tolist(), top_ref.tolist()))
+    check(min(cos) >= 0.999, "serve: cosine %r below 0.999" % (cos,))
+    check((top_got == top_ref).all(), "serve: top-1 differs: %r vs %r"
+          % (top_got.tolist(), top_ref.tolist()))
+    return rep
+
+
+# ---------------------------------------------------------------------------
+# --multichip: dp=4 ZeRO-1 step against the one-chip step
+# ---------------------------------------------------------------------------
+
+def multichip(dp, batch, image_size, steps, platform, classes=1000, seed=0,
+              loss_rtol=MULTICHIP_LOSS_RTOL, **step_kwargs):
+    """The data-parallel step ``bench.py --mesh-dp`` builds —
+    ``make_train_step(mesh=dp, zero=1)`` — for ``steps`` steps, and the
+    one-chip step on the same batch and seed for the first of them."""
+    import jax
+    import numpy as np
+
+    import bench
+
+    x, y = _batch(batch, image_size, classes, seed)
+
+    net_one, one = bench.build_train_step(image_size=image_size,
+                                          classes=classes, seed=seed,
+                                          **step_kwargs)
+    one.aot_compile(x, y)
+    loss_one, _ = _run_steps(one, x, y, 1)
+    log("multichip: one-chip step, first loss %.5f" % loss_one[0])
+    # its state leaves device 0 before the mesh step arrives
+    del net_one, one
+    gc.collect()
+
+    mesh = bench.dp_mesh(dp)
+    _, step = bench.build_train_step(image_size=image_size, classes=classes,
+                                     mesh=mesh, zero=1, seed=seed,
+                                     **step_kwargs)
+    times = step.aot_compile(x, y)
+    log("multichip: dp=%d zero=1 step: trace %.1fs, compile %.1fs"
+        % (dp, times["trace"], times["compile"]))
+    text = step.compiled.as_text()
+    ops = {op: text.count(op + "(") + text.count(op + "-start(")
+           for op in ("all-reduce", "reduce-scatter", "all-gather")}
+    log("multichip: collectives in the compiled step: %r" % ops)
+    check(ops["all-reduce"] + ops["reduce-scatter"] > 0,
+          "multichip: no all-reduce or reduce-scatter in the compiled step")
+    check(ops["all-gather"] > 0,
+          "multichip: no all-gather in the compiled step (ZeRO-1 gathers "
+          "the updated shards)")
+
+    losses, ms = _run_steps(step, x, y, steps)
+    log("multichip: losses %s; median step %.2f ms on %d x %s"
+        % (" ".join("%.5f" % v for v in losses), statistics.median(ms), dp,
+           jax.devices()[0].device_kind))
+    _check_losses(losses, "multichip")
+    rel = abs(losses[0] - loss_one[0]) / abs(loss_one[0])
+    log("multichip: first-step loss dp=%d %.5f vs one chip %.5f "
+        "(rel diff %.2e, tolerance %g)"
+        % (dp, losses[0], loss_one[0], rel, loss_rtol))
+    check(rel <= loss_rtol,
+          "multichip: first-step loss differs by %.3e" % rel)
+
+    params, state = _state_arrays(step)
+    devs = set()
+    for a in params:
+        shards = a.addressable_shards
+        devs |= {s.device for s in shards}
+        check(len({s.device for s in shards}) == dp
+              and all(s.data.shape == a.shape for s in shards),
+              "multichip: a parameter %r is not replicated over %d devices"
+              % (a.shape, dp))
+    check(len(devs) == dp and {d.platform for d in devs} == {platform},
+          "multichip: parameters live on %r" % (devs,))
+    per_dev = {d: 0 for d in devs}
+    for a in state:
+        shards = a.addressable_shards
+        check(len({s.device for s in shards}) == dp
+              and all(s.data.shape[0] * dp == a.shape[0] for s in shards),
+              "multichip: an optimizer-state leaf %r is not split %d ways "
+              "on its leading dim" % (a.shape, dp))
+        for s in shards:
+            per_dev[s.device] += s.data.nbytes
+    total = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in state)
+    log("multichip: %d params replicated on %d devices; optimizer state "
+        "%.1f MB in all, per device %s MB"
+        % (len(params), dp, total / 1e6,
+           sorted(round(v / 1e6, 1) for v in per_dev.values())))
+    check(all(v * dp == total for v in per_dev.values()),
+          "multichip: a device does not hold 1/%d of the optimizer state: "
+          "%r of %d" % (dp, per_dev, total))
+    return losses
+
+
+# ---------------------------------------------------------------------------
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--multichip", action="store_true",
+                    help="run only the dp=4 ZeRO-1 step and its one-chip "
+                         "comparison; needs four tpu devices")
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    dev = device("tpu", 4 if args.multichip else 1)
+    if args.multichip:
+        multichip(dp=4, batch=256, image_size=224, steps=3, platform="tpu",
+                  seed=args.seed)
+    else:
+        out = train(batch=256, image_size=224, steps=8, platform="tpu",
+                    seed=args.seed)
+        # the Pallas sites of the step that just ran: none while the
+        # default composition is stock BatchNorm
+        kernels(out["sites"], seed=args.seed)
+        serve(buckets=(16, 64), image_size=224, n_requests=64, qps=100.0,
+              n_check=8, seed=args.seed)
+    log("all phases passed")
+    print(json.dumps({"ok": True, "device": dev}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

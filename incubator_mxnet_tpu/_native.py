@@ -3,13 +3,16 @@
 The reference ships one libmxnet.so with a flat C ABI
 (include/mxnet/c_api.h); here the native side covers the host runtime —
 dependency engine, pooled/shm storage, recordio — while device compute is
-JAX/XLA.  The library is built on demand with ``make`` (g++) and cached;
-everything has a pure-Python fallback, so absence of a toolchain only
-costs speed, never functionality.
+JAX/XLA.  No binary is committed: :func:`build` makes each library on
+demand from the sources git holds, so what loads always matches the
+installed Python and toolchain.  The host runtime has a pure-Python
+fallback, so absence of a toolchain only costs speed there, never
+functionality.
 """
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -83,6 +86,47 @@ def _declare(lib):
 OPR_FN = ctypes.CFUNCTYPE(ctypes.c_int, ctypes.c_void_p)
 
 
+def _fresh(path: str) -> bool:
+    """``path`` exists and is no older than any source beside it — make's
+    own rule, applied to every source so it errs towards rebuilding."""
+    if not os.path.exists(path):
+        return False
+    srcs = [os.path.join(_SRC_DIR, f) for f in os.listdir(_SRC_DIR)
+            if f == "Makefile" or f.endswith((".cc", ".h"))]
+    return os.path.getmtime(path) >= max(map(os.path.getmtime, srcs))
+
+
+def build(target: str = _LIB_NAME, timeout: float = 600.0) -> str:
+    """Build ``src/native/<target>`` from the committed sources and return
+    its path.  The ONE way a native binary comes to exist — the loader
+    below, the C-ABI and cpp-package tests and
+    ``tools/make_serving_bundle.py`` all come through here — and one build
+    at a time: pytest-xdist workers and tools race for the same outputs,
+    so the look and the make run under an exclusive file lock.  A library
+    that is already up to date is returned as it is, so a checkout that
+    was built once needs neither ``make`` nor a writable ``src/native``.
+    Raises ``OSError`` / ``subprocess.SubprocessError`` when a build is
+    needed and there is no toolchain or it fails."""
+    path = os.path.join(_SRC_DIR, target)
+    try:
+        lock = open(os.path.join(_SRC_DIR, ".build.lock"), "w")
+    except OSError:
+        # a read-only checkout: nobody can be half-way through a link
+        if _fresh(path):
+            return path
+        raise
+    with lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _fresh(path):
+            return path
+        run = subprocess.run(["make", "-C", _SRC_DIR, target],
+                             capture_output=True, text=True, timeout=timeout)
+    if run.returncode != 0:
+        raise subprocess.CalledProcessError(
+            run.returncode, run.args, run.stdout, run.stderr[-4000:])
+    return path
+
+
 def get_lib():
     """Load (building if needed) the native library; None if unavailable."""
     global _LIB, _TRIED
@@ -92,19 +136,10 @@ def get_lib():
         if _LIB is not None or _TRIED:
             return _LIB
         _TRIED = True
-        path = os.path.join(_SRC_DIR, _LIB_NAME)
-        if not os.path.exists(path) and os.path.isdir(_SRC_DIR):
-            try:
-                subprocess.run(["make", "-C", _SRC_DIR],
-                               capture_output=True, timeout=120, check=True)
-            except Exception:
-                return None
-        if not os.path.exists(path):
-            return None
         try:
-            lib = ctypes.CDLL(path)
-            _declare(lib)
-            _LIB = lib
-        except OSError:
-            return None
+            lib = ctypes.CDLL(build(_LIB_NAME))
+        except (OSError, subprocess.SubprocessError):
+            return None  # no toolchain: the pure-Python host runtime
+        _declare(lib)
+        _LIB = lib
     return _LIB

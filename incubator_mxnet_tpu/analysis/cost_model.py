@@ -59,7 +59,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jex_core
 
 from .diagnostics import Diagnostic, Severity
 
@@ -301,7 +301,7 @@ def _eqn_flops(eqn) -> float:
                          default=0))
     if cls == "reduce":
         return float(max((_aval_elems(v.aval) for v in eqn.invars
-                          if not isinstance(v, jcore.Literal)), default=0))
+                          if not isinstance(v, jex_core.Literal)), default=0))
     if cls == "sg":
         return float(max((_aval_elems(v.aval) for v in eqn.outvars),
                          default=0))
@@ -313,7 +313,7 @@ def _eqn_flops(eqn) -> float:
         # roofline honest without decoding the kernel body
         return float(sum(_aval_elems(v.aval) for v in eqn.outvars)
                      + sum(_aval_elems(v.aval) for v in eqn.invars
-                           if not isinstance(v, jcore.Literal)))
+                           if not isinstance(v, jex_core.Literal)))
     return 0.0
 
 
@@ -326,7 +326,7 @@ def eqn_site_weight(eqn) -> Tuple[float, float]:
     of one pass, not the fused program traffic ``analyze_jaxpr``
     models."""
     reads = sum(_aval_bytes(v.aval) for v in eqn.invars
-                if not isinstance(v, jcore.Literal))
+                if not isinstance(v, jex_core.Literal))
     writes = sum(_aval_bytes(v.aval) for v in eqn.outvars)
     return _eqn_flops(eqn), float(reads + writes)
 
@@ -537,9 +537,9 @@ def _sub_closed(params):
     for v in params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for u in vs:
-            if isinstance(u, jcore.ClosedJaxpr):
+            if isinstance(u, jex_core.ClosedJaxpr):
                 yield u.jaxpr
-            elif isinstance(u, jcore.Jaxpr):
+            elif isinstance(u, jex_core.Jaxpr):
                 yield u
 
 
@@ -554,7 +554,7 @@ class _PVar:
 
 
 def _is_var(v) -> bool:
-    return isinstance(v, (jcore.Var, _PVar))
+    return isinstance(v, (jex_core.Var, _PVar))
 
 
 class _VEqn:
@@ -605,9 +605,9 @@ class _Walker:
             return None
         for k in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
             b = eqn.params.get(k)
-            if isinstance(b, jcore.ClosedJaxpr):
+            if isinstance(b, jex_core.ClosedJaxpr):
                 return b.jaxpr
-            if isinstance(b, jcore.Jaxpr):
+            if isinstance(b, jex_core.Jaxpr):
                 return b
         return None
 
@@ -621,7 +621,7 @@ class _Walker:
         must credit)."""
 
         def look(v):
-            if not isinstance(v, jcore.Var):
+            if not isinstance(v, jex_core.Var):
                 return v  # Literal
             return env.get(v, v)
 
@@ -639,14 +639,14 @@ class _Walker:
                         consts.append(benv[cv])
                 self._flatten(body, benv, flat, consts, depth + 1)
                 for eo, bo in zip(eqn.outvars, body.outvars):
-                    if isinstance(eo, jcore.Var):
+                    if isinstance(eo, jex_core.Var):
                         env[eo] = benv.get(bo, bo) \
-                            if isinstance(bo, jcore.Var) else bo
+                            if isinstance(bo, jex_core.Var) else bo
                 continue
             inv = [look(v) for v in eqn.invars]
             outv = []
             for o in eqn.outvars:
-                if not isinstance(o, jcore.Var):
+                if not isinstance(o, jex_core.Var):
                     outv.append(o)
                     continue
                 g = o if depth == 0 else _PVar(o.aval)
@@ -801,7 +801,7 @@ class _Walker:
         dup_eqns = self._cse(flat, alias)
 
         def res(v):
-            if isinstance(v, jcore.Var):
+            if isinstance(v, jex_core.Var):
                 v = env.get(v, v)
             return _res(alias, v)
 
@@ -991,7 +991,7 @@ class _Walker:
             branches = params.get("branches", ())
             best: Optional[_Acc] = None
             for br in branches:
-                sub = br.jaxpr if isinstance(br, jcore.ClosedJaxpr) else br
+                sub = br.jaxpr if isinstance(br, jex_core.ClosedJaxpr) else br
                 child = self.analyze(sub, axis_sizes)
                 if best is None or child_total(child) > child_total(best):
                     best = child
@@ -1106,7 +1106,7 @@ def analyze_jaxpr(closed_jaxpr, *,
     (comm-dominated) land in ``report.diagnostics``.
     """
     jaxpr = closed_jaxpr.jaxpr if isinstance(closed_jaxpr,
-                                             jcore.ClosedJaxpr) \
+                                             jex_core.ClosedJaxpr) \
         else closed_jaxpr
     donated = frozenset(jaxpr.invars[i] for i in donated_leaves
                         if i < len(jaxpr.invars))

@@ -96,6 +96,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import core as jcore
+from jax.extend import core as jex_core
 
 from .diagnostics import Diagnostic, LintError, LintReport, Severity
 
@@ -614,7 +615,7 @@ def interpret(jaxpr, consts, args, rule=None, skip=None):
     env: Dict[Any, Any] = {}
 
     def read(v):
-        return v.val if isinstance(v, jcore.Literal) else env[v]
+        return v.val if isinstance(v, jex_core.Literal) else env[v]
 
     for v, c in zip(jaxpr.constvars, consts):
         env[v] = c
@@ -628,7 +629,7 @@ def interpret(jaxpr, consts, args, rule=None, skip=None):
         if outs is None:
             outs = _default_bind(eqn, invals)
         for v, o in zip(eqn.outvars, outs):
-            if isinstance(v, jcore.Var):
+            if isinstance(v, jex_core.Var):
                 env[v] = o
     return [read(v) for v in jaxpr.outvars]
 
@@ -893,8 +894,8 @@ class AmpBf16Pass(GraftPass):
             from .value_range import bf16_fit, VRange as _VR
 
             for iv in eqn.invars[:2]:
-                vr = ranges.get(iv) if isinstance(iv, jcore.Var) else None
-                if vr is None and not isinstance(iv, jcore.Var):
+                vr = ranges.get(iv) if isinstance(iv, jex_core.Var) else None
+                if vr is None and not isinstance(iv, jex_core.Var):
                     import numpy as _np
 
                     val = _np.asarray(iv.val)
@@ -1248,14 +1249,14 @@ class CseDeadAuxPass(GraftPass):
 
     def _live_eqns(self, jaxpr) -> Tuple[set, int]:
         """ids of eqns some output (or effect) depends on."""
-        needed = {v for v in jaxpr.outvars if isinstance(v, jcore.Var)}
+        needed = {v for v in jaxpr.outvars if isinstance(v, jex_core.Var)}
         live, dead = set(), 0
         for eqn in reversed(jaxpr.eqns):
-            if any(isinstance(v, jcore.Var) and v in needed
+            if any(isinstance(v, jex_core.Var) and v in needed
                    for v in eqn.outvars) or eqn.effects:
                 live.add(id(eqn))
                 needed.update(v for v in eqn.invars
-                              if isinstance(v, jcore.Var))
+                              if isinstance(v, jex_core.Var))
             else:
                 dead += 1
         return live, dead
@@ -1276,7 +1277,7 @@ class CseDeadAuxPass(GraftPass):
         def rule(eqn, invals):
             prim = eqn.primitive.name
             if prim in self._NO_CSE or eqn.effects \
-                    or any(isinstance(sub, (jcore.Jaxpr, jcore.ClosedJaxpr))
+                    or any(isinstance(sub, (jex_core.Jaxpr, jex_core.ClosedJaxpr))
                            for v in eqn.params.values()
                            for sub in (v if isinstance(v, (tuple, list))
                                        else (v,))):

@@ -34,7 +34,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jex_core
 
 from .diagnostics import CODES, Diagnostic, LintError, LintReport, Severity
 
@@ -184,7 +184,7 @@ def _chase_var(var, producers):
     """Follow ``var`` back through layout-only ops; returns the var at
     the first non-layout producer (or the top-level input/constant)."""
     seen = 0
-    while isinstance(var, jcore.Var) and var in producers and seen < 64:
+    while isinstance(var, jex_core.Var) and var in producers and seen < 64:
         eqn = producers[var]
         if eqn.primitive.name in _LAYOUT_PRIMS and eqn.invars:
             var = eqn.invars[0]
@@ -199,9 +199,16 @@ def _chase_producer(var, producers):
     materialized it; returns the primitive name or None (top-level
     input / constant)."""
     var = _chase_var(var, producers)
-    if isinstance(var, jcore.Var) and var in producers:
+    if isinstance(var, jex_core.Var) and var in producers:
         return producers[var].primitive.name
     return None
+
+
+def _spec_names(spec) -> dict:
+    """A shard_map eqn's PartitionSpec as ``{dim: (axis, ...)}`` over its
+    sharded dims (empty = replicated)."""
+    return {d: tuple(e) if isinstance(e, (tuple, list)) else (e,)
+            for d, e in enumerate(spec) if e is not None}
 
 
 def _check_shard_map_eqn(eqn, diags: List[Diagnostic],
@@ -209,8 +216,8 @@ def _check_shard_map_eqn(eqn, diags: List[Diagnostic],
     mesh = eqn.params["mesh"]
     sizes = dict(mesh.shape)
     multi_axis = len(sizes) > 1
-    in_names = eqn.params.get("in_names", ())
-    out_names = eqn.params.get("out_names", ())
+    in_names = [_spec_names(s) for s in eqn.params["in_specs"]]
+    out_names = [_spec_names(s) for s in eqn.params["out_specs"]]
     for i, (var, names) in enumerate(zip(eqn.invars, in_names)):
         aval = var.aval
         ndim = getattr(aval, "ndim", 0)
@@ -282,7 +289,7 @@ def _check_donation(jaxpr, donated_mask: Sequence[bool],
     read is a read-after-donate (WARNING)."""
     outvars = list(jaxpr.outvars)
     out_avals = Counter(_aval_key(v.aval) for v in outvars
-                        if not isinstance(v, jcore.Literal))
+                        if not isinstance(v, jex_core.Literal))
     for i, (var, donated) in enumerate(zip(jaxpr.invars, donated_mask)):
         if not donated:
             continue
@@ -627,9 +634,9 @@ def _sub_jaxprs(params):
     for v in params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for u in vs:
-            if isinstance(u, jcore.ClosedJaxpr):
+            if isinstance(u, jex_core.ClosedJaxpr):
                 yield u.jaxpr
-            elif isinstance(u, jcore.Jaxpr):
+            elif isinstance(u, jex_core.Jaxpr):
                 yield u
 
 
@@ -671,9 +678,9 @@ def _walk(jaxpr, axis_sizes: Dict[str, int], diags: List[Diagnostic],
             inner_env = dict(axis_sizes)
             inner_env.update({k: int(v) for k, v in dict(mesh.shape).items()})
             body = eqn.params["jaxpr"]
-            in_names = eqn.params.get("in_names", ())
-            repl = frozenset(v for v, names in zip(body.invars, in_names)
-                             if not names)
+            repl = frozenset(
+                v for v, spec in zip(body.invars, eqn.params["in_specs"])
+                if not _spec_names(spec))
             _walk(body, inner_env, diags, path=where,
                   replicated_invars=repl)
         elif prim == "pjit":
@@ -690,7 +697,7 @@ def _walk(jaxpr, axis_sizes: Dict[str, int], diags: List[Diagnostic],
             for sub in _sub_jaxprs(eqn.params):
                 _walk(sub, axis_sizes, diags, path=where)
         for v in eqn.outvars:
-            if isinstance(v, jcore.Var):
+            if isinstance(v, jex_core.Var):
                 producers[v] = eqn
 
 
@@ -704,7 +711,7 @@ def lint_jaxpr(closed_jaxpr, *, axis_sizes: Optional[Dict[str, int]] = None,
     ``donated_leaves`` are flat invar indices donated at the top level.
     """
     jaxpr = closed_jaxpr.jaxpr if isinstance(
-        closed_jaxpr, jcore.ClosedJaxpr) else closed_jaxpr
+        closed_jaxpr, jex_core.ClosedJaxpr) else closed_jaxpr
     diags: List[Diagnostic] = []
     if donated_leaves:
         mask = [i in set(donated_leaves) for i in range(len(jaxpr.invars))]

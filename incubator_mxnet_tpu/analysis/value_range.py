@@ -89,7 +89,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from jax import core as jcore
+from jax.extend import core as jex_core
 
 from .diagnostics import Diagnostic, Severity
 
@@ -485,7 +485,7 @@ class _Interp:
         """Follow ``var`` back through value-transparent ops (and
         ``max``/``min`` against an infinite literal — the jnp.max
         ``initial=`` idiom)."""
-        while isinstance(var, jcore.Var) and depth > 0:
+        while isinstance(var, jex_core.Var) and depth > 0:
             eqn = producers.get(id(var))
             if eqn is None:
                 return var, None
@@ -494,9 +494,9 @@ class _Interp:
                 var = eqn.invars[0]
             elif prim in ("max", "min") and len(eqn.invars) == 2:
                 lits = [v for v in eqn.invars
-                        if isinstance(v, jcore.Literal)]
+                        if isinstance(v, jex_core.Literal)]
                 others = [v for v in eqn.invars
-                          if not isinstance(v, jcore.Literal)]
+                          if not isinstance(v, jex_core.Literal)]
                 if len(lits) == 1 and len(others) == 1 \
                         and np.isinf(np.asarray(lits[0].val)).all():
                     var = others[0]
@@ -580,7 +580,7 @@ class _Interp:
         if prim == "mul":
             a, b = ins[0], ins[1]
             if len(eqn.invars) == 2 and eqn.invars[0] is eqn.invars[1] \
-                    and isinstance(eqn.invars[0], jcore.Var):
+                    and isinstance(eqn.invars[0], jex_core.Var):
                 m = a.max_abs()
                 vr, _ = _clamp_overflow(
                     VRange(0.0, None if m is None else m * m, False,
@@ -981,7 +981,7 @@ class _Interp:
             env[cv] = _default_for_aval(cv.aval)
 
         def read(v) -> VRange:
-            if isinstance(v, jcore.Literal):
+            if isinstance(v, jex_core.Literal):
                 return _from_concrete(v.val,
                                       getattr(v.aval, "dtype", None))
             return env.get(v) or _default_for_aval(v.aval)
@@ -1011,7 +1011,7 @@ class _Interp:
                 if not sites_enabled:
                     del self.sites[n_sites:]
             for v, o in zip(eqn.outvars, outs):
-                if isinstance(v, jcore.Var):
+                if isinstance(v, jex_core.Var):
                     env[v] = o
                     producers[id(v)] = eqn
         return [read(v) for v in jaxpr.outvars]
@@ -1023,7 +1023,7 @@ class _Interp:
         if not outs_f64 or self.f64_inputs:
             return
         has_var_f64 = any(
-            isinstance(v, jcore.Var) and id(v) not in self.f64_consts
+            isinstance(v, jex_core.Var) and id(v) not in self.f64_consts
             and _dtype_is_f64(getattr(v.aval, "dtype", None))
             for v in eqn.invars)
         if has_var_f64:
@@ -1031,9 +1031,9 @@ class _Interp:
             # origin): one site per promotion chain, not per consumer
             return
         lit_f64 = [v for v in eqn.invars
-                   if isinstance(v, jcore.Literal)
+                   if isinstance(v, jex_core.Literal)
                    and _dtype_is_f64(getattr(v.aval, "dtype", None))]
-        const_f64 = any(isinstance(v, jcore.Var)
+        const_f64 = any(isinstance(v, jex_core.Var)
                         and id(v) in self.f64_consts
                         for v in eqn.invars)
         if lit_f64:
@@ -1055,10 +1055,10 @@ class _Interp:
         for v in params.values():
             vs = v if isinstance(v, (tuple, list)) else (v,)
             for u in vs:
-                if isinstance(u, jcore.ClosedJaxpr):
+                if isinstance(u, jex_core.ClosedJaxpr):
                     yield u
-                elif isinstance(u, jcore.Jaxpr):
-                    yield jcore.ClosedJaxpr(u, ())
+                elif isinstance(u, jex_core.Jaxpr):
+                    yield jex_core.ClosedJaxpr(u, ())
 
     def _call(self, eqn, ins, where, depth, collect):
         for body in self._bodies(eqn.params):
@@ -1131,8 +1131,8 @@ class _Interp:
         opnds = ins[1:]
         joined: Optional[List[VRange]] = None
         for br in branches:
-            closed = br if isinstance(br, jcore.ClosedJaxpr) \
-                else jcore.ClosedJaxpr(br, ())
+            closed = br if isinstance(br, jex_core.ClosedJaxpr) \
+                else jex_core.ClosedJaxpr(br, ())
             j = closed.jaxpr
             if len(j.invars) != len(opnds):
                 continue
@@ -1149,8 +1149,8 @@ class _Interp:
         body = eqn.params.get("jaxpr")
         if body is None:
             return [_unknown() for _ in eqn.outvars]
-        closed = body if isinstance(body, jcore.ClosedJaxpr) \
-            else jcore.ClosedJaxpr(body, ())
+        closed = body if isinstance(body, jex_core.ClosedJaxpr) \
+            else jex_core.ClosedJaxpr(body, ())
         j = closed.jaxpr
         if len(j.invars) != len(ins):
             return [_unknown() for _ in eqn.outvars]
@@ -1370,7 +1370,7 @@ def analyze_ranges(closed_jaxpr, *,
     amp gate's cheap mode: only ``var_ranges`` is needed).
     """
     jaxpr = closed_jaxpr.jaxpr if isinstance(closed_jaxpr,
-                                             jcore.ClosedJaxpr) \
+                                             jex_core.ClosedJaxpr) \
         else closed_jaxpr
     consts = getattr(closed_jaxpr, "consts", ())
     interp = _Interp(axis_sizes=axis_sizes)
@@ -1398,7 +1398,7 @@ def analyze_ranges(closed_jaxpr, *,
 
     report = RangeReport(meta=dict(meta or {}))
     report.var_ranges = {v: env[v] for v in env
-                         if isinstance(v, jcore.Var)}
+                         if isinstance(v, jex_core.Var)}
     if collect:
         for i, v in enumerate(jaxpr.invars):
             vr = env[v]

@@ -128,31 +128,21 @@ def _deconvolution(data, weight, bias=None, kernel=None, stride=None, dilate=Non
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
-def _maxpool_sws_impl(data, window, strides, padding, in_shape):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _maxpool_sws(data, window, strides, padding):
     return lax.reduce_window(data, -jnp.inf, lax.max, window, strides, padding)
 
 
-def _maxpool_sws(data, window, strides, padding):
-    return _maxpool_sws_impl(data, window, strides, padding,
-                             tuple(data.shape))
-
-
-def _maxpool_sws_fwd(data, window, strides, padding, in_shape):
-    from ..parallel import maxpool_idx
-
-    p = maxpool_idx.plan(in_shape, data.dtype.itemsize, window, strides,
-                         padding)
-    if p is not None:
-        # argmax-carrying forward (parallel/maxpool_idx.py): the winner
-        # offset rides out of the pooling pass as a 1-byte plane, so
-        # the backward never re-reads data/out to rediscover it — at
-        # 224 px that re-read was the stem ghost-BN output, the GL202
-        # census' sole remaining multi-pass tensor
-        out, first = maxpool_idx.maxpool_with_index(data, window, strides,
-                                                    padding, p)
-        return out, (first,)
-    out = _maxpool_sws_impl(data, window, strides, padding, in_shape)
+def _maxpool_sws_fwd(data, window, strides, padding):
+    # No Pallas form: an argmax-carrying forward kernel (round 20) never
+    # compiled for the chip.  On NCHW blocks W rides the lanes, and the
+    # v5e compiler refused every way to stride it: the in-register
+    # slice ("'vector.extract_strided_slice' op expected strides to be
+    # confined to [1, 2)"), the strided ref load of bf16 ("Strided load
+    # with non 32-bit data") and of f32 ("Stride on last dim is not
+    # 1").  A kernel over the (H, W, C, N) view would stride major dims
+    # only; until one exists the backward recomputes the winner below.
+    out = _maxpool_sws(data, window, strides, padding)
     return out, (data, out)
 
 
@@ -205,18 +195,12 @@ def shifted_window_unpool(data, out, g, window, strides, padding,
     return dx.astype(data.dtype)
 
 
-def _maxpool_sws_bwd(window, strides, padding, in_shape, res, g):
-    if len(res) == 1:
-        from ..parallel import maxpool_idx
-
-        (first,) = res
-        return (maxpool_idx.indexed_unpool(first, g, in_shape, window,
-                                           strides, padding),)
+def _maxpool_sws_bwd(window, strides, padding, res, g):
     data, out = res
     return (shifted_window_unpool(data, out, g, window, strides, padding),)
 
 
-_maxpool_sws_impl.defvjp(_maxpool_sws_fwd, _maxpool_sws_bwd)
+_maxpool_sws.defvjp(_maxpool_sws_fwd, _maxpool_sws_bwd)
 
 
 @register("Pooling", aliases=("pool",))

@@ -9,8 +9,8 @@ through, with running max/sum rescaling (the numerics of
 kernel so the MXU sees back-to-back (block_q × D) @ (D × block_k)
 matmuls and HBM traffic is O(S·D) instead of O(S²)).
 
-On non-TPU backends the kernels run in interpreter mode so the same code
-path is testable on CPU (tests/conftest.py virtual mesh).
+On the CPU backend the kernels run in interpreter mode so the same code
+path is testable there (tests/conftest.py virtual mesh).
 """
 from __future__ import annotations
 
@@ -20,8 +20,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as _np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .. import _backend
 
 __all__ = ["flash_attention"]
 
@@ -30,15 +33,7 @@ _NEG_INF = -1e30
 # index-map constant pinned to i32: the package enables jax_enable_x64, and
 # a python 0 in a BlockSpec index map lowers as i64, which Mosaic rejects
 # (failed to legalize func.return (i32, i32, i64))
-import numpy as _np
 _I0 = _np.int32(0)
-
-
-def _use_interpret():
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
 
 
 def _cdiv(a, b):
@@ -354,7 +349,7 @@ def flash_attention(q, k, v, causal=False, scale: Optional[float] = None,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = _backend.pallas_interpret()
     if use_pallas is None:
         use_pallas = interpret  # real-chip default: XLA fused attention
     if not use_pallas:

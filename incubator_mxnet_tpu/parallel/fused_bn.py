@@ -34,32 +34,42 @@ transposes around the custom call):
   bitcast.  Channels ride sublanes; the ghost group is the lane block
   of N (=128): an even larger statistics group.
 
-Layers whose whole-L windows can't fit VMEM no longer all fall back to
-jnp (round 20, docs/PERF.md):
+Layers whose whole-L windows can't fit VMEM do not all fall back to jnp
+(round 20, docs/PERF.md; re-planned in PR 23 against the v5e compiler,
+which gives EVERY operand of a call its own double-buffered window,
+aliased or not — see ``_plan``):
 
 * **lane-fold** (C < 128): the C lanes pad to 128 anyway, so k = 128/C
   rows of L are packed into the padded lane dimension — the view is
   (L/k, N, k*C) and the per-window footprint shrinks by k.  Stats
-  fold-reduce the k lane copies in-kernel; the ghost group stays the
-  sublane image block, so ``bn_group`` semantics are unchanged.  This
-  reclaims the 112x112x64 stem at bf16 (51.4 -> 25.7 MB windows).
+  fold-reduce the k lane copies in-kernel by rotating lanes
+  (``_fold_lanes``); the ghost group stays the sublane image block, so
+  ``bn_group`` semantics are unchanged.  This puts the FORWARD of the
+  112x112x64 stem at bf16 on Pallas (51.4 -> 25.7 MB windows).  It is a
+  forward-only form: the stem's backward needs three such windows, which
+  overrun VMEM even folded, so a lane-folded layer's backward is jnp by
+  plan.
 * **spatial-tiled** (cross-tile stat accumulation): a two-phase kernel
   pair — phase 1 accumulates per-tile partial sums over a sequential
   tile grid dimension into revisited (G, 1, C) blocks, the moments
   finalize on the tiny partials in jnp, and a parallel phase-2 kernel
   re-reads X to normalize (fwd) / write dX (bwd).  The window covers an
   L-tile instead of whole L, at the honest price of ONE extra read of
-  the operands (its own pallas_call, so graftcost charges it).  This
-  reclaims the 56x56x256 identity exits (3 windows x 12.8 MB).
+  the operands (its own pallas_call, so graftcost charges it).  At batch
+  256 this carries the 56x56x256 exits both ways and the backward of the
+  56x56x256 shortcut BN and of the 28x28x512 exits.
 
-Only layers that fit none of the forms fall back to the equivalent jnp
-formulation with the same ghost statistics.
+Only layers (or directions) that fit none of the forms use the equivalent
+jnp formulation with the same ghost statistics — chosen by ``_plan`` from
+shapes, the same on every backend.  ``tests/test_chip_compile.py`` compiles
+every ResNet-50 batch-256 site for a described v5e.
 
 Interpret mode runs the same kernels on CPU for tests, like
 parallel/flash_attention.py.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import sys
@@ -71,16 +81,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import _backend
+
 _I0 = np.int32(0)  # index-map literal pinned to i32 (package enables x64)
 
-#: jax 0.4.x ships the TPU params type as ``TPUCompilerParams``; newer
-#: releases renamed it ``CompilerParams``.  Resolve whichever exists —
-#: interpret mode accepts either, so the CPU parity tests run the same
-#: call path as the chip.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-__all__ = ["ghost_bn_act", "ghost_bn_stats_merge", "plan_describe", "Plan"]
+__all__ = ["ghost_bn_act", "ghost_bn_stats_merge", "plan_describe", "Plan",
+           "record_sites"]
 
 _VMEM_KERNEL_LIMIT = 120 * 1024 * 1024
 _WINDOW_BUDGET = 104 * 1024 * 1024
@@ -90,19 +96,12 @@ _WINDOW_BUDGET = 104 * 1024 * 1024
 _MAX_TILES = 16
 
 #: in-place output aliasing (dX over gY etc. — see _call_bwd).  A
-#: debugging escape hatch; the plan's window accounting assumes True.
+#: debugging escape hatch; it changes HBM buffers, not the plan.
 _IO_ALIASES = True
 
 
 def _aliases(d):
     return d if _IO_ALIASES else {}
-
-
-def _use_interpret():
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
 
 
 def _rup(x, m):
@@ -153,6 +152,23 @@ def _bshape(vec, ch_axis):
     return vec[None, :, None] if ch_axis == 1 else vec[None, None, :]
 
 
+def _fold_lanes(vec, fold):
+    """Sum the ``fold`` lane copies of a (fold*C,) vector.  Every lane
+    ends up holding its channel's total, so the result is already tiled
+    across the copies — the form the normalize/dX loops broadcast.
+    Rotate-and-add, log2(fold) steps (C divides 128, so fold is a power
+    of two): Mosaic has no lane-splitting reshape — the v5e compiler
+    refused ``sm.reshape(fold, -1)`` with ``infer-vector-layout:
+    unsupported shape cast`` (``vector<128xf32> -> vector<2x64xf32>``).
+    The shift is pinned to i32 like the index-map literals."""
+    row = vec.reshape(1, -1)
+    shift = row.shape[1] // fold
+    while shift < row.shape[1]:
+        row = row + pltpu.roll(row, np.int32(shift), 1)
+        shift *= 2
+    return row.reshape(-1)
+
+
 def _fwd_kernel(x_ref, g_ref, b_ref, y_ref, m_ref, v_ref, *, eps, act, lc,
                 ch_axis, r_ref=None, fold=1):
     l, a, b = x_ref.shape
@@ -173,24 +189,18 @@ def _fwd_kernel(x_ref, g_ref, b_ref, y_ref, m_ref, v_ref, *, eps, act, lc,
     sm = jnp.sum(sm, axis=cross)
     ssq = jnp.sum(ssq, axis=cross)
     if fold > 1:
-        # lane-fold: the lane dim carries (fold, C) — fold-reduce to the
-        # true channel axis before the moments
-        sm = jnp.sum(sm.reshape(fold, -1), axis=0)
-        ssq = jnp.sum(ssq.reshape(fold, -1), axis=0)
+        # lane-fold: the lane dim carries (fold, C).  Params and stats
+        # ride at the same fold*C width (tiled by the caller), so after
+        # the fold-reduce everything below is lane-wide
+        sm = _fold_lanes(sm, fold)
+        ssq = _fold_lanes(ssq, fold)
     m = sm / cnt
     v = jnp.maximum(ssq / cnt - m * m, 0.0)
     rstd = jax.lax.rsqrt(v + eps)
     g = g_ref[...].reshape(-1).astype(jnp.float32)
     bb = b_ref[...].reshape(-1).astype(jnp.float32)
-    scale_c = g * rstd
-    shift_c = bb - m * g * rstd
-    if fold > 1:
-        # tile the per-channel affine back across the fold copies so it
-        # broadcasts against the (lc, A, fold*C) chunks
-        scale_c = jnp.tile(scale_c, fold)
-        shift_c = jnp.tile(shift_c, fold)
-    scale = _bshape(scale_c, ch_axis)
-    shift = _bshape(shift_c, ch_axis)
+    scale = _bshape(g * rstd, ch_axis)
+    shift = _bshape(bb - m * g * rstd, ch_axis)
 
     def norm(i, _):
         sl = pl.ds(i * jnp.int32(lc), lc)
@@ -214,22 +224,16 @@ def _fwd_kernel_res(x_ref, r_ref, g_ref, b_ref, y_ref, m_ref, v_ref, *,
 
 def _bwd_kernel(gy_ref, x_ref, g_ref, b_ref, m_ref, v_ref, dx_ref, dg_ref,
                 db_ref, *, eps, act, lc, ch_axis, y_ref=None, dr_ref=None,
-                fold=1, gy2_ref=None):
+                gy2_ref=None):
     l, a, b = x_ref.shape
     k = l // lc
-    cnt = l * (b if ch_axis == 1 else a) * fold
+    cnt = l * (b if ch_axis == 1 else a)
     m = m_ref[...].reshape(-1)
     v = v_ref[...].reshape(-1)
     rstd = jax.lax.rsqrt(v + eps)
     g = g_ref[...].reshape(-1).astype(jnp.float32)
     bb = b_ref[...].reshape(-1).astype(jnp.float32) if b_ref is not None \
         else None
-    if fold > 1:
-        m = jnp.tile(m, fold)
-        rstd = jnp.tile(rstd, fold)
-        g = jnp.tile(g, fold)
-        if bb is not None:
-            bb = jnp.tile(bb, fold)
     mb = _bshape(m, ch_axis)
     rb = _bshape(rstd, ch_axis)
     gb = _bshape(g, ch_axis)
@@ -262,13 +266,8 @@ def _bwd_kernel(gy_ref, x_ref, g_ref, b_ref, m_ref, v_ref, dx_ref, dg_ref,
     cross = 1 if ch_axis == 1 else 0
     db = jnp.sum(db, axis=cross)
     dg = jnp.sum(dg, axis=cross)
-    if fold > 1:
-        # fold-reduce the lane copies FIRST (dX needs the per-channel
-        # totals), then tile back for the write loop's broadcasts
-        db = jnp.sum(db.reshape(fold, -1), axis=0)
-        dg = jnp.sum(dg.reshape(fold, -1), axis=0)
-    dbb = _bshape(jnp.tile(db, fold) if fold > 1 else db, ch_axis)
-    dgb = _bshape(jnp.tile(dg, fold) if fold > 1 else dg, ch_axis)
+    dbb = _bshape(db, ch_axis)
+    dgb = _bshape(dg, ch_axis)
 
     def wr(i, _):
         sl = pl.ds(i * jnp.int32(lc), lc)
@@ -285,23 +284,22 @@ def _bwd_kernel(gy_ref, x_ref, g_ref, b_ref, m_ref, v_ref, dx_ref, dg_ref,
 
 
 def _bwd_kernel_res(gy_ref, x_ref, y_ref, g_ref, m_ref, v_ref, dx_ref,
-                    dg_ref, db_ref, dr_ref, *, eps, act, lc, ch_axis,
-                    fold=1):
+                    dg_ref, db_ref, dr_ref, *, eps, act, lc, ch_axis):
     # residual variant: the post-add ReLU mask comes from the saved OUTPUT
     # (y > 0 iff pre+res > 0), so the residual tensor itself is not re-read
     _bwd_kernel(gy_ref, x_ref, g_ref, None, m_ref, v_ref, dx_ref, dg_ref,
                 db_ref, eps=eps, act=act, lc=lc, ch_axis=ch_axis,
-                y_ref=y_ref, dr_ref=dr_ref, fold=fold)
+                y_ref=y_ref, dr_ref=dr_ref)
 
 
 def _bwd_kernel_res_dual(gy_ref, gy2_ref, x_ref, y_ref, g_ref, m_ref, v_ref,
                          dx_ref, dg_ref, db_ref, dr_ref, *, eps, act, lc,
-                         ch_axis, fold=1):
+                         ch_axis):
     # dual-cotangent residual variant (the block-exit join absorption):
     # gy1 (conv path) + gy2 (shortcut) sum on the window load
     _bwd_kernel(gy_ref, x_ref, g_ref, None, m_ref, v_ref, dx_ref, dg_ref,
                 db_ref, eps=eps, act=act, lc=lc, ch_axis=ch_axis,
-                y_ref=y_ref, dr_ref=dr_ref, fold=fold, gy2_ref=gy2_ref)
+                y_ref=y_ref, dr_ref=dr_ref, gy2_ref=gy2_ref)
 
 
 # ---------------------------------------------------------------------------
@@ -508,16 +506,15 @@ def _bwd_dx_from_dr_tile_kernel(dr_ref, x_ref, g_ref, m_ref, v_ref, db_ref,
 # ---------------------------------------------------------------------------
 
 
-def _specs(l, n, c, ab, ch_axis, fold=1):
+def _specs(l, n, c, ab, ch_axis):
     """Block specs for the (L, A, B) view.  ab = (A-block, B-block).
     Grid is (groups, channel-blocks); channel params/stats use the
-    'equal-dim trick' shapes so small channel blocks stay legal.  With
-    ``fold`` > 1 (lane-fold, LNC only) the X blocks carry fold*B lanes
-    while params/stats stay at the true channel width — the kernels
-    fold-reduce/tile between the two."""
+    'equal-dim trick' shapes so small channel blocks stay legal.  The
+    lane-fold forward calls this with its folded width (``_lane_width``):
+    X, params and stats all carry fold*C lanes there."""
     a_blk, b_blk = ab
     if ch_axis == 2:   # LNC: A=N (groups on sublanes), B=C
-        xspec = pl.BlockSpec((l, a_blk, fold * b_blk),
+        xspec = pl.BlockSpec((l, a_blk, b_blk),
                              lambda g, ci: (_I0, g, ci))
         pspec = pl.BlockSpec((1, b_blk), lambda g, ci: (_I0, ci))
         sspec = pl.BlockSpec((1, 1, b_blk), lambda g, ci: (g, _I0, ci))
@@ -534,15 +531,28 @@ def _specs(l, n, c, ab, ch_axis, fold=1):
     return xspec, pspec, sspec, n_groups, pshape, sshape
 
 
+def _lane_width(x_v, ab, ch_axis, fold):
+    """(true C, kernel-visible C, kernel-visible ab) of a view.  Under
+    lane-fold (LNC only) the kernel sees fold*C channels: the caller
+    tiles params/stats up to that width and slices the kernel's stat
+    outputs back to the first C lanes (every copy holds the total)."""
+    if ch_axis == 2:
+        cw = x_v.shape[2]
+        return cw // fold, cw, (ab[0], fold * ab[1])
+    return x_v.shape[1], x_v.shape[1], ab
+
+
 def _call_fwd(x_v, gamma, beta, residual, eps, act, ab, ch_axis,
               donate_res=False, fold=1):
     l = x_v.shape[0]
     n = x_v.shape[1] if ch_axis == 2 else x_v.shape[2]
-    c = (x_v.shape[2] // fold) if ch_axis == 2 else x_v.shape[1]
-    xspec, pspec, sspec, ngroups, pshape, sshape = _specs(l, n, c, ab,
-                                                          ch_axis, fold)
-    grid = (ngroups, c // (ab[1] if ch_axis == 2 else ab[0]))
-    lc = _chunk(l, ab[0], ab[1] * (fold if ch_axis == 2 else 1))
+    c, cw, ab = _lane_width(x_v, ab, ch_axis, fold)
+    xspec, pspec, sspec, ngroups, pshape, sshape = _specs(l, n, cw, ab,
+                                                          ch_axis)
+    grid = (ngroups, cw // (ab[1] if ch_axis == 2 else ab[0]))
+    lc = _chunk(l, ab[0], ab[1])
+    if fold > 1:
+        gamma, beta = jnp.tile(gamma, fold), jnp.tile(beta, fold)
     out_shape = [jax.ShapeDtypeStruct(x_v.shape, x_v.dtype),
                  jax.ShapeDtypeStruct(sshape, jnp.float32),
                  jax.ShapeDtypeStruct(sshape, jnp.float32)]
@@ -567,37 +577,35 @@ def _call_fwd(x_v, gamma, beta, residual, eps, act, ab, ch_axis,
         kern, grid=grid, in_specs=in_specs,
         out_specs=[xspec, sspec, sspec], out_shape=out_shape,
         input_output_aliases=_aliases(aliases),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_VMEM_KERNEL_LIMIT),
-        interpret=_use_interpret())(*args)
-    return y, m.reshape(ngroups, c), v.reshape(ngroups, c)
+        interpret=_backend.pallas_interpret())(*args)
+    return y, m.reshape(ngroups, cw)[:, :c], v.reshape(ngroups, cw)[:, :c]
 
 
 def _call_bwd(gy, x_v, y_v, gamma, beta, m, v, eps, act, ab, ch_axis,
-              fold=1, gy2=None):
+              gy2=None):
     """One-read backward.  The cotangent gY and the saved X are both
     dead after this call (gY's only consumer is this vjp; X was saved
     exactly for it), so the kernels write their outputs in place:
     dX over gY (non-residual) / dR over gY and dX over X (residual) via
-    ``input_output_aliases`` — the reduction loop finishes every chunk
-    read before the write loop touches a window, and within the write
-    loop each chunk is read strictly before it is overwritten.  That
-    cuts the double-buffered VMEM budget from 3 (5 residual) full
-    windows to 2 (3), which is what lets the 28x28x512 residual exits
-    and the 56x56x256 downsample BN run the fused bwd at batch 256
-    (docs/PERF.md round 19).  ``gy2`` is the dual-output shortcut
+    ``input_output_aliases`` — each grid step reads and writes the same
+    block index, so the in-place update is race-free.  The alias saves
+    the HBM buffer only: Mosaic still gives every operand, input or
+    output, its own double-buffered VMEM window, and ``_plan`` counts
+    them all.  ``gy2`` is the dual-output shortcut
     cotangent (round 20): a block exit returning its tensor in TWO
     output positions receives the conv-path and shortcut cotangents
     separately, and the kernel sums them on the window load instead of
     the program paying a materialized add_any join."""
     l = x_v.shape[0]
     n = x_v.shape[1] if ch_axis == 2 else x_v.shape[2]
-    c = (x_v.shape[2] // fold) if ch_axis == 2 else x_v.shape[1]
+    c = x_v.shape[2] if ch_axis == 2 else x_v.shape[1]
     xspec, pspec, sspec, ngroups, pshape, sshape = _specs(l, n, c, ab,
-                                                          ch_axis, fold)
+                                                          ch_axis)
     grid = (ngroups, c // (ab[1] if ch_axis == 2 else ab[0]))
-    lc = _chunk(l, ab[0], ab[1] * (fold if ch_axis == 2 else 1))
+    lc = _chunk(l, ab[0], ab[1])
     dstat = jax.ShapeDtypeStruct(sshape, jnp.float32)
     m_s = m.reshape(sshape)
     v_s = v.reshape(sshape)
@@ -607,7 +615,7 @@ def _call_bwd(gy, x_v, y_v, gamma, beta, m, v, eps, act, ab, ch_axis,
             # residual block exits dual) — merge upfront, stay correct
             gy = gy + gy2
         kern = functools.partial(_bwd_kernel, eps=eps, act=act, lc=lc,
-                                 ch_axis=ch_axis, fold=fold)
+                                 ch_axis=ch_axis)
         dx, dg, db = pl.pallas_call(
             kern, grid=grid,
             in_specs=[xspec, xspec, pspec, pspec, sspec, sspec],
@@ -615,23 +623,22 @@ def _call_bwd(gy, x_v, y_v, gamma, beta, m, v, eps, act, ab, ch_axis,
             out_shape=[jax.ShapeDtypeStruct(x_v.shape, x_v.dtype), dstat,
                        dstat],
             input_output_aliases=_aliases({0: 0}),  # dX over dead gY
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
                 vmem_limit_bytes=_VMEM_KERNEL_LIMIT),
-            interpret=_use_interpret())(
+            interpret=_backend.pallas_interpret())(
             gy, x_v, gamma.reshape(pshape), beta.reshape(pshape), m_s, v_s)
         dr = None
     else:
         if gy2 is None:
             kern = functools.partial(_bwd_kernel_res, eps=eps, act=act,
-                                     lc=lc, ch_axis=ch_axis, fold=fold)
+                                     lc=lc, ch_axis=ch_axis)
             in_specs = [xspec, xspec, xspec, pspec, sspec, sspec]
             args = (gy, x_v, y_v, gamma.reshape(pshape), m_s, v_s)
             aliases = {0: 3, 1: 0}  # dR/gY, dX/X
         else:
             kern = functools.partial(_bwd_kernel_res_dual, eps=eps,
-                                     act=act, lc=lc, ch_axis=ch_axis,
-                                     fold=fold)
+                                     act=act, lc=lc, ch_axis=ch_axis)
             in_specs = [xspec, xspec, xspec, xspec, pspec, sspec, sspec]
             args = (gy, gy2, x_v, y_v, gamma.reshape(pshape), m_s, v_s)
             aliases = {0: 3, 2: 0}  # dR/gY1, dX/X
@@ -641,10 +648,10 @@ def _call_bwd(gy, x_v, y_v, gamma, beta, m, v, eps, act, ab, ch_axis,
             out_shape=[jax.ShapeDtypeStruct(x_v.shape, x_v.dtype), dstat,
                        dstat, jax.ShapeDtypeStruct(x_v.shape, x_v.dtype)],
             input_output_aliases=_aliases(aliases),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
                 vmem_limit_bytes=_VMEM_KERNEL_LIMIT),
-            interpret=_use_interpret())(*args)
+            interpret=_backend.pallas_interpret())(*args)
     return (dx, dg.reshape(ngroups, c).sum(0), db.reshape(ngroups, c).sum(0),
             dr)
 
@@ -658,7 +665,7 @@ def _tile_specs(lt, ng, c):
 
 
 def _tile_params(sequential):
-    return _CompilerParams(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel",
                              "arbitrary" if sequential else "parallel"),
         vmem_limit_bytes=_VMEM_KERNEL_LIMIT)
@@ -683,7 +690,7 @@ def _call_fwd_tiled(x_v, gamma, beta, residual, eps, act, ab, lt,
         out_specs=[sspec, sspec],
         out_shape=[jax.ShapeDtypeStruct(sshape, jnp.float32)] * 2,
         compiler_params=_tile_params(True),
-        interpret=_use_interpret())(x_v)
+        interpret=_backend.pallas_interpret())(x_v)
     cnt = l * ng
     m = (s / cnt).reshape(ngroups, c)
     v = jnp.maximum((ss / cnt).reshape(ngroups, c) - m * m, 0.0)
@@ -706,7 +713,7 @@ def _call_fwd_tiled(x_v, gamma, beta, residual, eps, act, ab, lt,
         out_shape=jax.ShapeDtypeStruct(x_v.shape, x_v.dtype),
         input_output_aliases=_aliases(aliases),
         compiler_params=_tile_params(False),
-        interpret=_use_interpret())(*args)
+        interpret=_backend.pallas_interpret())(*args)
     return y, m, v
 
 
@@ -739,7 +746,7 @@ def _call_bwd_tiled(gy, x_v, y_v, gamma, beta, m, v, eps, act, ab, lt,
             in_specs=[xspec, xspec, pspec, pspec, sspec, sspec],
             out_specs=[sspec, sspec], out_shape=[dstat, dstat],
             compiler_params=_tile_params(True),
-            interpret=_use_interpret())(
+            interpret=_backend.pallas_interpret())(
             gy, x_v, gamma.reshape(1, c), beta.reshape(1, c), m_s, v_s)
         kern = functools.partial(_bwd_dx_tile_kernel, eps=eps, act=act,
                                  lc=lc, cnt=cnt)
@@ -751,7 +758,7 @@ def _call_bwd_tiled(gy, x_v, y_v, gamma, beta, m, v, eps, act, ab, lt,
             out_shape=jax.ShapeDtypeStruct(x_v.shape, x_v.dtype),
             input_output_aliases=_aliases({0: 0}),  # dX over dead gY
             compiler_params=_tile_params(False),
-            interpret=_use_interpret())(
+            interpret=_backend.pallas_interpret())(
             gy, x_v, gamma.reshape(1, c), beta.reshape(1, c), m_s, v_s,
             db, dg)
         dr = None
@@ -773,7 +780,7 @@ def _call_bwd_tiled(gy, x_v, y_v, gamma, beta, m, v, eps, act, ab, lt,
                        jax.ShapeDtypeStruct(x_v.shape, x_v.dtype)],
             input_output_aliases=_aliases({0: 2}),  # dR over dead gY
             compiler_params=_tile_params(True),
-            interpret=_use_interpret())(*args)
+            interpret=_backend.pallas_interpret())(*args)
         kern = functools.partial(_bwd_dx_from_dr_tile_kernel, eps=eps,
                                  lc=lc, cnt=cnt)
         dx = pl.pallas_call(
@@ -783,7 +790,7 @@ def _call_bwd_tiled(gy, x_v, y_v, gamma, beta, m, v, eps, act, ab, lt,
             out_shape=jax.ShapeDtypeStruct(x_v.shape, x_v.dtype),
             input_output_aliases=_aliases({1: 0}),  # dX over dead X
             compiler_params=_tile_params(False),
-            interpret=_use_interpret())(
+            interpret=_backend.pallas_interpret())(
             dr, x_v, gamma.reshape(1, c), m_s, v_s, db, dg)
     return (dx, dg.reshape(ngroups, c).sum(0), db.reshape(ngroups, c).sum(0),
             dr)
@@ -812,30 +819,32 @@ class Plan(NamedTuple):
     window_bytes: int = 0  # padded per-window bytes of the fwd form
 
 
-def _plan(n, c, l, itemsize, group, has_res, donate_res=False, dual=False):
+def _plan(n, c, l, itemsize, group, has_res, dual=False):
     """Choose a :class:`Plan` or None for the full-jnp fallback.
 
-    Feasibility is per DIRECTION: Mosaic double-buffers every window
-    (x2) and pads sublanes/lanes to the dtype tile.  Window counts
-    reflect the in-place aliasing ``_call_fwd``/``_call_bwd`` declare:
-    fwd needs 2 windows (X in, Y out) + 1 for a residual — or +0 when
-    the caller donates it (``donate_residual``: dead shortcut tensors
-    alias into Y); bwd needs 2 (X in, dX over the dead gY window) + 1
-    residual (Y for the post-add ReLU mask; dR rides the gY window and
-    dX the X window) + 1 when the exit is dual (``dual``: the separate
-    shortcut cotangent gY2 needs its own window).  The tiled residual
-    bwd peaks in phase 1 at the same count (gY[, gY2], X, Y in, dR over
-    gY); its phase 2 needs only 2 (dR and X in, dX over X) — under the
-    phase-1 peak.
+    Feasibility is per DIRECTION and counts what the chip's compiler
+    counts: EVERY operand of a ``pallas_call``, input or output, gets
+    its own double-buffered (x2) VMEM window, padded to the dtype tile.
+    ``input_output_aliases`` shares the HBM buffer, not the window —
+    the v5e compiler charged the aliased 28x28x512 residual backward
+    "Scoped allocation with size 122.50M", which is 5 windows x 2 x
+    12.25 MiB.  So a donated residual does not change the plan (it
+    only lets ``_call_fwd`` write Y over the dead residual in HBM).  Whole-L
+    fwd: X [R] -> Y is 2 (+1 residual); bwd: gY X -> dX is 3, the
+    residual form gY X Y -> dX dR is 5, +1 for a dual exit's gY2.  The
+    two-phase tiled forms peak in the phase with most operands: fwd
+    phase 2 (X [R] -> Y), non-residual bwd phase 2 (gY X -> dX, 3),
+    residual bwd phase 1 (gY [gY2] X Y -> dR, 4 or 5; its phase 2,
+    dR X -> dX, is 3).  What ``_WINDOW_BUDGET`` leaves under
+    ``_VMEM_KERNEL_LIMIT`` is for the kernels' stack temporaries (the
+    f32 chunk temps ``_chunk`` sizes, the accumulators).
 
     Selection order on the LNC path (round 20): whole-L fused both
-    directions > lane-fold both (C < 128: the window shrinks by
-    k = 128/C, same one-read kernels) > whole-L fused fwd + spatial-
+    directions > lane-fold fwd + jnp bwd (C < 128: the window shrinks by
+    k = 128/C, same one-read fwd kernel) > whole-L fused fwd + spatial-
     tiled bwd > spatial-tiled both > whole-L fused fwd + jnp bwd (the
     legacy hybrid) > None.  Earlier forms read each operand once; the
-    tiled forms pay one extra read of the operands (the stats phase) —
-    still a win over the jnp fallback's unfused multi-pass traffic, and
-    census-exempt custom DMA either way.
+    tiled forms pay one extra read of the operands (the stats phase).
     """
     sub = _sublane(itemsize)
 
@@ -845,8 +854,9 @@ def _plan(n, c, l, itemsize, group, has_res, donate_res=False, dual=False):
     def fits(nwin, a_blk, b_blk, rows=l):
         return nwin * 2 * padded(a_blk, b_blk, rows) <= _WINDOW_BUDGET
 
-    fw = (3 - (1 if donate_res else 0)) if has_res else 2
-    bw = ((4 if dual else 3) if has_res else 2)
+    fw = 2 + has_res
+    bw = (5 + dual) if has_res else 3
+    bw_tiled = (4 + dual) if has_res else 3
     if c >= 128 or n > 128:
         # LNC: full C on lanes, ghost group on sublanes.  Prefer
         # tile-multiple groups (a sub-tile group pads VMEM to the tile
@@ -869,14 +879,15 @@ def _plan(n, c, l, itemsize, group, has_res, donate_res=False, dual=False):
         # k = 128/C rows of L into the padding so the window shrinks by
         # k.  The ghost group stays the sublane image block (bn_group
         # cap semantics unchanged); stats fold-reduce in-kernel.
+        # Forward only: where the whole-L backward does not fit, its 3+
+        # windows rarely fit folded either (never at a ResNet-50
+        # batch-256 site), so the backward of a lane-folded layer is jnp.
         fold = 128 // c if (c < 128 and 128 % c == 0) else 1
         if fold > 1 and l % fold == 0:
             lf = l // fold
             for ng in ngs:
                 if fits(fw, ng, fold * c, lf):
-                    bwd_ok = fits(bw, ng, fold * c, lf)
-                    return Plan(2, (ng, c), bwd_ok, "lanefold",
-                                "lanefold" if bwd_ok else "jnp",
+                    return Plan(2, (ng, c), False, "lanefold", "jnp",
                                 fold=fold,
                                 window_bytes=padded(ng, fold * c, lf))
 
@@ -890,10 +901,10 @@ def _plan(n, c, l, itemsize, group, has_res, donate_res=False, dual=False):
             return 0
 
         # whole-L fused fwd + spatial-tiled bwd: keeps the one-read fwd
-        # and still retires the bwd multi-pass (the donated 56x56x256
-        # downsample at batch 256)
+        # and still retires the bwd multi-pass (the 56x56x256
+        # downsample-shortcut BN at batch 256)
         if best_fwd is not None:
-            ltb = tile_rows(bw, best_fwd)
+            ltb = tile_rows(bw_tiled, best_fwd)
             if ltb:
                 return Plan(2, (best_fwd, c), True, "fused", "tiled",
                             l_tile_bwd=ltb,
@@ -902,7 +913,7 @@ def _plan(n, c, l, itemsize, group, has_res, donate_res=False, dual=False):
         for ng in ngs:
             ltf = tile_rows(fw, ng)
             if ltf:
-                ltb = tile_rows(bw, ng)
+                ltb = tile_rows(bw_tiled, ng)
                 return Plan(2, (ng, c), bool(ltb), "tiled",
                             "tiled" if ltb else "jnp",
                             l_tile=ltf, l_tile_bwd=ltb,
@@ -969,7 +980,7 @@ def _gbn_fwd(x, gamma, beta, residual, eps, act, group, donate_res=False,
              dual=False):
     n, c, h, w = x.shape
     plan = _plan(n, c, h * w, x.dtype.itemsize, group,
-                 residual is not None, donate_res, dual)
+                 residual is not None, dual)
     ch_axis = plan.ch_axis
     fold = plan.fold if plan.variant == "lanefold" else 1
     x_v = _to_view(x, ch_axis, fold)
@@ -1020,16 +1031,16 @@ def _gbn_bwd_jnp(gy, x, y, gamma, beta, m, v, eps, act, ng):
             dr)
 
 
-def _gbn_bwd_impl(eps, act, group, donate_res, dual, res, gy, gy2):
+def _gbn_bwd_impl(eps, act, group, dual, res, gy, gy2):
     x_v, y_v, gamma, beta, m, v, shape = res
     n, c, h, w = shape
     plan = _plan(n, c, h * w, x_v.dtype.itemsize, group, y_v is not None,
-                 donate_res, dual)
+                 dual)
     ch_axis = plan.ch_axis
-    fold = plan.fold if plan.variant == "lanefold" else 1
     if plan.bwd_pallas:
-        gy_v = _to_view(gy, ch_axis, fold)
-        gy2_v = None if gy2 is None else _to_view(gy2, ch_axis, fold)
+        # (never a lane-folded layer: its backward is jnp by plan)
+        gy_v = _to_view(gy, ch_axis)
+        gy2_v = None if gy2 is None else _to_view(gy2, ch_axis)
         if plan.bwd_variant == "tiled":
             dx, dg, db, dr = _call_bwd_tiled(gy_v, x_v, y_v, gamma, beta,
                                              m, v, eps, act, plan.ab,
@@ -1037,12 +1048,13 @@ def _gbn_bwd_impl(eps, act, group, donate_res, dual, res, gy, gy2):
         else:
             dx, dg, db, dr = _call_bwd(gy_v, x_v, y_v, gamma, beta, m, v,
                                        eps, act, plan.ab, ch_axis,
-                                       fold=fold, gy2=gy2_v)
-        dx = _from_view(dx, shape, ch_axis, fold)
-        dr = None if dr is None else _from_view(dr, shape, ch_axis, fold)
+                                       gy2=gy2_v)
+        dx = _from_view(dx, shape, ch_axis)
+        dr = None if dr is None else _from_view(dr, shape, ch_axis)
     else:
         if gy2 is not None:
             gy = gy + gy2
+        fold = plan.fold if plan.variant == "lanefold" else 1
         x = _from_view(x_v, shape, ch_axis, fold)
         y = None if y_v is None else _from_view(y_v, shape, ch_axis, fold)
         ng = plan.ab[0] if ch_axis == 2 else plan.ab[1]
@@ -1053,7 +1065,7 @@ def _gbn_bwd_impl(eps, act, group, donate_res, dual, res, gy, gy2):
 
 def _gbn_bwd(eps, act, group, donate_res, res, ct):
     gy, _, _ = ct  # cotangents for the stat outputs are not propagated
-    return _gbn_bwd_impl(eps, act, group, donate_res, False, res, gy, None)
+    return _gbn_bwd_impl(eps, act, group, False, res, gy, None)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -1073,7 +1085,7 @@ def _gbn_fwd_dual(x, gamma, beta, residual, eps, act, group, donate_res):
 
 def _gbn_bwd_dual(eps, act, group, donate_res, res, ct):
     gy, gy2, _, _ = ct
-    return _gbn_bwd_impl(eps, act, group, donate_res, True, res, gy, gy2)
+    return _gbn_bwd_impl(eps, act, group, True, res, gy, gy2)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -1126,7 +1138,7 @@ def _gbn_ref(x, gamma, beta, residual, eps, act, group):
 
 
 def plan_describe(n, c, h, w, itemsize=2, group=0, has_res=False,
-                  donate_res=False, dual=False):
+                  dual=False):
     """One layer's kernel-plan decision as a plain dict — the inspectable
     face of :func:`_plan` (``tools/graftcost.py``'s per-layer table, the
     ``MXTPU_BN_PLAN`` trace log).  ``variant``/``bwd`` name the per-
@@ -1135,7 +1147,7 @@ def plan_describe(n, c, h, w, itemsize=2, group=0, has_res=False,
     lane-fold factor and spatial tile rows where those forms apply;
     ``dual`` marks a dual-cotangent block exit (one extra bwd window)."""
     plan = _plan(int(n), int(c), int(h) * int(w), int(itemsize),
-                 int(group), bool(has_res), bool(donate_res), bool(dual))
+                 int(group), bool(has_res), bool(dual))
     if plan is None:
         return {"variant": "jnp", "bwd": "jnp", "fold": 1, "l_tile": 0,
                 "l_tile_bwd": 0, "window_mb": 0.0, "group": 0,
@@ -1151,21 +1163,41 @@ def plan_describe(n, c, h, w, itemsize=2, group=0, has_res=False,
 
 
 _PLAN_LOGGED = set()
+_SITE_RECORDERS = []
+
+
+@contextlib.contextmanager
+def record_sites():
+    """Collect the distinct BN sites traced inside the ``with`` block, in
+    first-seen order, as ``(shape, dtype_name, group, has_res, donate,
+    dual)`` tuples — what ``chip_smoke.py`` reads to print each site's
+    plan and to check the Pallas ones against the jnp reference."""
+    sites = []
+    _SITE_RECORDERS.append(sites)
+    try:
+        yield sites
+    finally:
+        # by identity: two recorders with equal contents are not the same
+        _SITE_RECORDERS[:] = [s for s in _SITE_RECORDERS if s is not sites]
 
 
 def _log_plan(shape, dtype, group, has_res, donate, dual=False):
     """Once-per-distinct-layer plan trace (MXTPU_BN_PLAN=1): the layer
-    selection is automatic, this makes it visible without a debugger."""
+    selection is automatic, this makes it visible without a debugger.
+    Also feeds :func:`record_sites`."""
+    key = (tuple(shape), np.dtype(dtype).name, int(group), bool(has_res),
+           bool(donate), bool(dual))
+    for sites in _SITE_RECORDERS:
+        if key not in sites:
+            sites.append(key)
     if not os.environ.get("MXTPU_BN_PLAN"):
         return
-    key = (tuple(shape), str(dtype), int(group), bool(has_res),
-           bool(donate), bool(dual))
     if key in _PLAN_LOGGED:
         return
     _PLAN_LOGGED.add(key)
     n, c, h, w = shape
     d = plan_describe(n, c, h, w, np.dtype(dtype).itemsize, group,
-                      has_res, donate, dual)
+                      has_res, dual)
     print("[ghost-bn] %dx%dx%dx%d %s group<=%d res=%d donate=%d dual=%d "
           "-> fwd=%s bwd=%s fold=%d l_tile=%d/%d window=%.1fMB group=%d"
           % (n, c, h, w, np.dtype(dtype).name, int(group), bool(has_res),
@@ -1187,8 +1219,8 @@ def ghost_bn_act(x, gamma, beta, residual=None, eps=1e-3, act="relu",
     ``donate_residual=True`` declares the residual tensor dead after
     this layer (the downsample-shortcut case — NEVER an identity
     shortcut, which the surrounding program still reads): the fwd
-    kernel then writes Y over the residual's window, saving one VMEM
-    window and letting larger exits fuse.  ``dual_out=True`` (residual
+    kernel then writes Y over the residual's HBM buffer (its VMEM
+    window stays, so the plan is the same).  ``dual_out=True`` (residual
     block exits feeding both the next block's conv path and its
     shortcut) returns ``(y, y, group_mean, group_var)`` — the same
     tensor in two output positions, so autodiff delivers the two
@@ -1207,7 +1239,7 @@ def ghost_bn_act(x, gamma, beta, residual=None, eps=1e-3, act="relu",
     _log_plan(x.shape, x.dtype, int(group), residual is not None, donate,
               dual)
     if _plan(n, c, h * w, x.dtype.itemsize, int(group),
-             residual is not None, donate, dual) is None:
+             residual is not None, dual) is None:
         y, m, v = _gbn_ref(x, gamma, beta, residual, float(eps), act,
                            int(group))
         return (y, y, m, v) if dual else (y, m, v)

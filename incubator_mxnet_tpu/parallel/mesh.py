@@ -19,12 +19,8 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-try:  # jax >= 0.5 exports it at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
 
 __all__ = ["Mesh", "NamedSharding", "PartitionSpec", "P", "make_mesh",
            "replicated", "shard_along", "current_devices", "shard_map",

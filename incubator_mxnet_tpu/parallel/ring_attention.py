@@ -143,18 +143,10 @@ def sharded_self_attention(q, k, v, mesh: Mesh, seq_axis="sp", causal=False,
     fn = ring_attention if impl == "ring" else ulysses_attention
     spec = P(None, None, seq_axis, None)
     # pallas_call (flash kernel in the ulysses path) doesn't carry
-    # varying-mesh-axis metadata; skip the replication/vma check
-    # (named check_vma on jax >= 0.6, check_rep on 0.4.x)
-    try:
-        mapped = shard_map(
-            functools.partial(fn, axis_name=seq_axis, causal=causal,
-                              scale=scale),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False)
-    except TypeError:
-        mapped = shard_map(
-            functools.partial(fn, axis_name=seq_axis, causal=causal,
-                              scale=scale),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False)
+    # varying-mesh-axis metadata; skip the vma check
+    mapped = shard_map(
+        functools.partial(fn, axis_name=seq_axis, causal=causal,
+                          scale=scale),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
     return jax.jit(mapped)(q, k, v)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import _backend
 from .ndarray import NDArray
 
 __all__ = ["PallasModule", "CudaModule"]
@@ -44,10 +45,7 @@ class _Kernel:
         pallas launch geometry (the reference's grid_dims/block_dims).
         """
         if interpret is None:
-            try:
-                interpret = jax.default_backend() != "tpu"
-            except Exception:
-                interpret = True
+            interpret = _backend.pallas_interpret()
 
         def norm_shape(s):
             if isinstance(s, jax.ShapeDtypeStruct):

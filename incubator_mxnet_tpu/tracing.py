@@ -41,17 +41,11 @@ def _pop_hooks() -> List[Any]:
 
 
 def _dynamic_trace():
-    """The jax trace active right now (stackless tracing machinery,
-    jax >= 0.4.36); None when undeterminable.  Recorded per aux-effect
+    """The jax trace active right now.  Recorded per aux-effect
     registration so graftlint can tell 'registered in the trace that
     will consume it' from 'registered in an inner region that already
     finalized' (GL004)."""
-    try:
-        from jax._src import core as _c
-
-        return _c.trace_ctx.trace
-    except Exception:
-        return None
+    return jax.core.trace_ctx.trace
 
 
 class TraceContext:

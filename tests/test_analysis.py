@@ -92,7 +92,7 @@ def test_gl001_eager_collectives_validation():
         def body(xb):
             return ppermute(xb, "pp", [(0, 7)])
         return shard_map(body, mesh=mesh, in_specs=(P("pp"),),
-                         out_specs=P("pp"), check_rep=False)(x)
+                         out_specs=P("pp"), check_vma=False)(x)
 
     with pytest.raises(ValueError, match="out of range"):
         jax.make_jaxpr(oob)(jnp.ones(8))
@@ -133,7 +133,7 @@ def test_gl002_stacked_operand_hazard_minimal_repro():
         def body(s, xb):
             return xb + s[0].sum()
         return shard_map(body, mesh=mesh, in_specs=(P("pp"), P()),
-                         out_specs=P(), check_rep=False)(stacked, x)
+                         out_specs=P(), check_vma=False)(stacked, x)
 
     ps = [jnp.ones((3,)) for _ in range(4)]
     report = lint_traceable(hazard, (*ps, jnp.ones(8)))
@@ -156,7 +156,7 @@ def test_gl002_production_workaround_is_clean():
             return xb + lax.dynamic_index_in_dim(
                 s, i, keepdims=False).sum()
         return shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                         out_specs=P(), check_rep=False)(stacked, x)
+                         out_specs=P(), check_vma=False)(stacked, x)
 
     ps = [jnp.ones((3,)) for _ in range(4)]
     report = lint_traceable(clean, (*ps, jnp.ones(8)))
@@ -451,7 +451,7 @@ def test_eager_reduce_scatter_divisibility():
         def body(xb):
             return reduce_scatter(xb, "pp", scatter_dimension=0)
         return shard_map(body, mesh=mesh, in_specs=(P(),),
-                         out_specs=P("pp"), check_rep=False)(x)
+                         out_specs=P("pp"), check_vma=False)(x)
 
     with pytest.raises(ValueError, match=r"reduce_scatter over axis 'pp' "
                                          r"\(size 4\).*size 6.*not divide"):
@@ -461,7 +461,7 @@ def test_eager_reduce_scatter_divisibility():
         def body(xb):
             return reduce_scatter(xb, "pp", scatter_dimension=2)
         return shard_map(body, mesh=mesh, in_specs=(P(),),
-                         out_specs=P("pp"), check_rep=False)(x)
+                         out_specs=P("pp"), check_vma=False)(x)
 
     with pytest.raises(ValueError, match="scatter 2 is out of range"):
         jax.make_jaxpr(bad_dim)(jnp.ones(8))
@@ -476,7 +476,7 @@ def test_eager_allgather_and_alltoall_validation():
         def body(xb):
             return allgather(xb, "pp", axis=3)
         return shard_map(body, mesh=mesh, in_specs=(P("pp"),),
-                         out_specs=P("pp"), check_rep=False)(x)
+                         out_specs=P("pp"), check_vma=False)(x)
 
     with pytest.raises(ValueError, match="allgather over axis 'pp'.*"
                                          "concat 3 is out of range"):
@@ -486,7 +486,7 @@ def test_eager_allgather_and_alltoall_validation():
         def body(xb):
             return alltoall(xb, "pp", split_axis=0, concat_axis=1)
         return shard_map(body, mesh=mesh, in_specs=(P(),),
-                         out_specs=P("pp"), check_rep=False)(x)
+                         out_specs=P("pp"), check_vma=False)(x)
 
     with pytest.raises(ValueError, match=r"alltoall over axis 'pp' "
                                          r"\(size 4\).*split dimension 0 "
@@ -526,7 +526,7 @@ def test_gl006_redundant_allgather_of_replicated_operand():
         def body(xb):
             return lax.all_gather(xb, "dp", axis=0, tiled=True)
         return shard_map(body, mesh=mesh, in_specs=(P(),),
-                         out_specs=P("dp"), check_rep=False)(x)
+                         out_specs=P("dp"), check_vma=False)(x)
 
     report = lint_traceable(redundant, (jnp.ones(4),))
     hits = report.by_code("GL006")
@@ -537,7 +537,7 @@ def test_gl006_redundant_allgather_of_replicated_operand():
         def body(xb):
             return lax.all_gather(xb, "dp", axis=0, tiled=True)
         return shard_map(body, mesh=mesh, in_specs=(P("dp"),),
-                         out_specs=P("dp"), check_rep=False)(x)
+                         out_specs=P("dp"), check_vma=False)(x)
 
     assert not lint_traceable(legitimate, (jnp.ones(4),)).by_code("GL006")
 

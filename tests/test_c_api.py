@@ -4,19 +4,21 @@ would)."""
 import ctypes
 import json
 import os
+import shutil
 
 import numpy as np
 import pytest
 
+from incubator_mxnet_tpu import _native
+
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_SO = os.path.join(_REPO, "src", "native", "libmxtpu_capi.so")
 
 
 @pytest.fixture(scope="module")
 def lib():
-    if not os.path.exists(_SO):
-        pytest.skip("libmxtpu_capi.so not built (cd src/native && make)")
-    lib = ctypes.CDLL(_SO)
+    if shutil.which("make") is None or shutil.which("g++") is None:
+        pytest.skip("no make/g++ to build libmxtpu_capi.so from source")
+    lib = ctypes.CDLL(_native.build("libmxtpu_capi.so"))
     lib.MXGetLastError.restype = ctypes.c_char_p
     return lib
 

@@ -8,8 +8,18 @@ import sys
 
 import pytest
 
+from incubator_mxnet_tpu import _native
+
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _DIR = os.path.join(_REPO, "cpp-package")
+
+
+@pytest.fixture(scope="module")
+def capi_so():
+    """Every binding links libmxtpu_capi.so: build it from source through
+    the one locked builder BEFORE a demo's own make looks for it (other
+    xdist workers build the same file for tests/test_c_api.py)."""
+    return _native.build("libmxtpu_capi.so")
 
 
 @functools.lru_cache(maxsize=1)
@@ -31,7 +41,7 @@ def _cpp_env():
 
 
 @pytest.mark.skipif(shutil.which("g++") is None, reason="no g++")
-def test_cpp_predict_demo_builds_and_serves(tmp_path):
+def test_cpp_predict_demo_builds_and_serves(tmp_path, capi_so):
     env = _cpp_env()
     build = subprocess.run(["make", "predict_demo"], cwd=_DIR, env=env,
                            capture_output=True, text=True, timeout=300)
@@ -55,7 +65,7 @@ def test_cpp_predict_demo_builds_and_serves(tmp_path):
 
 
 @pytest.mark.skipif(shutil.which("g++") is None, reason="no g++")
-def test_cpp_train_demo_learns(tmp_path):
+def test_cpp_train_demo_learns(tmp_path, capi_so):
     """Full TRAINING through the C++ binding package: symbolic MLP built
     with Operator/Symbol, Executor fwd+bwd, Optimizer in-place updates —
     the cpp-package/example/mlp.cpp analog."""
@@ -72,7 +82,7 @@ def test_cpp_train_demo_learns(tmp_path):
 
 
 @pytest.mark.skipif(shutil.which("g++") is None, reason="no g++")
-def test_cpp_custom_op_demo():
+def test_cpp_custom_op_demo(capi_so):
     """A custom operator defined ENTIRELY in C through the
     MXCustomOpRegister struct protocol (c_api.h:3029, custom.cc:70-119):
     prop creator + list/infer/create callbacks + fwd/bwd kernels, driven
@@ -92,17 +102,12 @@ def test_cpp_custom_op_demo():
                     or shutil.which("g++") is None
                     or shutil.which("make") is None,
                     reason="needs perl + g++ + make")
-def test_perl_binding():
+def test_perl_binding(capi_so):
     """L9: the AI::MXNetTPU Perl binding (perl-package/ — the reference's
     AI::MXNet analog at minimal scale): XS CAPI shim + pure-Perl NDArray
     whose operators dispatch through MXImperativeInvokeByName."""
     pdir = os.path.join(_REPO, "perl-package", "AI-MXNetTPU")
     env = _cpp_env()
-    # the binding links libmxtpu_capi.so; build it first (fresh checkout)
-    so = subprocess.run(["make"], cwd=os.path.join(_REPO, "src", "native"),
-                        env=env, capture_output=True, text=True,
-                        timeout=600)
-    assert so.returncode == 0, so.stderr[-2000:]
     cfg = subprocess.run(["perl", "Makefile.PL"], cwd=pdir, env=env,
                          capture_output=True, text=True, timeout=300)
     assert cfg.returncode == 0, cfg.stderr[-2000:]

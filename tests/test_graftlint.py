@@ -365,7 +365,7 @@ def test_cli_gate_over_package_with_select():
                            "--select", "GL101,GL102,GL103"]) == 0
 
 
-def test_gl007_legacy_save_states_from_zero1_fused_trainer():
+def test_gl007_legacy_save_states_from_zero1_fused_trainer(tmp_path):
     """GL007 gate: a zero=1 fused step built from a Trainer warns that
     the legacy save_states path is still reachable (it cannot round-trip
     dp-sharded optimizer state), and the Trainer raises if it IS called
@@ -406,15 +406,15 @@ def test_gl007_legacy_save_states_from_zero1_fused_trainer():
     assert any("GL007" in str(w.message) for w in caught), \
         [str(w.message) for w in caught]
     with pytest.raises(RuntimeError, match="save_checkpoint"):
-        trainer.save_states("/tmp/should_not_exist.states")
+        trainer.save_states(str(tmp_path / "should_not_exist.states"))
     with pytest.raises(RuntimeError, match="restore_checkpoint"):
-        trainer.load_states("/tmp/should_not_exist.states")
+        trainer.load_states(str(tmp_path / "should_not_exist.states"))
     # a plain (zero=0) fused-step Trainer keeps the legacy path
     trainer2 = gluon.Trainer(net.collect_params(), "sgd",
                              {"learning_rate": 0.1})
     trainer2.make_fused_step(net, gluon.loss.SoftmaxCrossEntropyLoss())
-    trainer2.save_states("/tmp/gl007_plain.states")
-    os.unlink("/tmp/gl007_plain.states")
+    trainer2.save_states(str(tmp_path / "gl007_plain.states"))
+    assert os.path.exists(tmp_path / "gl007_plain.states")
 
 
 def test_gl012_unbounded_silent_skip_streak():

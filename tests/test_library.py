@@ -1,27 +1,20 @@
 """Dynamic native custom-op libraries (lib_api.h / MXLoadLib analog)."""
-import os
 import shutil
-import subprocess
 
 import numpy as np
 import pytest
 
 import incubator_mxnet_tpu as mx
-from incubator_mxnet_tpu import nd
+from incubator_mxnet_tpu import _native, nd
 from incubator_mxnet_tpu.ops import registry as reg
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_SO = os.path.join(_REPO, "src", "native", "libsample_custom_op.so")
 
 
 @pytest.fixture(scope="module")
 def loaded():
-    if not os.path.exists(_SO):
-        if shutil.which("make") is None:
-            pytest.skip("sample lib not built and no make")
-        subprocess.run(["make", "libsample_custom_op.so"],
-                       cwd=os.path.dirname(_SO), check=True, timeout=120)
-    return mx.library.load(_SO, verbose=False)
+    if shutil.which("make") is None or shutil.which("g++") is None:
+        pytest.skip("no make/g++ to build the sample lib from source")
+    return mx.library.load(_native.build("libsample_custom_op.so"),
+                           verbose=False)
 
 
 def test_load_registers_ops(loaded):

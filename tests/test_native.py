@@ -289,3 +289,70 @@ def test_engine_sanitizer_harness():
                          timeout=300)
     assert run.returncode == 0, run.stdout + run.stderr[-1500:]
     assert "ENGINE_TEST_OK" in run.stdout
+
+
+# ------------------------------------------------- build on demand (PR 23)
+
+def _no_make(*_args, **_kwargs):
+    raise FileNotFoundError(2, "No such file or directory: 'make'")
+
+
+def test_build_returns_an_up_to_date_library_without_make(monkeypatch):
+    """A checkout that was built once loads its library on a host with no
+    toolchain: ``build`` looks before it makes."""
+    from incubator_mxnet_tpu import _native
+
+    path = _native.build()  # exists from here on
+    monkeypatch.setattr(_native.subprocess, "run", _no_make)
+    assert _native.build() == path
+
+
+def test_build_returns_an_up_to_date_library_from_a_read_only_checkout(
+        monkeypatch):
+    """No lock file can be made in a read-only ``src/native``; nothing can
+    be half-way through a link there either, so a fresh library loads."""
+    from incubator_mxnet_tpu import _native
+
+    path = _native.build()
+
+    def read_only(name, *args, **kwargs):
+        raise PermissionError(13, "Read-only file system", name)
+
+    monkeypatch.setattr(_native, "open", read_only, raising=False)
+    monkeypatch.setattr(_native.subprocess, "run", _no_make)
+    assert _native.build() == path
+    monkeypatch.setattr(_native, "_fresh", lambda path: False)
+    with pytest.raises(PermissionError):
+        _native.build()
+
+
+def test_a_stale_library_is_rebuilt_or_refused_never_loaded(monkeypatch,
+                                                            tmp_path):
+    """A library older than a source beside it is not up to date: with a
+    toolchain it is rebuilt, without one ``build`` raises and ``get_lib``
+    answers None (the pure-Python host runtime) instead of loading it."""
+    import shutil
+
+    from incubator_mxnet_tpu import _native
+
+    src = tmp_path / "native"
+    src.mkdir()
+    for name in os.listdir(_native._SRC_DIR):
+        if name == "Makefile" or name.endswith(".cc"):
+            shutil.copy2(os.path.join(_native._SRC_DIR, name), src / name)
+    monkeypatch.setattr(_native, "_SRC_DIR", str(src))
+    lib = _native.build()
+    assert os.path.dirname(lib) == str(src) and _native._fresh(lib)
+    built = os.path.getmtime(lib)
+    os.utime(src / "engine.cc", (built + 10, built + 10))
+    assert not _native._fresh(lib)
+    assert os.path.getmtime(_native.build()) > built  # make ran
+
+    os.utime(src / "engine.cc", None)  # newer than the rebuilt library
+    os.utime(lib, (built, built))
+    monkeypatch.setattr(_native.subprocess, "run", _no_make)
+    with pytest.raises(OSError):
+        _native.build()
+    monkeypatch.setattr(_native, "_LIB", None)
+    monkeypatch.setattr(_native, "_TRIED", False)
+    assert _native.get_lib() is None

@@ -82,7 +82,7 @@ def test_image_record_iter_normalization(tmp_path):
 
 def test_image_record_iter_throughput(tmp_path):
     """The pipeline must sustain more img/s than the bench's training rate
-    (VERDICT r2 #3 'done' bar) — measured here with tiny 32x32 PNGs on CPU."""
+    (the round-2 review's 'done' bar) — measured here with tiny 32x32 PNGs on CPU."""
     prefix = _write_rec(tmp_path, n=256)
     it = mio.ImageRecordIter(path_imgrec=prefix + ".rec",
                              path_imgidx=prefix + ".idx",

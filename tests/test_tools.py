@@ -9,9 +9,10 @@
 * ``bench.py --schedule-config``: the autotune winner loader fails
   fast (before the ResNet build) on a malformed config.
 * ``tools/graftcost.py --kernel-plans``: the per-layer fused-BN
-  kernel-plan table pins the round-20 selections at the real VMEM
-  budget — lane-fold stem, spatial-tiled 56x56 identity exits, whole-L
-  everywhere else — and accounts for all 53 BN layers of ResNet-50.
+  kernel-plan table pins the selections at the real VMEM budget, every
+  operand's window counted as the chip's compiler counts it — lane-fold
+  stem forward, spatial-tiled 56x56 exits, tiled backward at 28x28,
+  whole-L below — and accounts for all 53 BN layers of ResNet-50.
 """
 import importlib.util
 import json
@@ -32,7 +33,8 @@ def _load_cli(name, path):
 
 
 def test_chip_queue_dry_run(tmp_path):
-    env = dict(os.environ, CHIP_QUEUE_DRY_RUN="1", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, CHIP_QUEUE_DRY_RUN="1", JAX_PLATFORMS="cpu",
+               TMPDIR=str(tmp_path))
     log = tmp_path / "queue.log"
     r = subprocess.run(
         ["bash", os.path.join(ROOT, "tools", "chip_queue.sh"), str(log)],
@@ -45,6 +47,11 @@ def test_chip_queue_dry_run(tmp_path):
     # chip legs were skipped, not silently attempted on CPU
     assert "[dry-run] skip" in out
     assert "== done" in out
+    # its scratch files lived under $TMPDIR, in a directory of the run's
+    # own, and are gone: nothing is shared with another run on the machine
+    assert os.listdir(tmp_path) == ["queue.log"], os.listdir(tmp_path)
+    script = open(os.path.join(ROOT, "tools", "chip_queue.sh")).read()
+    assert "/tmp" not in script
 
 
 def test_bench_schedule_config_rejects_malformed(tmp_path):
@@ -71,17 +78,21 @@ def test_graftcost_kernel_plans_table(capsys):
     stem = layers["stem"]
     assert stem["variant"] == "lanefold" and stem["fold"] == 2
     assert stem["window_mb"] == 25.7  # 51.4 MB whole-L halved
+    # gY, X, dX at 2 x 25.7 MB each do not fit VMEM even folded
+    assert stem["bwd"] == "jnp"
     ex = layers["stage1.exit"]
     assert ex["variant"] == "tiled" and ex["bwd"] == "tiled"
-    assert ex["l_tile"] == 1568 and ex["dual"]
-    # the 56x56 downsample exit fits whole-L fwd (donated residual) but
-    # must tile its backward
-    ds = layers["stage1.exit.ds"]
-    assert ds["variant"] == "fused" and ds["bwd"] == "tiled"
-    # everything from 28x28 down stays whole-L fused
-    for name in ("stage2.exit", "stage3.exit", "stage4.exit",
-                 "stage4.exit.tail"):
-        assert layers[name]["variant"] == "fused", (name, layers[name])
+    assert ex["l_tile"] == 1568 and ex["l_tile_bwd"] == 784 and ex["dual"]
+    # donating the residual saves its HBM buffer, not its VMEM window: the
+    # downsample exit plans like the identity exits and shares their row
+    assert ex["count"] == 3 and "donate" not in ex
+    # 28x28x512 exits: whole-L fwd, the 6-window dual bwd tiles
+    assert layers["stage2.exit"]["variant"] == "fused" \
+        and layers["stage2.exit"]["bwd"] == "tiled"
+    # everything from 14x14 down stays whole-L fused both ways
+    for name in ("stage3.exit", "stage4.exit", "stage4.exit.tail"):
+        assert (layers[name]["variant"], layers[name]["bwd"]) == \
+            ("fused", "fused"), (name, layers[name])
     assert layers["stage4.exit.tail"]["dual"] is False
 
     rc = gc.main(["--model", "resnet50", "--kernel-plans",

@@ -130,31 +130,28 @@ def _resnet50_kernel_plans(batch, itemsize, group):
     with the padded window bytes and fold factor the feasibility check
     charged.  Mirrors the model zoo's dual_out wiring: every residual
     block exit is a dual-cotangent site except the LAST stage's tail
-    block (resnet.py::_make_layer)."""
+    block (resnet.py::_make_layer).  A downsample block's exit donates
+    its residual; that saves an HBM buffer, never a VMEM window, so it
+    plans like the stage's other exits and shares their row."""
     from incubator_mxnet_tpu.parallel.fused_bn import plan_describe
 
-    rows = [("stem", 64, 112, 1, False, False, False)]
+    rows = [("stem", 64, 112, 1, False, False)]
     last = len(_R50_STAGES) - 1
     for i, (bc, ec, hw, k) in enumerate(_R50_STAGES):
         s = "stage%d" % (i + 1)
-        rows.append((s + ".body", bc, hw, 2 * k, False, False, False))
-        rows.append((s + ".shortcut", ec, hw, 1, False, False, False))
-        rows.append((s + ".exit.ds", ec, hw, 1, True, True, True))
+        rows.append((s + ".body", bc, hw, 2 * k, False, False))
+        rows.append((s + ".shortcut", ec, hw, 1, False, False))
         if i == last:
-            if k > 2:
-                rows.append((s + ".exit", ec, hw, k - 2, True, False,
-                             True))
-            rows.append((s + ".exit.tail", ec, hw, 1, True, False,
-                         False))
+            rows.append((s + ".exit", ec, hw, k - 1, True, True))
+            rows.append((s + ".exit.tail", ec, hw, 1, True, False))
         else:
-            rows.append((s + ".exit", ec, hw, k - 1, True, False, True))
+            rows.append((s + ".exit", ec, hw, k, True, True))
     out = []
-    for layer, c, hw, count, res, donate, dual in rows:
-        d = plan_describe(batch, c, hw, hw, itemsize, group, res,
-                          donate, dual)
+    for layer, c, hw, count, res, dual in rows:
+        d = plan_describe(batch, c, hw, hw, itemsize, group, res, dual)
         out.append({"layer": layer, "count": count,
                     "shape": "%dx%dx%dx%d" % (batch, c, hw, hw),
-                    "residual": res, "donate": donate, **d})
+                    "residual": res, **d})
     return out
 
 
@@ -173,8 +170,7 @@ def _print_kernel_plans(plans, batch, itemsize, group, fmt):
 
     def cell(p, h):
         if h == "res":
-            return "res+don" if p["donate"] else \
-                ("res" if p["residual"] else "-")
+            return "res" if p["residual"] else "-"
         if h == "dual":
             return "dual" if p["dual"] else "-"
         return str(p.get(h, "-"))

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""INT8 quantization coverage + ceiling analysis (VERDICT r3 weak #7).
+"""INT8 quantization coverage + ceiling analysis (asked for in the round-3 review).
 
 Quantizes ResNet-50 (the graded int8 config) and accounts, node by node
 over the quantized symbol with inferred shapes:

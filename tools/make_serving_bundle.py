@@ -102,12 +102,13 @@ def main():
     prefix, outdir = sys.argv[1], sys.argv[2]
     default_shape = sys.argv[3] if len(sys.argv) == 4 else "[1, 3, 224, 224]"
     os.makedirs(outdir, exist_ok=True)
-    shutil.copy2(os.path.join(REPO, "src", "native", "libmxtpu_capi.so"),
-                 outdir)
-    for native in ("libmxtpu_native.so", "libsample_custom_op.so"):
-        srcp = os.path.join(REPO, "src", "native", native)
-        if os.path.exists(srcp):
-            shutil.copy2(srcp, outdir)
+    # no binary is committed: each library is built from src/native here
+    sys.path.insert(0, REPO)
+    from incubator_mxnet_tpu import _native
+
+    for native in ("libmxtpu_capi.so", "libmxtpu_native.so",
+                   "libsample_custom_op.so"):
+        shutil.copy2(_native.build(native), outdir)
     shutil.copy2(prefix + "-symbol.json",
                  os.path.join(outdir, "model-symbol.json"))
     shutil.copy2(prefix + "-0000.params",

@@ -141,9 +141,9 @@ def main():
     else:
         import jax
 
-        cache = os.path.join(REPO, ".jax_cache")
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        from incubator_mxnet_tpu import _backend
+
+        _backend.use_compile_cache()
         logdir = os.path.join(REPO, ".profile",
                               time.strftime("%Y%m%d-%H%M%S"))
         os.makedirs(logdir, exist_ok=True)
