@@ -456,7 +456,16 @@ class HybridBlock(Block):
         return self._forward_impl(x, *args)
 
     def _forward_impl(self, x, *args):
-        """Eager forward body (never routes through CachedOp)."""
+        """Forward body (never routes through CachedOp).  Inside a
+        whole-graph trace it runs under ``jax.named_scope("<Class>.<name>")``
+        so the compiled program's op names say which block built each
+        instruction (docs/PROFILING.md); an eager call is not wrapped."""
+        if tracing.current_trace() is None:
+            return self._forward_body(x, *args)
+        with jax.named_scope("%s.%s" % (type(self).__name__, self.name)):
+            return self._forward_body(x, *args)
+
+    def _forward_body(self, x, *args):
         from .. import ndarray as F  # noqa: N812
 
         try:

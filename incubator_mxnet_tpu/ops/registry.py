@@ -239,7 +239,8 @@ def invoke_raw(op: Op, arrays: Sequence[Any], **attrs):
         from .. import rng
 
         attrs["key"] = rng.next_key()
-    return op.fn(*_amp_cast_inputs(op, list(arrays), attrs), **attrs)
+    with jax.named_scope("op." + op.name):
+        return op.fn(*_amp_cast_inputs(op, list(arrays), attrs), **attrs)
 
 
 def invoke(name: str, inputs: Sequence[Any], out=None, **attrs):
@@ -251,12 +252,8 @@ def invoke(name: str, inputs: Sequence[Any], out=None, **attrs):
     from .. import profiler
 
     if profiler.is_running():
-        import time
-        t0 = time.monotonic()
-        try:
+        with profiler.Operator(name):
             return _invoke_impl(name, inputs, out, **attrs)
-        finally:
-            profiler.record_op(name, (time.monotonic() - t0) * 1e6)
     return _invoke_impl(name, inputs, out, **attrs)
 
 
@@ -326,24 +323,28 @@ def _invoke_impl(name: str, inputs: Sequence[Any], out=None, **attrs):
         and any(autograd.requires_grad(i) for i in inputs if isinstance(i, NDArray))
     )
     jfn = _eager_fn(op, attrs)
+    if jfn is None:
+        # the body is traced into an outer program (or cannot be cached):
+        # name it there, so the compiled program's op names say which op
+        # built each instruction (docs/PROFILING.md)
+        def jfn(*a):
+            with jax.named_scope("op." + op.name):
+                return op.fn(*a, **attrs)
 
     if recording:
         # differentiate only wrt non-None tensor inputs
         live = [j for j, d in enumerate(datas) if d is not None]
-        body = (lambda *a: jfn(*a)) if jfn is not None \
-            else (lambda *a: op.fn(*a, **attrs))
 
         def fn(*xs, _datas=tuple(datas), _live=tuple(live)):
             full = list(_datas)
             for j, x in zip(_live, xs):
                 full[j] = x
-            return body(*full)
+            return jfn(*full)
 
         out_datas, vjp_fn = jax.vjp(fn, *[datas[j] for j in live])
         live_inputs = [inputs[j] for j in live]
     else:
-        out_datas = jfn(*datas) if jfn is not None \
-            else op.fn(*datas, **attrs)
+        out_datas = jfn(*datas)
 
     multi = isinstance(out_datas, (tuple, list))
     outs_list = list(out_datas) if multi else [out_datas]

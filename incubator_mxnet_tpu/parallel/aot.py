@@ -395,9 +395,9 @@ def compile_timed(traced, t_trace: float = 0.0, *,
     never share an executable while the SAME schedule cross-process
     hits at zero XLA compiles.
     """
-    t0 = time.time()
+    t0 = time.perf_counter()
     lowered = traced.lower()
-    t_trace = t_trace + (time.time() - t0)
+    t_trace = t_trace + (time.perf_counter() - t0)
     if cache is None:
         cache = default_compile_cache()
     times: Dict[str, Any] = {"trace": t_trace}
@@ -410,10 +410,10 @@ def compile_timed(traced, t_trace: float = 0.0, *,
             times["cache"] = "hit"
             times["compile"] = 0.0
             return hit, times
-    t0 = time.time()
+    t0 = time.perf_counter()
     compiled = lowered.compile()
     XLA_COMPILES.bump()
-    times["compile"] = time.time() - t0
+    times["compile"] = time.perf_counter() - t0
     if cache is not None:
         times["cache"] = "stored" if cache.store(key, compiled) \
             else "store-failed"

@@ -23,7 +23,18 @@ from ..ops import optimizer_ops as _oops
 from .pipeline import shard_map, spmd_pipeline
 
 __all__ = ["DynamicLossScale", "FunctionalOptimizer", "make_train_step",
-           "TrainStep"]
+           "TrainStep", "STEP_SCOPES"]
+
+#: The ``jax.named_scope`` names of the step program's phases, as they show
+#: in the op names of the compiled program (docs/PROFILING.md).  ``step.cast``
+#: nests in ``step.forward`` and ``step.update.zero`` in ``step.update``; JAX
+#: itself wraps ``step.forward`` as ``jvp(step.forward)`` on the forward pass
+#: and ``transpose(jvp(step.forward))`` on the backward pass.  Readers of the
+#: device trace (``perfbench/readers/scope_op.py``) rely on these names.
+STEP_SCOPES = ("step.forward", "step.cast", "step.guard", "step.unscale",
+               "step.update", "step.update.zero", "step.select")
+(_FORWARD, _CAST, _GUARD, _UNSCALE, _UPDATE, _UPDATE_ZERO,
+ _SELECT) = STEP_SCOPES
 
 
 class DynamicLossScale:
@@ -589,45 +600,48 @@ class TrainStep:
             # finiteness is checked on the RAW (still scaled) grads:
             # that is where fp16 overflow appears, and unscaling an inf
             # cannot rescue it anyway
-            ok = _oops.tree_all_finite(grads)
+            with jax.named_scope(_GUARD):
+                ok = _oops.tree_all_finite(grads)
         else:
             ok = jnp.array(True)
         if scaling:
-            # powers-of-two scales make the multiply exact; compute in
-            # the wider of (grad dtype, f32) so f16/bf16 grads unscale
-            # in f32 while f64 grads keep their full mantissa
-            inv = (1.0 / scale).astype(jnp.float32)
-
             def unscale(g):
                 ct = jnp.promote_types(g.dtype, jnp.float32)
                 return (g.astype(ct) * inv.astype(ct)).astype(g.dtype)
 
-            grads = [unscale(g) for g in grads]
-            loss_val = loss_val * inv
+            with jax.named_scope(_UNSCALE):
+                # powers-of-two scales make the multiply exact; compute in
+                # the wider of (grad dtype, f32) so f16/bf16 grads unscale
+                # in f32 while f64 grads keep their full mantissa
+                inv = (1.0 / scale).astype(jnp.float32)
+                grads = [unscale(g) for g in grads]
+                loss_val = loss_val * inv
         c1 = step_count + 1
-        new_p, new_s = self._apply_update(p_vals, grads, opt_state, c1)
+        with jax.named_scope(_UPDATE):
+            new_p, new_s = self._apply_update(p_vals, grads, opt_state, c1)
         if guard:
             def sel(n, o):
                 return jnp.where(ok, n, o)
 
-            new_p = [sel(n, o) for n, o in zip(new_p, p_vals)]
-            new_aux = [sel(n, o) for n, o in zip(new_aux, aux_vals)]
-            new_s = jax.tree.map(sel, new_s, opt_state)
-            c1 = sel(c1, step_count)
-            skipped = skipped + jnp.where(ok, jnp.int32(0), jnp.int32(1))
-            if self._dynamic_scale:
-                cfg = self._scale_cfg
-                unsk = jnp.where(ok, unskipped + 1, jnp.int32(0))
-                grow = unsk >= cfg.scale_window
-                scale = jnp.where(
-                    ok,
-                    jnp.where(grow,
-                              jnp.minimum(scale * cfg.scale_factor,
-                                          cfg.max_loss_scale),
-                              scale),
-                    jnp.maximum(scale / cfg.scale_factor,
-                                cfg.min_loss_scale)).astype(jnp.float32)
-                unskipped = jnp.where(grow, jnp.int32(0), unsk)
+            with jax.named_scope(_SELECT):
+                new_p = [sel(n, o) for n, o in zip(new_p, p_vals)]
+                new_aux = [sel(n, o) for n, o in zip(new_aux, aux_vals)]
+                new_s = jax.tree.map(sel, new_s, opt_state)
+                c1 = sel(c1, step_count)
+                skipped = skipped + jnp.where(ok, jnp.int32(0), jnp.int32(1))
+                if self._dynamic_scale:
+                    cfg = self._scale_cfg
+                    unsk = jnp.where(ok, unskipped + 1, jnp.int32(0))
+                    grow = unsk >= cfg.scale_window
+                    scale = jnp.where(
+                        ok,
+                        jnp.where(grow,
+                                  jnp.minimum(scale * cfg.scale_factor,
+                                              cfg.max_loss_scale),
+                                  scale),
+                        jnp.maximum(scale / cfg.scale_factor,
+                                    cfg.min_loss_scale)).astype(jnp.float32)
+                    unskipped = jnp.where(grow, jnp.int32(0), unsk)
         return (loss_val, new_p, list(new_aux), new_s, key, c1,
                 (scale, unskipped, skipped), ok)
 
@@ -697,25 +711,30 @@ class TrainStep:
         z_pad = [pad0s[i] for i in z_idx]
         shard_spec = P(ax)
 
+        # the slices and the all-gather carry their own scope, so a trace
+        # tells the sharding's cost from the optimizer's arithmetic
         def body(zp, zg, zs, c):
-            idx = jax.lax.axis_index(ax)
+            with jax.named_scope(_UPDATE_ZERO):
+                idx = jax.lax.axis_index(ax)
             out_p, out_s = [], []
             for k, (p, g) in enumerate(zip(zp, zg)):
                 pad0 = z_pad[k]
                 rows = pad0 // n
-                p_pad = self._zero_padded(p, pad0)
-                g_pad = self._zero_padded(g, pad0)
-                g_shard = jax.lax.dynamic_slice_in_dim(
-                    g_pad, idx * rows, rows, 0)
-                p_shard = jax.lax.dynamic_slice_in_dim(
-                    p_pad, idx * rows, rows, 0)
+                with jax.named_scope(_UPDATE_ZERO):
+                    p_pad = self._zero_padded(p, pad0)
+                    g_pad = self._zero_padded(g, pad0)
+                    g_shard = jax.lax.dynamic_slice_in_dim(
+                        g_pad, idx * rows, rows, 0)
+                    p_shard = jax.lax.dynamic_slice_in_dim(
+                        p_pad, idx * rows, rows, 0)
                 s_k = zs[k] if opt.has_state else None
                 w_shard, s_new = opt.apply_single(p_shard, g_shard, s_k, c)
-                w_full = collectives.allgather(w_shard, ax, axis=0,
-                                               tiled=True)
-                if pad0 != p.shape[0]:
-                    w_full = jax.lax.slice_in_dim(w_full, 0, p.shape[0],
-                                                  axis=0)
+                with jax.named_scope(_UPDATE_ZERO):
+                    w_full = collectives.allgather(w_shard, ax, axis=0,
+                                                   tiled=True)
+                    if pad0 != p.shape[0]:
+                        w_full = jax.lax.slice_in_dim(w_full, 0, p.shape[0],
+                                                      axis=0)
                 out_p.append(w_full)
                 out_s.append(s_new)
             if opt.has_state:
@@ -797,6 +816,7 @@ class TrainStep:
         self._stage0_blocks = groups[0]
         self._stage0_gp = first
 
+    @jax.named_scope(_CAST)
     def _cast_inputs(self, pv, x):
         """Shared dtype policy: params re-cast to the compute dtype;
         unsigned-int inputs are raw image bytes (ImageRecordUInt8Iter) —
@@ -825,6 +845,7 @@ class TrainStep:
         gp_list, aux_list = self._gp, self._aux
         net, loss_fn = self.net, self.loss_fn
 
+        @jax.named_scope(_FORWARD)
         def loss_of(pv):
             pv_c, x_c = self._cast_inputs(pv, x)
             tc = tracing.TraceContext(use_key, training=True)
@@ -941,6 +962,7 @@ class TrainStep:
         def step(p_vals, aux_vals, opt_state, x, y, key, step_count, scaler):
             key, use_key = jax.random.split(key)
 
+            @jax.named_scope(_FORWARD)
             def loss_of(pv):
                 pv_c, x_c = self._cast_inputs(pv, x)
                 if x_c.shape[0] % num_micro:
@@ -1803,7 +1825,7 @@ class TrainStep:
         # compile split below stays honest (the jaxpr walk is ms-scale)
         from .aot import compile_timed
 
-        t0 = _time.time()
+        t0 = _time.perf_counter()
         self._maybe_apply_passes((p_vals, aux_vals, self._opt_state, xv,
                                   yv, self._key_dev, self._step_dev,
                                   self._scaler_dev))
@@ -1811,7 +1833,7 @@ class TrainStep:
                                   (p_vals, aux_vals, self._opt_state, xv,
                                    yv, self._key_dev, self._step_dev,
                                    self._scaler_dev))
-        compiled, times = compile_timed(traced, t_trace=_time.time() - t0,
+        compiled, times = compile_timed(traced, t_trace=_time.perf_counter() - t0,
                                         cache=cache,
                                         cache_extra=self._cache_extra())
         self._compiled = compiled
@@ -2069,6 +2091,17 @@ class TrainStep:
         return NDArray(loss)
 
     def __call__(self, x, y):
+        from .. import profiler
+
+        if not profiler.is_running():
+            return self._step_once(x, y)
+        # one "mx.train_step" span a call on the host plane of the trace
+        # mx.profiler records, beside the device ops it dispatched
+        with jax.profiler.StepTraceAnnotation("mx.train_step",
+                                              step_num=self._step_count):
+            return self._step_once(x, y)
+
+    def _step_once(self, x, y):
         if self._applied_sync == "async":
             return self._async_call(x, y)
         self._ensure_built()

@@ -2,13 +2,21 @@
 python/mxnet/profiler.py:33-404; core src/profiler/profiler.h:251).
 
 Events are collected in-process and dumped as Chrome tracing JSON
-(``chrome://tracing`` / Perfetto), like the reference's ``DumpProfile``.
-On TPU the heavy lifting lives inside XLA programs, so two sources exist:
+(``chrome://tracing`` / Perfetto), like the reference's ``DumpProfile``:
+op dispatch, user scopes (Task/Frame/Event/Counter) and markers, on this
+module's own monotonic clock.
 
-- framework events: op dispatch, user scopes (Task/Frame/Event/Counter),
-  C-API-style markers — recorded here;
-- device timeline: bridged to ``jax.profiler`` (XPlane/TensorBoard) when
-  ``profile_device=True`` — start/stop a jax trace alongside.
+On TPU the heavy lifting lives inside XLA programs, which only the device
+trace sees.  ``set_config(profile_device=True)`` (or ``profile_all``) makes
+``set_state("run")`` start a ``jax.profiler`` trace into ``jax_trace_dir``
+(default ``<filename without extension>_xplane``) and raise if it cannot.
+While that trace runs, every Task/Frame/Event, Marker and op-dispatch event
+is ALSO written into it as a ``jax.profiler.TraceAnnotation`` of the same
+name, and every ``TrainStep`` call as a step ``mx.train_step``: they land on
+the ``/host:CPU`` plane of the one ``*.xplane.pb``, on the clock of the
+device ops they caused.  How to take such a trace and read the op names of
+the compiled step (``step.*``, ``<Class>.<name>``, ``op.<name>``) is in
+``docs/PROFILING.md``.
 """
 from __future__ import annotations
 
@@ -69,26 +77,23 @@ profiler_set_config = set_config
 
 
 def set_state(state="stop"):
-    """'run' | 'stop' (profiler.py:89)."""
+    """'run' | 'stop' (profiler.py:89).  With ``profile_device`` set, 'run'
+    raises what ``jax.profiler.start_trace`` raises (another trace is
+    running, the directory cannot be written) and leaves the profiler
+    stopped: whoever asked for a device profile is told there is none."""
     if state == "run":
+        if _state["profile_device"] and not _state["jax_tracing"]:
+            import jax
+            jax.profiler.start_trace(_state["jax_trace_dir"])
+            _state["jax_tracing"] = True
         _state["running"] = True
         _state["paused"] = False
-        if _state["profile_device"] and not _state["jax_tracing"]:
-            try:
-                import jax
-                jax.profiler.start_trace(_state["jax_trace_dir"])
-                _state["jax_tracing"] = True
-            except Exception:
-                pass
     elif state == "stop":
         _state["running"] = False
         if _state["jax_tracing"]:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+            import jax
             _state["jax_tracing"] = False
+            jax.profiler.stop_trace()
     else:
         raise ValueError("state must be 'run' or 'stop'")
 
@@ -138,19 +143,35 @@ def dump(finished=True, profile_process="worker"):
 # user scopes (profiler.py:284-404)
 # ---------------------------------------------------------------------------
 
+def _annotate(name):
+    """An entered ``jax.profiler.TraceAnnotation``, or None unless this
+    profiler's own jax trace is recording: the span on the device's clock."""
+    if not _state["jax_tracing"] or _state["paused"]:
+        return None
+    import jax
+    annotation = jax.profiler.TraceAnnotation(name)
+    annotation.__enter__()
+    return annotation
+
+
 class _Scope:
     _cat = "user"
 
     def __init__(self, name):
         self.name = name
         self._start: Optional[float] = None
+        self._annotation = None
 
     def start(self):
         self._start = _now_us()
+        self._annotation = _annotate(self.name)
 
     def stop(self):
         if self._start is None:
             return
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         _emit("X", self.name, self._cat, ts=self._start,
               dur=_now_us() - self._start)
         self._start = None
@@ -181,6 +202,12 @@ class Frame(_Scope):
 
 class Event(_Scope):
     _cat = "event"
+
+
+class Operator(_Scope):
+    """Framework op-dispatch event, opened by ``ops.registry.invoke`` (the
+    engine's ProfileOperator analog — threaded_engine.h:354)."""
+    _cat = "operator"
 
 
 class Domain:
@@ -229,13 +256,9 @@ class Marker:
 
     def mark(self, scope="process"):
         _emit("i", self.name, "marker")
-
-
-def record_op(name, dur_us, args=None):
-    """Internal hook: framework op-dispatch event (the engine's
-    ProfileOperator analog — threaded_engine.h:354)."""
-    _emit("X", name, "operator", ts=_now_us() - dur_us, dur=dur_us,
-          args=args)
+        annotation = _annotate(self.name)
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
 
 
 def is_running():
