@@ -1114,12 +1114,18 @@ class SpaceToDepthPass(GraftPass):
 # ---------------------------------------------------------------------------
 
 class MaxPoolBwdMaskPass(GraftPass):
-    """Replace ``select_and_scatter_add`` — XLA's max-pool backward,
-    a slow scatter pass on TPU (1.5 ms/step in the ResNet-50 profile,
-    docs/PERF.md lever c) — with the shifted-window mask form: one
-    strided view per in-window offset, the winner being the FIRST
-    argmax in row-major window scan order, the gradient routed to it
-    by a fused elementwise select/pad chain.
+    """Replace ``select_and_scatter_add`` — XLA's max-pool backward —
+    with the shifted-window mask form: one strided view per in-window
+    offset, the winner being the FIRST argmax in row-major window scan
+    order, the gradient routed to it by an elementwise select/pad chain.
+
+    **On the v5e this is the SLOWER form** (PERF.md section 6, PR 28:
+    15.9 ms against 1.48 ms for ResNet-50's stem pool, 23.5 ms against
+    3.97 ms for VGG-16's five), and ``op.Pooling`` no longer builds it:
+    applied to a zoo net the pass now turns the faster backward into the
+    slower one, and its own cost receipt refuses it there (GL303, more
+    HBM traffic).  No cell and no default step runs it; ROADMAP 1.4
+    leaves its deletion to a ``simplicity`` issue.
 
     First-argmax is exactly ``select_and_scatter_add``'s GE-select tie
     rule (and the reference's pool.h unpool semantics), so the rewrite
@@ -1135,11 +1141,9 @@ class MaxPoolBwdMaskPass(GraftPass):
     XLA dedup it), so the bwd costs reads of (X, out, gY) and the dX
     write — no scatter, no padded operand materialization.
 
-    The model-zoo path (``ops.nn._maxpool_sws``) already builds this
-    form in the model; this pass retrofits the same rewrite onto ANY
-    traced program that still carries the scatter (raw
-    ``lax.reduce_window`` code, imported graphs), with the PR-12
-    contract machinery vouching for it.
+    The rewrite applies to ANY traced program that carries the scatter
+    (``op.Pooling``, raw ``lax.reduce_window`` code, imported graphs),
+    with the PR-12 contract machinery vouching for it.
     """
 
     name = "maxpool_bwd_mask"

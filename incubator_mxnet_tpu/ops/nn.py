@@ -13,7 +13,6 @@ re-layouts internally for the TPU's native tiling.
 """
 from __future__ import annotations
 
-import functools
 import itertools
 
 import jax
@@ -128,29 +127,13 @@ def _deconvolution(data, weight, bias=None, kernel=None, stride=None, dilate=Non
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _maxpool_sws(data, window, strides, padding):
-    return lax.reduce_window(data, -jnp.inf, lax.max, window, strides, padding)
-
-
-def _maxpool_sws_fwd(data, window, strides, padding):
-    # No Pallas form: an argmax-carrying forward kernel (round 20) never
-    # compiled for the chip.  On NCHW blocks W rides the lanes, and the
-    # v5e compiler refused every way to stride it: the in-register
-    # slice ("'vector.extract_strided_slice' op expected strides to be
-    # confined to [1, 2)"), the strided ref load of bf16 ("Strided load
-    # with non 32-bit data") and of f32 ("Stride on last dim is not
-    # 1").  A kernel over the (H, W, C, N) view would stride major dims
-    # only; until one exists the backward recomputes the winner below.
-    out = _maxpool_sws(data, window, strides, padding)
-    return out, (data, out)
-
-
 def shifted_window_unpool(data, out, g, window, strides, padding,
                           _shift_mask=0):
     """Shifted-window mask max-pool backward: route ``g`` to the FIRST
-    argmax of each window (row-major scan order) with a handful of
-    fused elementwise passes instead of XLA's ``select-and-scatter``.
+    argmax of each window (row-major scan order) with elementwise passes
+    instead of XLA's ``select-and-scatter``.  Only the
+    ``maxpool_bwd_mask`` graftpass (analysis/passes.py) builds it;
+    ``op.Pooling`` does not (see ``_pooling``).
 
     One shifted strided view of the padded input per in-window offset:
     position p of the padded input contributes to window w iff
@@ -160,8 +143,8 @@ def shifted_window_unpool(data, out, g, window, strides, padding,
     also ``select_and_scatter_add``'s GE-select tie rule, so the result
     is BIT-exact vs XLA's own gradient (post-ReLU zero ties are common;
     giving every tie the full gradient would inflate dX by the tie
-    count).  Shared by the model-level ``_maxpool_sws`` custom VJP and
-    the ``maxpool_bwd_mask`` graftpass (analysis/passes.py).
+    count).  The price is one ``lax.pad`` of the input's shape per
+    offset, which the v5e runs at an eighth of its HBM rate.
 
     ``_shift_mask`` is a test-only fault knob: a non-zero value offsets
     the winner index, deliberately mis-routing the gradient — the
@@ -193,14 +176,6 @@ def shifted_window_unpool(data, out, g, window, strides, padding,
     dx = lax.slice(dxp, [lo for lo, _ in padding],
                    [d - hi for d, (_, hi) in zip(xp.shape, padding)])
     return dx.astype(data.dtype)
-
-
-def _maxpool_sws_bwd(window, strides, padding, res, g):
-    data, out = res
-    return (shifted_window_unpool(data, out, g, window, strides, padding),)
-
-
-_maxpool_sws.defvjp(_maxpool_sws_fwd, _maxpool_sws_bwd)
 
 
 @register("Pooling", aliases=("pool",))
@@ -236,16 +211,15 @@ def _pooling(data, kernel=None, pool_type="max", global_pool=False,
     if pool_type == "max":
         # init must carry the operand dtype (an int-typed pool — e.g. the
         # int8 inference path — rejects a python-int/int64 init)
-        if jnp.issubdtype(data.dtype, jnp.floating):
-            # custom VJP: XLA's autodiff of reduce_window-max is
-            # select-and-scatter, which is slow on TPU (1.5 ms/step in the
-            # ResNet-50 profile, docs/PERF.md).  The shifted-window mask
-            # backward is a handful of fused elementwise passes and
-            # matches the reference's active unpool semantics (pool.h
-            # unpool_max_*_cpu: the whole gradient goes to the first
-            # argmax in window scan order, not to every tie).
-            return _maxpool_sws(data, window, strides, tuple(padding))
-        init = np.asarray(jnp.iinfo(data.dtype).min, data.dtype)[()]
+        init = (-jnp.inf if jnp.issubdtype(data.dtype, jnp.floating)
+                else np.asarray(jnp.iinfo(data.dtype).min, data.dtype)[()])
+        # The backward is reduce_window's own transpose, ONE
+        # select-and-scatter a pool: the whole gradient of a window goes to
+        # its first maximum in scan order (pool.h unpool_max_*), and dx is
+        # written once.  Do not replace it with masks and pads
+        # (shifted_window_unpool): on the v5e that took 15.9 ms for
+        # ResNet-50's stem pool and 23.5 ms for VGG-16's five where this
+        # takes 1.48 and 3.97 ms (PERF.md section 6, PR 28).
         return lax.reduce_window(data, init, lax.max, window, strides, padding)
     if pool_type in ("avg", "sum"):
         summed = lax.reduce_window(data, 0.0 if jnp.issubdtype(

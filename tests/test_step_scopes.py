@@ -99,8 +99,9 @@ def test_the_max_pool_backward_sits_under_the_pooling_scope(one_chip):
     under = ("transpose(jvp(step.forward))/HybridSequential.%s/MaxPool2D.%s/"
              "op.Pooling/" % (net.name, net[3].name))
     built = {n.split(";")[0].rsplit("/", 1)[-1] for n in names if under in n}
-    # ops/nn.py::shifted_window_unpool: one pad per in-window offset
-    assert "pad" in built, built
+    # reduce_window's own transpose: one select-and-scatter a pool, and no
+    # pad of the pool input's shape per in-window offset
+    assert "select_and_scatter" in built and "pad" not in built, built
 
 
 def test_the_zero_all_gather_sits_under_step_update_zero(dp4_zero1):
