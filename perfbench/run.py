@@ -8,9 +8,11 @@ configuration, its traffic file and its layer metrics by name, hands them to
 the runner the traffic file names (``perfbench/runners/<runner>.py``) and
 prints, as the last line of stdout, one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
-with ``--trace 1`` its per-layer metrics), ``device`` and, traced,
-``breakdown``.  Without the chips the cell asks for it exits non-zero and
-prints no such line: there is no CPU continuation.
+with ``--trace 1`` its per-layer metrics), ``device``, traced ``breakdown``,
+and last ``compared``: every number ``correct`` was decided from as
+``[value, limit]``, which are also the last lines of stderr.  Without the
+chips the cell asks for it exits non-zero and prints no such line: there is
+no CPU continuation.
 """
 import time
 
@@ -19,6 +21,7 @@ T_START = time.monotonic()  # set-up is counted from here
 import argparse
 import importlib
 import json
+import math
 import os
 import sys
 
@@ -94,13 +97,18 @@ def result_line(cell: dict, facts: dict, traced: bool) -> dict:
         device["window_s"] = trace["window_s"]
         line["breakdown"] = {"device_ops": trace["device_ops"],
                              "idle_gaps": trace["idle_gaps"]}
+    # what ``correct`` compared, each number beside its limit; last in the
+    # line, and a number that is not finite as a string, so the line is JSON
+    line["compared"] = {
+        name: [v if math.isfinite(v) else repr(v) for v in pair]
+        for name, pair in facts.get("compared", {}).items()}
     return line
 
 
-def run_cell(root, name, platform, seed, seconds, traced, t_start,
-             counter=None):
-    """Load the cell, run its runner, build the line.  ``platform`` is what
-    the devices must be; only ``main()`` insists on ``tpu``."""
+def cell_facts(root, name, platform, seed, seconds, traced, t_start,
+               counter=None):
+    """Load the cell and run its runner: ``(cell, facts)``.  ``platform`` is
+    what the devices must be; only ``main()`` insists on ``tpu``."""
     import jax
 
     cell = load_cell(root, name)
@@ -122,6 +130,14 @@ def run_cell(root, name, platform, seed, seconds, traced, t_start,
     facts["peaks"] = cell["peaks"]
     for problem in facts["problems"]:
         print("NOT CORRECT: " + problem, flush=True)
+    return cell, facts
+
+
+def run_cell(root, name, platform, seed, seconds, traced, t_start,
+             counter=None):
+    """One run of the cell: the contract's last line."""
+    cell, facts = cell_facts(root, name, platform, seed, seconds, traced,
+                             t_start, counter)
     return result_line(cell, facts, traced)
 
 
@@ -137,11 +153,18 @@ def main(argv=None) -> int:
     if seconds is None:
         seconds = _json(os.path.join(ROOT, "BENCHMARK.json"))["run_seconds"]
     try:
-        line = run_cell(ROOT, args.workload, "tpu", args.seed, seconds,
-                        bool(args.trace), T_START)
+        # not through run_cell(): one Python frame more under the runner
+        # moves trace_s.train by half a second on the chip's host (PERF.md
+        # section 6, PR 29), so main() keeps the depth it always had
+        cell, facts = cell_facts(ROOT, args.workload, "tpu", args.seed,
+                                 seconds, bool(args.trace), T_START)
     except checks.NoChip as e:
         print("perfbench: %s" % e, file=sys.stderr)
         return 1
+    line = result_line(cell, facts, bool(args.trace))
+    for name, (value, limit) in line["compared"].items():
+        print("compared: %s %r limit %r" % (name, value, limit),
+              file=sys.stderr)
     sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0

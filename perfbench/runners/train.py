@@ -24,6 +24,18 @@ import time
 
 from perfbench import checks, hlo_tag, trace_reduce
 
+#: the keys a configuration of this runner holds besides the common ones
+CONFIG_KEYS = ("image_size", "channels", "classes", "loss", "initializer")
+
+
+def abstract_sample(config):
+    """One sample as the net takes it, abstractly: a batch of one image."""
+    import jax
+
+    size = config["image_size"]
+    return jax.ShapeDtypeStruct((1, config["channels"], size, size),
+                                "float32")
+
 
 def log(t_start, msg):
     print("[train %6.1fs] %s" % (time.monotonic() - t_start, msg), flush=True)
@@ -151,7 +163,8 @@ def reference_check(net, weights, config, seed, mesh, zero, x, y, t_start):
     the same seeded weights: ``reference.steps`` losses each, loss ``i``
     within ``reference.rtol[i]``.  Loss 0 checks the forward pass, loss 1
     the gradient and the update.  Lint is off on both: neither is the
-    program under test.  Returns the problems found."""
+    program under test.  Returns the problems found and the numbers
+    compared, each ``[value, limit]``."""
     import jax
 
     ref = config["reference"]
@@ -173,9 +186,11 @@ def reference_check(net, weights, config, seed, mesh, zero, x, y, t_start):
     log(t_start, "reference: %d rows, cell %s vs float32 %s, rel %s "
         "(tolerances %s)" % (rows, got["cell"], got["float32"],
                              ["%.2e" % r for r in rel], rtol))
-    return ["reference: loss %d differs from float32 by %.3e (> %g)"
-            % (i, r, tol) for i, (r, tol) in enumerate(zip(rel, rtol))
-            if not r <= tol]
+    return (["reference: loss %d differs from float32 by %.3e (> %g)"
+             % (i, r, tol) for i, (r, tol) in enumerate(zip(rel, rtol))
+             if not r <= tol],
+            {"ref_loss%d_rel" % i: [r, tol]
+             for i, (r, tol) in enumerate(zip(rel, rtol))})
 
 
 def _chunk(step, x, y, chunk_steps, annotate):
@@ -261,8 +276,9 @@ def run(cell, platform, seed, seconds, trace, t_start, counter):
     net = build_net(config)
     weights = Weights(net, seed)
     lap("build_s")
-    problems += reference_check(net, weights, config, seed, mesh, zero,
-                                x, y, t_start)
+    found, compared = reference_check(net, weights, config, seed, mesh, zero,
+                                      x, y, t_start)
+    problems += found
     lap("reference_s")
 
     weights.restore()
@@ -324,6 +340,15 @@ def run(cell, platform, seed, seconds, trace, t_start, counter):
     if off:
         problems.append("%d of %d parameter and state arrays are not on %s "
                         "devices" % (len(off), len(params + state), platform))
+    compared.update({
+        "nonfinite_losses": [
+            sum(1 for v in values if not math.isfinite(v)), 0],
+        "last_chunk_min_loss_over_first": [
+            min(in_window[-chunk_steps:]) / values[0],
+            1.0 if config.get("loss_must_fall", True) else math.inf],
+        "programs_built_in_window": [built, 0],
+        "arrays_off_device": [len(off), 0],
+    })
 
     flops_per_sample = 3 * 2 * config["fwd_macs_per_sample"]
     log(t_start, "window: %d steps of batch %d in %.3fs = %.2f samples/s; "
@@ -339,12 +364,14 @@ def run(cell, platform, seed, seconds, trace, t_start, counter):
         "%s=%.2f" % kv for kv in parts.items()))
     return {
         "problems": problems,
+        "compared": compared,
         "attempted": n_steps,
         "failed": sum(1 for v in in_window if not math.isfinite(v)),
         "setup_s": setup_s,
         "end_to_end": {"train_samples_per_s": samples_per_s},
         "setup_parts": parts,
         "counters": {
+            "flops_per_sample": flops_per_sample,
             "flops_per_module_per_chip":
                 flops_per_sample * config["recipe"]["per_chip_batch"],
         },

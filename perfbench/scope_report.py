@@ -4,24 +4,19 @@ program gives itself (``hlo_scope``).
 
     python3 perfbench/scope_report.py --workload <cell> --seed <n> --seconds <s>
 
-Runs the cell as ``run.py --trace 1`` does and reads, besides the per-layer
-metrics ``BENCHMARK.json`` registers, every ``layer_metrics/<metric>.json``
-of the cell's data directory whose ``reader`` is a module
-``perfbench/readers/<reader>.py`` with ``read(spec, facts)``.  Until
-``layer_metrics.read`` looks such modules up itself (a ``benchmark`` PR's
-line), this is the command that reads those metrics.  It prints the step by
-direction, family and kind, its heaviest ops with their scope and the
-heaviest ops that carry no name, and as the last line of stdout one JSON
-object: ``metrics``, ``families``, ``ops``, ``unscoped`` (ms per traced
-step), ``device`` and ``correct``.  Like ``run.py`` it needs the cell's chips.
+Runs the cell as ``run.py --trace 1`` does and reads the same per-layer
+metrics the same way (``layer_metrics.read``); what it adds is below the
+metrics: the step by direction, family and kind, its heaviest ops with their
+scope and the heaviest ops that carry no name.  The last line of stdout is
+one JSON object: ``metrics``, ``families``, ``ops``, ``unscoped`` (ms per
+traced step), ``device`` and ``correct``.  Like ``run.py`` it needs the
+cell's chips.
 """
 import time
 
 T_START = time.monotonic()
 
 import argparse
-import glob
-import importlib
 import json
 import os
 import sys
@@ -32,25 +27,6 @@ if ROOT not in sys.path:
 
 from perfbench import checks, hlo_scope, layer_metrics, run
 from perfbench.readers import scope_op
-
-
-def module_readers(root: str) -> list:
-    """``[(metric name, spec, read)]`` for the metric files under the data
-    directory of ``<root>/BENCHMARK.json`` whose reader is a module of
-    ``perfbench/readers/``."""
-    data = os.path.join(root, run._json(
-        os.path.join(root, "BENCHMARK.json"))["paths"][0])
-    found = []
-    for path in sorted(glob.glob(os.path.join(data, "layer_metrics",
-                                              "*.json"))):
-        spec = run._json(path)
-        if os.path.exists(os.path.join(ROOT, "perfbench", "readers",
-                                       spec["reader"] + ".py")):
-            module = importlib.import_module(
-                "perfbench.readers." + spec["reader"])
-            found.append((os.path.basename(path)[:-len(".json")], spec,
-                          module.read))
-    return found
 
 
 def family(path: str) -> str:
@@ -90,22 +66,13 @@ def tables(facts: dict, n_ops: int = 15) -> dict:
 
 def report(root, name, platform, seed, seconds, t_start, n_ops=15) -> dict:
     """One traced run of the cell; what the module docstring lists."""
-    cell = run.load_cell(root, name)
-    devices = checks.require_devices(platform, cell["chips"])
-    cell["peaks"] = (checks.peaks(devices[0].device_kind)
-                     if platform == "tpu" else None)
-    runner = importlib.import_module(
-        "perfbench.runners." + cell["traffic"]["runner"])
-    facts = runner.run(cell, platform, seed, seconds, True, t_start,
-                       checks.CompileCounter())
-    facts["peaks"] = cell["peaks"]
+    cell, facts = run.cell_facts(root, name, platform, seed, seconds, True,
+                                 t_start)
+    t0 = time.perf_counter()
     metrics = {m["name"]: layer_metrics.read(m["spec"], facts)
                for m in cell["per_layer"]}
-    t0 = time.perf_counter()
-    for metric, spec, read in module_readers(root):
-        metrics[metric] = read(spec, facts)
     out = dict(tables(facts, n_ops), metrics=metrics)
-    print("scope_report: reading the scopes took %.2fs"
+    print("scope_report: reading the metrics and the scopes took %.2fs"
           % (time.perf_counter() - t0), flush=True)
     first = facts["devices"][0]
     out["device"] = {"platform": first.platform, "kind": first.device_kind,

@@ -1,6 +1,9 @@
-"""``fwd_macs_per_sample`` of each configuration against a count of the
-multiply-adds in the zoo net's own forward pass, traced abstractly at batch 1
-(nothing runs).  MFU and the roofline share rest on this number."""
+"""``fwd_macs_per_sample`` of each configuration ``BENCHMARK.json`` lists
+against a count of the multiply-adds in the net's own forward pass, traced
+abstractly on one sample as the configuration's runner describes it
+(``abstract_sample``; nothing runs).  MFU and the roofline share rest on this
+number."""
+import importlib
 import json
 import os
 
@@ -11,9 +14,18 @@ import pytest
 _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _BENCH = json.load(open(os.path.join(_ROOT, "BENCHMARK.json")))
-_CONFIG_FILES = sorted(
-    f for f in os.listdir(os.path.join(_ROOT, "perfbench", "configs"))
-    if f.endswith(".json"))
+
+
+def _runner_of(config_name):
+    """The runner of the cells that use the configuration: one, or the
+    configuration's keys would have to serve two."""
+    mixes = {w["traffic"] for w in _BENCH["workloads"]
+             if w["config"] == config_name}
+    runners = {json.load(open(os.path.join(
+        _ROOT, _BENCH["paths"][0], "mixes", mix + ".json")))["runner"]
+        for mix in mixes}
+    assert len(runners) == 1, (config_name, runners)
+    return importlib.import_module("perfbench.runners." + runners.pop())
 
 
 def _macs(jaxpr) -> int:
@@ -38,19 +50,19 @@ def _macs(jaxpr) -> int:
     return total
 
 
-@pytest.mark.parametrize("config_file", _CONFIG_FILES)
-def test_fwd_macs_per_sample_matches_the_traced_forward(config_file):
+@pytest.mark.parametrize(
+    "entry", _BENCH["configs"],
+    ids=[os.path.basename(c["file"]) for c in _BENCH["configs"]])
+def test_fwd_macs_per_sample_matches_the_traced_forward(entry):
     from incubator_mxnet_tpu.gluon.block import pure_forward
-    from perfbench.runners import train
 
-    config = json.load(open(os.path.join(_ROOT, "perfbench", "configs",
-                                         config_file)))
+    config = json.load(open(os.path.join(_ROOT, entry["file"])))
+    runner = _runner_of(entry["name"])
     import incubator_mxnet_tpu as mx
 
-    net = train._factory(config["factory"])(**config["factory_kwargs"])
+    net = runner._factory(config["factory"])(**config["factory_kwargs"])
     net.initialize(init=mx.init.Xavier())
-    size = config["image_size"]
-    shape = (1, config["channels"], size, size)
+    sample = runner.abstract_sample(config)
     from incubator_mxnet_tpu.gluon.parameter import shape_only_init
 
     params = None
@@ -61,12 +73,10 @@ def test_fwd_macs_per_sample_matches_the_traced_forward(config_file):
 
     # deferred shapes resolve under an abstract forward; no initializer runs
     with shape_only_init():
-        jax.eval_shape(lambda x: pure_forward(net, [], [], x)[0],
-                       jax.ShapeDtypeStruct(shape, np.float32))
+        jax.eval_shape(lambda x: pure_forward(net, [], [], x)[0], sample)
     params = list(net.collect_params().values())
     avals = [jax.ShapeDtypeStruct(tuple(p.shape), np.float32) for p in params]
-    jaxpr = jax.make_jaxpr(forward)(
-        avals, jax.ShapeDtypeStruct(shape, np.float32))
+    jaxpr = jax.make_jaxpr(forward)(avals, sample)
     counted = _macs(jaxpr.jaxpr)
     stated = config["fwd_macs_per_sample"]
     assert abs(counted - stated) / stated < 0.02, (counted, stated)

@@ -1,6 +1,8 @@
 """``BENCHMARK.json`` and the data files against the limits of the
 benchmark's contract that can be read off the files: names, units, lengths,
 which metric moves which, and that every file a cell needs is there."""
+import importlib
+import inspect
 import json
 import os
 import re
@@ -15,6 +17,12 @@ _DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 _NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 _UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 _SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+#: what every configuration holds, whatever its runner; the runner's own
+#: ``CONFIG_KEYS`` come on top
+_COMMON_KEYS = ("factory", "recipe", "precision", "fwd_macs_per_sample",
+                "reference", "reduced", "assumed")
+_RUN_PARAMETERS = ["cell", "platform", "seed", "seconds", "trace", "t_start",
+                   "counter"]
 _WIDTH = re.compile(r"(_dim|_rank)$|hidden|intermediate|latent|head_size|"
                     r"experts_per_tok")
 
@@ -103,20 +111,42 @@ def test_every_cell_reports_what_its_layer_metrics_move(root):
 
 
 @pytest.mark.parametrize("root", _ROOTS)
-def test_every_cell_loads_from_its_files_by_name(root):
+def test_every_cell_loads_from_its_files_by_name(root, toy_tokens_runner):
     b = _load(root)
     for w in b["workloads"]:
         cell = run.load_cell(root, w["name"])
-        assert cell["traffic"]["runner"] in ("train",)
-        assert os.path.exists(os.path.join(
-            _ROOT, "perfbench", "runners", cell["traffic"]["runner"] + ".py"))
-        for key in ("factory", "recipe", "precision", "fwd_macs_per_sample",
-                    "reference", "reduced", "assumed"):
+        # a runner is a module of perfbench/runners/ (the test data's second
+        # runner is installed there by the fixture), checked by what it
+        # declares and not against a list of the runners there are
+        assert _NAME.match(cell["traffic"]["runner"])
+        runner = importlib.import_module(
+            "perfbench.runners." + cell["traffic"]["runner"])
+        assert list(inspect.signature(runner.run).parameters) == \
+            _RUN_PARAMETERS, w["name"]
+        assert callable(runner.abstract_sample)
+        assert isinstance(runner.CONFIG_KEYS, tuple) and runner.CONFIG_KEYS
+        for key in _COMMON_KEYS + runner.CONFIG_KEYS:
             assert key in cell["config"], (w["name"], key)
         assert [m["name"] for m in cell["end_to_end"]].count("setup_s") == 1
         assert all("reader" in m["spec"] for m in cell["per_layer"])
     with pytest.raises(KeyError, match="no workload"):
         run.load_cell(root, "no_such_cell")
+
+
+def test_the_test_data_has_a_runner_that_is_not_train_and_counts_tokens(
+        toy_tokens_runner):
+    cells = [run.load_cell(_DATA, w["name"])
+             for w in _load(_DATA)["workloads"]]
+    runners = {c["name"]: c["traffic"]["runner"] for c in cells}
+    assert runners == {"tiny_train": "train", "tiny_train_dp4": "train",
+                       "tiny_tokens": "toy_tokens"}
+    config = cells[2]["config"]
+    assert "image_size" not in config and "channels" not in config
+    sample = toy_tokens_runner.abstract_sample(config)
+    assert sample.shape == (1, config["seq_len"]) and sample.dtype == "int32"
+    # and no toy is left among the benchmark's own runners
+    assert not os.path.exists(os.path.join(_ROOT, "perfbench", "runners",
+                                           "toy_tokens.py"))
 
 
 def test_files_under_paths_are_named_from_the_characters_of_a_name():

@@ -1,6 +1,7 @@
 """The train runner at a tiny size on the CPU, through cells that are defined
 wholly by files under ``tests/benchmark_tests/data/`` (so adding a cell, a
-configuration or a layer metric edits nothing under ``perfbench/``); the
+configuration or a layer metric edits nothing under ``perfbench/``); a cell
+of token ids through a runner that the test installs; the
 result line's keys; and ``run.py`` as the driver runs it, which must FAIL
 here: this machine has no accelerator."""
 import json
@@ -33,8 +34,17 @@ def lines(counter):
 
 def test_plain_run_reports_every_end_to_end_metric_of_the_cell(lines):
     line = lines[False]
-    assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]
+    # every number correct was decided from, beside its limit
+    assert set(line["compared"]) == {
+        "ref_loss0_rel", "ref_loss1_rel", "nonfinite_losses",
+        "last_chunk_min_loss_over_first", "programs_built_in_window",
+        "arrays_off_device"}
+    assert line["compared"]["ref_loss0_rel"][0] <= \
+        line["compared"]["ref_loss0_rel"][1] == 0.1
+    assert line["compared"]["last_chunk_min_loss_over_first"][1] == "inf"
+    assert line["compared"]["programs_built_in_window"] == [0, 0]
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 2 and line["attempted"] % 2 == 0
     assert set(line["metrics"]) == {"train_samples_per_s", "setup_s"}
@@ -56,7 +66,7 @@ def test_traced_run_reports_layer_metrics_and_no_device_number_on_a_cpu(lines):
     assert set(line["metrics"]) == {"trace_s.train", "compile_s.train",
                                     "reference_check_s.train"}
     assert "busy_s" not in line["device"] and "breakdown" not in line
-    assert line["correct"] is True
+    assert line["correct"] is True and list(line)[-1] == "compared"
 
 
 def test_mesh_cell_runs_on_four_virtual_devices(counter):
@@ -64,6 +74,30 @@ def test_mesh_cell_runs_on_four_virtual_devices(counter):
                         time.monotonic(), counter)
     assert line["correct"] is True and line["attempted"] >= 2
     assert set(line["metrics"]) == {"train_samples_per_s", "setup_s"}
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["plain", "traced"])
+def test_a_cell_of_token_ids_runs_through_a_runner_that_is_not_train(
+        traced, toy_tokens_runner, counter):
+    line = run.run_cell(_DATA, "tiny_tokens", "cpu", 2 ** 31 + 11, 0.2,
+                        traced, time.monotonic(), counter)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] >= 1
+    assert set(line["metrics"]) == (
+        {"trace_s.train", "compile_s.train", "reference_check_s.train"}
+        if traced else {"train_samples_per_s", "setup_s"})
+    assert all(m["value"] > 0 for m in line["metrics"].values())
+    assert line["device"]["platform"] == "cpu"
+    assert line["device"]["memory_peak_bytes"] > 0
+    assert json.loads(json.dumps(line)) == line
+
+
+def test_a_runner_that_is_no_module_of_runners_is_an_import_error():
+    # without the fixture the toy is not there: a mix can only name a file
+    # of perfbench/runners/
+    with pytest.raises(ModuleNotFoundError, match="toy_tokens"):
+        run.run_cell(_DATA, "tiny_tokens", "cpu", 1, 0.1, False,
+                     time.monotonic())
 
 
 def test_a_cell_that_asks_for_more_chips_than_there_are_is_refused():
@@ -87,6 +121,10 @@ def test_result_line_leaves_out_a_metric_its_reader_cannot_read():
     line = run.result_line(cell, facts, traced=True)
     assert line["correct"] is False and line["failed"] == 1
     assert line["metrics"] == {"trace_s.train": {"value": 0.5, "unit": "s"}}
+    assert line["compared"] == {}
+    facts["compared"] = {"x": [float("nan"), 0.1]}
+    assert run.result_line(cell, facts, traced=True)["compared"] == {
+        "x": ["nan", 0.1]}
     assert line["device"]["memory_peak_bytes"] == 123
     plain = run.result_line(cell, facts, traced=False)
     assert plain["metrics"]["setup_s"] == {"value": 2.0, "unit": "s"}

@@ -1,6 +1,7 @@
-"""``hlo_scope`` on hand-written HLO, the ``scope_op`` reader on hand-made
-facts with the metric files ``perfbench/layer_metrics/`` really holds, and
-``scope_report`` on a tiny cell defined wholly under
+"""``hlo_scope`` on hand-written HLO, the ``scope_op`` reader and the
+``roofline`` reader's ``scope`` and bytes on hand-made facts with the metric
+files ``perfbench/layer_metrics/`` really holds, and ``scope_report`` on a
+tiny cell defined wholly under
 ``tests/benchmark_tests/data_scope/``, on the CPU (where every device metric
 is None)."""
 import json
@@ -196,14 +197,94 @@ def test_the_data_directory_holds_the_metric_files_perfbench_holds():
         assert _spec(metric, os.path.join(_DATA, "bench")) == _spec(metric)
 
 
-def test_module_readers_finds_scope_op_and_nothing_built_in():
-    found = scope_report.module_readers(_DATA)
-    assert sorted(m for m, _, _ in found) == sorted(PHASES + FAMILIES)
-    assert {read for _, _, read in found} == {scope_op.read}
-    # layer_metrics.py itself does not dispatch to a module yet: registering
-    # these metrics in BENCHMARK.json waits for that line (PERF.md section 7)
-    with pytest.raises(ValueError, match="unknown reader"):
-        layer_metrics.read(_spec("fwd_ms.train"), _facts())
+def test_layer_metrics_read_finds_scope_op_as_a_module_of_readers(capsys):
+    from perfbench import run
+
+    cell = run.load_cell(_DATA, "tiny_scoped")
+    registered = {m["name"]: m["spec"] for m in cell["per_layer"]}
+    assert set(PHASES + FAMILIES) <= set(registered)
+    for metric in PHASES + FAMILIES:
+        assert registered[metric]["reader"] == "scope_op"
+        got = layer_metrics.read(registered[metric], _facts())
+        assert got == scope_op.read(_spec(metric), _facts()) and got > 0
+    # a reader that is neither built in nor a module of perfbench/readers/
+    with pytest.raises(ValueError, match="unknown reader 'no_such_reader'"):
+        layer_metrics.read({"reader": "no_such_reader"}, _facts())
+
+
+_PEAKS = {"bf16_flops_per_s": 2e12, "hbm_bytes_per_s": 1e9}
+_ROOFLINE = {"reader": "roofline", "pattern": r"^other\.fusion\.",
+             "work_counter": "kernel_flops", "peak": "bf16_flops_per_s"}
+_WITH_BYTES = dict(_ROOFLINE, bytes_counter="kernel_bytes",
+                   bytes_peak="hbm_bytes_per_s")
+# ``pattern`` selects fusion.1, .2, .3 and .5 of ``_facts()``, which follow
+# one another: 2 + 4 + 8 + 64 = 78 ms a module; under step.forward only the
+# first two, 6 ms.  2e12 FLOP/s x 1 ms = 2e9 FLOP; 1e9 B/s x 1 ms = 1e6 B.
+
+
+@pytest.mark.parametrize("spec,counters,share,bound", [
+    pytest.param(_ROOFLINE, {"kernel_flops": 78e9}, 50.0, None,
+                 id="none_of_the_new_keys_reads_as_before"),
+    pytest.param(_WITH_BYTES, {"kernel_flops": 78e9, "kernel_bytes": 7.8e6},
+                 50.0, "compute", id="compute_bound_bytes_side_smaller"),
+    pytest.param(_WITH_BYTES, {"kernel_flops": 78e9, "kernel_bytes": 58.5e6},
+                 75.0, "bytes", id="hbm_bound_bytes_side_larger"),
+    pytest.param(dict(_WITH_BYTES, scope=r"\bstep\.forward\b"),
+                 {"kernel_flops": 3e9, "kernel_bytes": 3e6}, 50.0, "bytes",
+                 id="scope_narrows_pattern"),
+    pytest.param(_WITH_BYTES, {"kernel_flops": 78e9, "kernel_bytes": 93.6e6},
+                 120.0, "bytes", id="over_100_passed_through"),
+    pytest.param(_WITH_BYTES, {"kernel_flops": 78e9}, None, None,
+                 id="a_counter_the_runner_did_not_return_reads_none"),
+    pytest.param(dict(_ROOFLINE, scope="no_such_scope"),
+                 {"kernel_flops": 78e9}, None, None,
+                 id="a_scope_nothing_carries_reads_none"),
+])
+def test_roofline_takes_the_larger_of_operations_and_bytes(
+        spec, counters, share, bound, capsys):
+    facts = dict(_facts(), counters=counters, peaks=_PEAKS)
+    facts["trace"]["ops"] = [          # laid end to end, so none overlaps
+        (tag, i * 2e9, dur)
+        for i, (tag, _, dur) in enumerate(facts["trace"]["ops"])]
+    got = layer_metrics.read(spec, facts)
+    assert got == (None if share is None else pytest.approx(share))
+    out = capsys.readouterr().out
+    if bound is None:
+        assert "roofline:" not in out
+    else:
+        assert "roofline: %s bound" % bound in out
+
+
+_MFU = {"reader": "mfu", "rate": "train_samples_per_s",
+        "work_counter": "flops_per_sample", "peak": "bf16_flops_per_s"}
+
+
+@pytest.mark.parametrize("rate,counters,peaks,want", [
+    # 250 samples/s x 4e9 FLOP over two chips of 2e12 FLOP/s
+    pytest.param(250.0, {"flops_per_sample": 4e9}, _PEAKS, 25.0,
+                 id="rate_times_work_over_the_peak_of_the_chips_used"),
+    pytest.param(250.0, {"flops_per_sample": 4e9}, None, None,
+                 id="no_peaks_off_the_tpu_reads_none"),
+    pytest.param(None, {"flops_per_sample": 4e9}, _PEAKS, None,
+                 id="a_cell_that_does_not_report_the_rate_reads_none"),
+    pytest.param(250.0, {}, _PEAKS, None, id="no_work_count_reads_none"),
+])
+def test_mfu_is_the_timed_windows_rate_times_the_work_count_over_the_peak(
+        rate, counters, peaks, want):
+    facts = {"end_to_end": {"train_samples_per_s": rate}, "trace": None,
+             "counters": counters, "peaks": peaks, "devices": ["a", "b"]}
+    got = layer_metrics.read(_MFU, facts)
+    assert got == (None if want is None else pytest.approx(want))
+    # the file the benchmark registers names exactly these parameters
+    assert {k: v for k, v in _spec("step_mfu.train").items()
+            if k != "what"} == _MFU
+
+
+def test_trace_op_takes_a_scope_too_and_reads_what_scope_op_reads(capsys):
+    spec = {"reader": "trace_op", "pattern": "^(?!coll\\.)", "reduce": "sum",
+            "scope": _spec("fwd_ms.train")["scope"]}
+    assert layer_metrics.read(spec, _facts()) == pytest.approx(
+        scope_op.read(_spec("fwd_ms.train"), _facts()))
 
 
 def test_scope_report_names_every_metric_of_a_cell_defined_beside_it():
