@@ -507,7 +507,8 @@ class HybridBlock(Block):
         opted-in block on each call path becomes the remat region) or
         globally via MXNET_BACKWARD_DO_MIRROR=1.  Aux-state writes (BN
         running stats) are routed through the checkpoint as outputs so
-        they stay valid in the outer trace."""
+        they stay valid in the outer trace.  What an op names
+        ``tracing.REMAT_KEEP`` is kept, not recomputed."""
         tc = tracing.current_trace()
         pnames = sorted(params)
         pvals = [params[n]._data for n in pnames]
@@ -559,7 +560,9 @@ class HybridBlock(Block):
             del tc.aux_loss_origins[n_aux_loss:]
             return outs, writes, losses
 
-        outs, writes, losses = jax.checkpoint(inner)(arr_vals, pvals)
+        outs, writes, losses = jax.checkpoint(
+            inner, policy=jax.checkpoint_policies.save_only_these_names(
+                tracing.REMAT_KEEP))(arr_vals, pvals)
         for h, v in zip(shape_meta["aux"], writes):
             tc.write_aux(h, v)
         for al in losses:

@@ -1,5 +1,6 @@
 """``mx.gluon.model_zoo`` (gluon/model_zoo parity)."""
+from . import text
 from . import vision
 from .vision import get_model
 
-__all__ = ["vision", "get_model"]
+__all__ = ["text", "vision", "get_model"]

@@ -24,6 +24,7 @@ from . import parity_tail  # noqa: F401  (remaining user-visible tail:
 #                                         *_like samplers, multi-tensor
 #                                         optimizer updates)
 from . import npi  # noqa: F401  (numpy-internal _npi_*/_np_* ABI names)
+from . import decoder  # noqa: F401  (RMS norm, rotary embedding)
 
 __all__ = ["registry", "Op", "get_op", "invoke", "invoke_raw", "list_ops",
            "register"]

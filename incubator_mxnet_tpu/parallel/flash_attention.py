@@ -50,69 +50,144 @@ def _fit_block(size, block):
 
 
 # ---------------------------------------------------------------------------
-# forward kernel
+# which tiles a mask admits
 # ---------------------------------------------------------------------------
+#
+# Right-aligned causal mask with an optional sliding window: query row i
+# attends key j iff  i + offset - window < j <= i + offset, with
+# offset = kv_len - q_len (KV-cache decode convention, matching
+# attention_reference's tril(klen - qlen)).  A grid step whose tile the mask
+# rules out entirely is skipped twice over: its compute by pl.when, and its
+# DMA because the index maps below walk only the tiles between first and
+# last (a step past the last repeats the last tile's index, and Pallas does
+# not fetch a block again whose index did not change).  With a window the
+# grid's inner axis is as short as the widest run of admitted tiles.
 
-def _causal_mask(s, qi, ki, block_q, block_k, offset):
-    """Right-aligned causal mask: query row i attends keys j with
-    j <= i + offset, offset = kv_len - q_len (KV-cache decode
-    convention, matching attention_reference's tril(klen - qlen))."""
+
+def _mask(s, qi, ki, block_q, block_k, offset, window):
     rows = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     cols = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
+    keep = rows + offset >= cols
+    if window is not None:
+        keep &= cols > rows + offset - window
     # explicit f32 fill: a python float would enter the kernel as f64 and
     # Mosaic cannot legalize the f64->f32 truncf
-    return jnp.where(rows + offset >= cols, s, jnp.float32(_NEG_INF))
+    return jnp.where(keep, s, jnp.float32(_NEG_INF))
 
 
-def _block_relevant(qi, ki, block_q, block_k, offset):
-    """False iff the (qi, ki) tile lies entirely above the causal
-    diagonal (its mask would zero everything) — skip ~half the grid."""
-    last_row = qi * block_q + block_q - 1
-    first_col = ki * block_k
-    return first_col <= last_row + offset
+def _steps(n_other, block_self, block_other, window):
+    """Length of the inner grid axis: every tile without a window, else the
+    most tiles of ``block_other`` that ``window + block_self - 1``
+    consecutive positions can touch."""
+    if window is None:
+        return n_other
+    return min(n_other, _cdiv(window + block_self - 1, block_other) + 1)
 
+
+def _div(a, n):
+    """a // n and a % n for a non-negative int32 ``a`` (grid indices): as
+    ``lax.div`` / ``lax.rem``, see ``_floordiv``."""
+    return jax.lax.div(a, _np.int32(n))
+
+
+def _rem(a, n):
+    return jax.lax.rem(a, _np.int32(n))
+
+
+def _floordiv(num, den, low):
+    """floor(num / den) for an int32 ``num`` that is never below the static
+    ``low``: shifted to be non-negative and divided by ``lax.div`` (jnp's
+    ``//`` on traced ints does not lower through Mosaic under x64)."""
+    shift = _cdiv(max(0, -low), den)
+    return jax.lax.div(num + _np.int32(shift * den), _np.int32(den)) \
+        - _np.int32(shift)
+
+
+def _k_range(qi, block_q, block_k, offset, causal, window, nk):
+    """(first, last) key tile a query tile needs; last < first: none."""
+    first, last = 0, nk - 1
+    if causal:
+        last = jnp.minimum(_floordiv(
+            qi * block_q + (block_q - 1 + offset), block_k, offset), nk - 1)
+    if window is not None:
+        low = offset - window + 1
+        first = jnp.maximum(_floordiv(
+            qi * block_q + low, block_k, low), 0)
+    return first, last
+
+
+def _q_range(kj, block_q, block_k, offset, causal, window, nq):
+    """(first, last) query tile a key tile is needed by."""
+    first, last = 0, nq - 1
+    if causal:
+        first = jnp.maximum(_floordiv(
+            kj * block_k - offset, block_q, -offset), 0)
+    if window is not None:
+        low = block_k - 1 + window - 1 - offset
+        last = jnp.minimum(_floordiv(
+            kj * block_k + low, block_q, low), nq - 1)
+    return first, last
+
+
+def _tile(first, last, step):
+    """Index-map form of ``first + step``: clamped into [0, last] so that a
+    skipped step names a tile that exists (and, past the last, the one
+    already resident)."""
+    return jnp.maximum(jnp.minimum(first + step, last), 0).astype(jnp.int32)
+
+
+def _dot(a, b, contract):
+    """a·b over the given axes, f32 accumulate, operands in their own dtype
+    (bf16 operands keep the MXU on its fast path; for them the precision is
+    pinned, since Mosaic refuses a bf16 product under a process-wide
+    ``jax_default_matmul_precision`` of "highest")."""
+    precision = None if a.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# forward kernel
+# ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, causal, block_q, block_k, nk, offset):
+                *, scale, causal, window, block_q, block_k, nk, steps, offset):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
+    first, last = _k_range(qi, block_q, block_k, offset, causal, window, nk)
+    ki = first + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    relevant = _block_relevant(qi, ki, block_q, block_k, offset) \
-        if causal else True
-
-    @pl.when(relevant)
+    @pl.when(ki <= last)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)               # (bq, D)
-        k = k_ref[0].astype(jnp.float32)               # (bk, D)
-        v = v_ref[0].astype(jnp.float32)               # (bk, D)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]         # (bq, D), (bk, D) x2
+        s = _dot(q, k, (1, 1)) * scale
         if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, offset)
+            s = _mask(s, qi, ki, block_q, block_k, offset, window)
 
         m_prev = m_scr[:]                              # (bq, 1)
         l_prev = l_scr[:]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
-        # rows with zero unmasked keys (causal, kv_len < q_len): every score
-        # is _NEG_INF, so exp(s - m_new) would be 1 everywhere and emit
-        # mean(V); force those rows to contribute nothing (output 0)
+        # rows with zero unmasked keys so far: every score is _NEG_INF, so
+        # exp(s - m_new) would be 1 everywhere and emit mean(V); force those
+        # rows to contribute nothing (output 0)
         p = jnp.where(m_new > jnp.float32(_NEG_INF / 2), p, jnp.float32(0.0))
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        acc_scr[:] = acc_scr[:] * alpha + _dot(p.astype(v.dtype), v, (1, 0))
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == steps - 1)
     def _finish():
         l = l_scr[:]
         o_ref[0] = (acc_scr[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
@@ -121,38 +196,60 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l, 1e-30))
 
 
-def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
+def _geometry(q, k, block_q, block_k, heads, kv_heads, causal, window):
+    s, sk = q.shape[1], k.shape[1]
+    bq, bk = _fit_block(s, block_q), _fit_block(sk, block_k)
+    nq, nk = s // bq, sk // bk
+    group = heads // kv_heads
+    g = dict(bq=bq, bk=bk, nq=nq, nk=nk, offset=sk - s, group=group,
+             k_steps=_steps(nk, bq, bk, window),
+             q_steps=_steps(nq, bk, bq, window))
+
+    def kv_of(b):
+        """Row of the flattened (B*Hkv) keys that query row ``b`` of the
+        flattened (B*H) queries reads."""
+        return _div(b, heads) * kv_heads + _div(_rem(b, heads), group)
+
+    def k_tile(i, j):
+        first, last = _k_range(i, bq, bk, sk - s, causal, window, nk)
+        return _tile(first, last, j)
+
+    g["q_map"] = lambda b, i, j: (b, i, _I0)
+    g["k_map"] = lambda b, i, j: (kv_of(b), k_tile(i, j), _I0)
+    return g
+
+
+def _fwd(q, k, v, scale, causal, window, heads, kv_heads, block_q, block_k,
+         interpret):
     bh, s, d = q.shape
-    sk = k.shape[1]
-    block_q = _fit_block(s, block_q)
-    block_k = _fit_block(sk, block_k)
-    nq = s // block_q
-    nk = sk // block_k
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k, nk=nk,
-                               offset=sk - s)
+    g = _geometry(q, k, block_q, block_k, heads, kv_heads, causal, window)
+    bq, bk = g["bq"], g["bk"]
+    kernel = functools.partial(
+        _fwd_kernel, scale=scale, causal=causal, window=window, block_q=bq,
+        block_k=bk, nk=g["nk"], steps=g["k_steps"], offset=g["offset"])
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, nq, nk),
+        grid=(bh, g["nq"], g["k_steps"]),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, _I0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, _I0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, _I0)),
+            pl.BlockSpec((1, bq, d), g["q_map"]),
+            pl.BlockSpec((1, bk, d), g["k_map"]),
+            pl.BlockSpec((1, bk, d), g["k_map"]),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, _I0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, _I0)),
+            pl.BlockSpec((1, bq, d), g["q_map"]),
+            pl.BlockSpec((1, bq, 1), g["q_map"]),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype),
             jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse[:, :, 0]
 
@@ -161,141 +258,143 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
 # backward kernels
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, scale, causal, block_q, block_k, nk, offset):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+def _p_and_ds(q, k, v, do, lse, delta, qi, ki, *, scale, causal, window,
+              block_q, block_k, offset):
+    """The tile's probabilities and score gradients, both (bq, bk) f32."""
+    s = _dot(q, k, (1, 1)) * scale
+    if causal:
+        s = _mask(s, qi, ki, block_q, block_k, offset, window)
+    p = jnp.exp(s - lse)
+    # rows with zero unmasked keys have lse ~= _NEG_INF, which would
+    # blow exp() up instead of zeroing it; mask on the raw scores
+    p = jnp.where(s > jnp.float32(_NEG_INF / 2), p, jnp.float32(0.0))
+    dp = _dot(do, v, (1, 1))
+    return p, p * (dp - delta) * scale
 
-    @pl.when(ki == 0)
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   dq_scr, *, nk, steps, **tile):
+    qi = pl.program_id(1)
+    step = pl.program_id(2)
+    first, last = _k_range(qi, tile["block_q"], tile["block_k"],
+                           tile["offset"], tile["causal"], tile["window"], nk)
+    ki = first + step
+
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    relevant = _block_relevant(qi, ki, block_q, block_k, offset) \
-        if causal else True
-
-    @pl.when(relevant)
+    @pl.when(ki <= last)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]                                # (bq, 1)
-        delta = delta_ref[0]                            # (bq, 1)
+        k = k_ref[0]
+        _, ds = _p_and_ds(q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0],
+                          delta_ref[0], qi, ki, **tile)
+        dq_scr[:] = dq_scr[:] + _dot(ds.astype(k.dtype), k, (1, 0))
 
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, offset)
-        p = jnp.exp(s - lse)
-        # rows with zero unmasked keys have lse ~= _NEG_INF, which would
-        # blow exp() up instead of zeroing it; mask on the raw scores
-        p = jnp.where(s > jnp.float32(_NEG_INF / 2), p, jnp.float32(0.0))
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dq_scr[:] = dq_scr[:] + jnp.dot(ds, k,
-                                        preferred_element_type=jnp.float32)
-
-    @pl.when(ki == nk - 1)
+    @pl.when(step == steps - 1)
     def _finish():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, scale, causal, block_q, block_k, nq, offset):
+                    dk_ref, dv_ref, dk_scr, dv_scr, *, nq, steps, group,
+                    **tile):
+    """One key tile of one key/value head: the inner axis walks the query
+    heads that share it (``group``) and, for each, the query tiles that see
+    it; dk and dv are their sum."""
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    t = pl.program_id(2)
+    first, last = _q_range(kj, tile["block_q"], tile["block_k"],
+                           tile["offset"], tile["causal"], tile["window"], nq)
+    qi = first + _rem(t, steps)
 
-    @pl.when(qi == 0)
+    @pl.when(t == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    relevant = _block_relevant(qi, kj, block_q, block_k, offset) \
-        if causal else True
-
-    @pl.when(relevant)
+    @pl.when(qi <= last)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]                                # (bq, 1)
-        delta = delta_ref[0]                            # (bq, 1)
+        q, do = q_ref[0], do_ref[0]
+        p, ds = _p_and_ds(q, k_ref[0], v_ref[0], do, lse_ref[0],
+                          delta_ref[0], qi, kj, **tile)
+        dv_scr[:] = dv_scr[:] + _dot(p.astype(do.dtype), do, (0, 0))
+        dk_scr[:] = dk_scr[:] + _dot(ds.astype(q.dtype), q, (0, 0))
 
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k, offset)
-        p = jnp.exp(s - lse)                            # (bq, bk)
-        p = jnp.where(s > jnp.float32(_NEG_INF / 2), p, jnp.float32(0.0))
-        dv_scr[:] = dv_scr[:] + jnp.dot(p.T, do,
-                                        preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dk_scr[:] = dk_scr[:] + jnp.dot(ds.T, q,
-                                        preferred_element_type=jnp.float32)
-
-    @pl.when(qi == nq - 1)
+    @pl.when(t == group * steps - 1)
     def _finish():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _bwd(scale, causal, window, heads, kv_heads, block_q, block_k, interpret,
+         res, do):
     q, k, v, out, lse = res
-    do = g
     bh, s, d = q.shape
-    sk = k.shape[1]
-    bq = _fit_block(s, block_q)
-    bk = _fit_block(sk, block_k)
-    nq = s // bq
-    nk = sk // bk
+    bkv, sk, _ = k.shape
+    g = _geometry(q, k, block_q, block_k, heads, kv_heads, causal, window)
+    bq, bk, nq, nk = g["bq"], g["bk"], g["nq"], g["nk"]
+    tile = dict(scale=scale, causal=causal, window=window, block_q=bq,
+                block_k=bk, offset=g["offset"])
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)             # (bh, s, 1)
     lse3 = lse[:, :, None]                              # (bh, s, 1)
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, nk=nk, offset=sk - s),
-        grid=(bh, nq, nk),
+        functools.partial(_bwd_dq_kernel, nk=nk, steps=g["k_steps"], **tile),
+        grid=(bh, nq, g["k_steps"]),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, _I0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, _I0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, _I0)),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, _I0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, _I0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, _I0)),
+            pl.BlockSpec((1, bq, d), g["q_map"]),
+            pl.BlockSpec((1, bk, d), g["k_map"]),
+            pl.BlockSpec((1, bk, d), g["k_map"]),
+            pl.BlockSpec((1, bq, d), g["q_map"]),
+            pl.BlockSpec((1, bq, 1), g["q_map"]),
+            pl.BlockSpec((1, bq, 1), g["q_map"]),
         ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, _I0)),
+        out_specs=pl.BlockSpec((1, bq, d), g["q_map"]),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse3, delta)
 
+    group, q_steps = g["group"], g["q_steps"]
+
+    def q_map(b, j, t):
+        first, last = _q_range(j, bq, bk, g["offset"], causal, window, nq)
+        head = _div(b, kv_heads) * heads + _rem(b, kv_heads) * group \
+            + _div(t, q_steps)
+        return head, _tile(first, last, _rem(t, q_steps)), _I0
+
+    def k_map(b, j, t):
+        return b, j, _I0
+
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, nq=nq, offset=sk - s),
-        grid=(bh, nk, nq),
+        functools.partial(_bwd_dkv_kernel, nq=nq, steps=q_steps, group=group,
+                          **tile),
+        grid=(bkv, nk, group * q_steps),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, _I0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, _I0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, _I0)),
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, _I0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, _I0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, _I0)),
+            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bk, d), k_map),
+            pl.BlockSpec((1, bk, d), k_map),
+            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bq, 1), q_map),
+            pl.BlockSpec((1, bq, 1), q_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, _I0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, _I0)),
+            pl.BlockSpec((1, bk, d), k_map),
+            pl.BlockSpec((1, bk, d), k_map),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((bkv, sk, d), k.dtype),
+            jax.ShapeDtypeStruct((bkv, sk, d), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse3, delta)
     return dq, dk, dv
 
@@ -305,23 +404,25 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, g):
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=128)
-def _make_attn(scale, causal, block_q, block_k, interpret):
+def _make_attn(scale, causal, block_q, block_k, interpret, window=None,
+               heads=1, kv_heads=1):
     """One custom_vjp function per static-param tuple — cached so eager
     callers hit JAX's trace cache instead of re-tracing the kernels every
-    invocation."""
+    invocation.  ``heads`` and ``kv_heads`` matter only where they differ
+    (equal, each row of the flattened queries reads its own row of keys)."""
+    static = (scale, causal, window, heads, kv_heads, block_q, block_k,
+              interpret)
+
     @jax.custom_vjp
     def _attn(qf, kf, vf):
-        out, _ = _fwd(qf, kf, vf, scale, causal, block_q, block_k,
-                      interpret)
-        return out
+        return _fwd(qf, kf, vf, *static)[0]
 
     def _attn_fwd(qf, kf, vf):
-        out, lse = _fwd(qf, kf, vf, scale, causal, block_q, block_k,
-                        interpret)
+        out, lse = _fwd(qf, kf, vf, *static)
         return out, (qf, kf, vf, out, lse)
 
     def _attn_bwd(res, g):
-        return _bwd(scale, causal, block_q, block_k, interpret, res, g)
+        return _bwd(*static, res, g)
 
     _attn.defvjp(_attn_fwd, _attn_bwd)
     return _attn
@@ -329,35 +430,50 @@ def _make_attn(scale, causal, block_q, block_k, interpret):
 
 def flash_attention(q, k, v, causal=False, scale: Optional[float] = None,
                     block_q=None, block_k=None, interpret=None,
-                    use_pallas=None):
-    """Flash attention over (B, H, S, D) tensors.
+                    use_pallas=None, window: Optional[int] = None):
+    """Flash attention: q is (B, H, S, D), k and v (B, Hkv, Sk, D) with Hkv
+    dividing H (each key/value head serves H / Hkv consecutive query heads).
 
-    Returns softmax(QKᵀ·scale [+ causal mask]) V without materializing
-    the score matrix.  Differentiable.
+    Returns softmax(QKᵀ·scale [+ mask]) V without materializing the score
+    matrix.  Differentiable.  ``causal`` masks keys j > i + (Sk - S);
+    ``window`` (needs ``causal``) also masks keys j <= i + (Sk - S) - window,
+    so each query sees at most ``window`` keys, itself included.  Tiles the
+    mask rules out are neither fetched nor computed.
 
     Backend policy (round-4 measurement, docs/PERF.md): on TPU the stock
     XLA fused attention (`jax.nn.dot_product_attention`) beat this
     module's Pallas kernels (5.8 vs 6.3 ms at 2048/8/128), so the XLA
-    path is the DEFAULT; the Pallas kernels remain behind
-    ``use_pallas=True`` (and keep serving ring attention's per-shard
-    block compute, where the blockwise-update formulation is required).
+    path is the DEFAULT for plain attention; the Pallas kernels remain
+    behind ``use_pallas=True`` (and keep serving ring attention's
+    per-shard block compute, where the blockwise-update formulation is
+    required).  With a window or grouped heads the kernels ARE the path:
+    the XLA form materialises H x S x Sk scores, which a long sequence
+    cannot hold, and computes what the window masks out.
     Interpret-mode (non-TPU backends) keeps Pallas so the kernels stay
     CPU-tested.
     """
     b, h, s, d = q.shape
-    sk = k.shape[2]
+    hkv, sk = k.shape[1], k.shape[2]
+    if h % hkv or k.shape != v.shape:
+        raise ValueError("flash_attention: %d query heads over key/value of "
+                         "shapes %s, %s" % (h, k.shape, v.shape))
+    if window is not None and (not causal or window < 1):
+        raise ValueError("flash_attention: window=%r needs causal=True and "
+                         "at least 1" % (window,))
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
         interpret = _backend.pallas_interpret()
     if use_pallas is None:
-        use_pallas = interpret  # real-chip default: XLA fused attention
+        # real-chip default: XLA fused attention, where it can express it
+        use_pallas = interpret or window is not None or hkv != h
     if not use_pallas:
         # jax.nn.dot_product_attention is (B, S, H, D)
         out = jax.nn.dot_product_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), scale=float(scale),
-            is_causal=bool(causal))
+            is_causal=bool(causal),
+            local_window_size=None if window is None else (window - 1, 0))
         return out.transpose(0, 2, 1, 3)
 
     if block_q is None or block_k is None:
@@ -369,10 +485,15 @@ def flash_attention(q, k, v, causal=False, scale: Optional[float] = None,
         block_q = bq_d if block_q is None else block_q
         block_k = bk_d if block_k is None else block_k
     qf = q.reshape(b * h, s, d)
-    kf = k.reshape(b * h, sk, d)
-    vf = v.reshape(b * h, sk, d)
+    kf = k.reshape(b * hkv, sk, d)
+    vf = v.reshape(b * hkv, sk, d)
+    extra = {}
+    if window is not None:
+        extra["window"] = int(window)
+    if hkv != h:
+        extra.update(heads=h, kv_heads=hkv)
     _attn = _make_attn(float(scale), bool(causal), int(block_q),
-                       int(block_k), bool(interpret))
+                       int(block_k), bool(interpret), **extra)
     return _attn(qf, kf, vf).reshape(b, h, s, d)
 
 
@@ -382,10 +503,13 @@ from ..ops.registry import register as _register_op  # noqa: E402
 
 @_register_op("_contrib_flash_attention", num_inputs=3)
 def _flash_attention_op(q, k, v, causal=False, scale=None, block_q=None,
-                        block_k=None):
+                        block_k=None, window=None):
     """Fused attention op (the TPU answer to
-    _contrib_interleaved_matmul_selfatt_* in transformer.cc)."""
+    _contrib_interleaved_matmul_selfatt_* in transformer.cc); ``window`` and
+    fewer key/value heads than query heads as ``flash_attention`` takes
+    them."""
     return flash_attention(
         q, k, v, causal=bool(causal), scale=scale,
         block_q=None if block_q is None else int(block_q),
-        block_k=None if block_k is None else int(block_k))
+        block_k=None if block_k is None else int(block_k),
+        window=None if window is None else int(window))

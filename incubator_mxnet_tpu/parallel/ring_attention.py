@@ -32,14 +32,23 @@ __all__ = ["attention_reference", "ring_attention", "ulysses_attention",
            "sharded_self_attention"]
 
 
-def attention_reference(q, k, v, causal=False, scale=None):
-    """Dense softmax attention (correctness oracle). q,k,v: (B,H,S,D)."""
+def attention_reference(q, k, v, causal=False, scale=None, window=None):
+    """Dense softmax attention (correctness oracle). q: (B,H,S,D); k, v:
+    (B,Hkv,Sk,D), each key/value head serving H // Hkv consecutive query
+    heads.  ``window`` (with ``causal``) keeps only the last ``window`` keys
+    a query may see, itself included."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = jnp.repeat(k, group, 1), jnp.repeat(v, group, 1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
         qlen, klen = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((qlen, klen), bool), klen - qlen)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((qlen, klen), bool),
+                              klen - qlen - window)
         s = jnp.where(mask, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)

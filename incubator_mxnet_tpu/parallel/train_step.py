@@ -236,7 +236,8 @@ class FunctionalOptimizer:
                 rescale_grad=self.rescale_grad,
                 clip_gradient=self.clip_gradient)
             return w, (m2, v2)
-        # lamb / adamw
+        # lamb / adamw: one direction (bias-corrected Adam plus decoupled
+        # weight decay); lamb scales it by its trust ratio, adamw does not
         mean, var = s
         gw, m2, v2 = _oops._lamb_phase1(p, g, mean, var, beta1=self.beta1,
                                         beta2=self.beta2,
@@ -244,6 +245,8 @@ class FunctionalOptimizer:
                                         t=step_count, wd=self.wd,
                                         rescale_grad=self.rescale_grad,
                                         clip_gradient=self.clip_gradient)
+        if self.name == "adamw":
+            return p - self.lr * gw, (m2, v2)
         w = _oops._lamb_phase2(p, gw, None, lr=self.lr)
         return w, (m2, v2)
 

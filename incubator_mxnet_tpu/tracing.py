@@ -24,9 +24,18 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-__all__ = ["TraceContext", "current_trace", "push_trace", "pop_trace"]
+__all__ = ["TraceContext", "current_trace", "push_trace", "pop_trace",
+           "REMAT_KEEP"]
 
 _STATE = threading.local()
+
+#: the ``jax.ad_checkpoint.checkpoint_name`` of what a block's remat region
+#: (``hybridize(remat=True)``) keeps instead of recomputing: discrete
+#: decisions such as a router's chosen experts.  The recomputed forward is
+#: another fusion with other roundings, and a decision made again near a tie
+#: comes out differently: the backward pass would then differentiate a
+#: forward that was never run.
+REMAT_KEEP = "remat_keep"
 
 def _pop_hooks() -> List[Any]:
     """Per-thread observers called with the popped TraceContext on every

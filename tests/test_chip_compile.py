@@ -181,6 +181,50 @@ def test_flash_attention_fwd_bwd_compiles_for_v5e(one_chip, mosaic):
     assert compiled.as_text().count("tpu_custom_call") == 3
 
 
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_flash_window_and_grouped_heads_compile_for_v5e(one_chip, mosaic,
+                                                        window):
+    """The decoder cell's attention at its real size: 8,192 tokens, 32 query
+    heads over 4 key/value heads of 128, tiles of 1024 x 1024, with and
+    without the window of 2,048; forward, dq and dk/dv kernels.  With a
+    window or grouped heads the Pallas kernels are the default path."""
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash.flash_attention(
+            q, k, v, causal=True, window=window, block_q=1024,
+            block_k=1024).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, k).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    # no (S, S) score array in the program
+    assert not re.search(r"\[(\d+,)*8192,8192\]", text)
+
+
+def test_grouped_expert_product_is_the_compilers_own_kernel(one_chip, mosaic):
+    """``lax.ragged_dot`` over the held experts at the published widths
+    becomes XLA's grouped-product kernel (a custom call it names
+    ``ragged-dot-none``, which the benchmark's metrics read by that name),
+    not a dense product over every expert."""
+    from incubator_mxnet_tpu.parallel import moe
+
+    rows = jax.ShapeDtypeStruct((65536, 2048), jnp.bfloat16,
+                                sharding=one_chip)
+    w13 = jax.ShapeDtypeStruct((16, 2048, 1024), jnp.bfloat16,
+                               sharding=one_chip)
+    w2 = jax.ShapeDtypeStruct((16, 1024, 2048), jnp.bfloat16,
+                              sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    text = jax.jit(moe.moe_experts).lower(
+        rows, w13, w13, w2, sizes).compile().as_text()
+    assert len(re.findall(r'op_name="ragged-dot-none"', text)) == 3
+    assert not re.search(r" (dot|convolution)\(", text)
+
+
 def test_stem_maxpool_has_no_pallas_form(one_chip, mosaic):
     """The stem max-pool (256,64,112,112) window 3x3 stride 2 is on jnp by
     construction: the argmax-carrying Pallas forward never compiled — W

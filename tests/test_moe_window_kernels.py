@@ -1,0 +1,261 @@
+"""Window and grouped heads in the flash kernels (forward and both backward
+kernels, against the masked dense ``attention_reference``), and the no-drop
+expert layer of a chip that holds a share of the experts
+(``parallel/moe.py``): against a loop over tokens, under a router forced onto
+one expert, its gradients, and the eight shares that add up to the uncut
+layer.  Pallas runs in interpret mode here; sizes are small enough that the
+file takes well under a minute."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import incubator_mxnet_tpu as mx
+from incubator_mxnet_tpu.gluon.model_zoo import text
+from incubator_mxnet_tpu.parallel import flash_attention, moe
+from incubator_mxnet_tpu.parallel.ring_attention import attention_reference
+from perfbench.references import trinity_mini as ref
+
+#: what the plain reference's expert layer reads of a configuration
+_CFG = dict(num_experts_per_tok=2, route_norm=True, route_scale=2.826)
+
+
+# ---------------------------------------------------------------------------
+# flash attention: window and grouped heads
+# ---------------------------------------------------------------------------
+
+def _qkv(h=4, hkv=2, s=64, sk=None, d=16, seed=0):
+    rng = np.random.RandomState(seed)
+    return (jnp.asarray(rng.normal(size=(1, h, s, d)), jnp.float32),
+            jnp.asarray(rng.normal(size=(1, hkv, sk or s, d)), jnp.float32),
+            jnp.asarray(rng.normal(size=(1, hkv, sk or s, d)), jnp.float32))
+
+
+@pytest.mark.parametrize("window", [8, 16, 24, 100],
+                         ids=["below_block", "one_block", "above_block",
+                              "above_sequence"])
+def test_flash_window_and_grouped_heads_forward_and_backward(window):
+    q, k, v = _qkv()
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=16, block_k=16)
+
+    def dense(q, k, v):
+        return attention_reference(q, k, v, causal=True, window=window)
+
+    want = dense(q, k, v)
+    np.testing.assert_allclose(flash(q, k, v), want, rtol=2e-5, atol=2e-5)
+    weight = jnp.cos(want)   # some cotangent that is not all ones
+    got = jax.grad(lambda *a: (flash(*a) * weight).sum(), (0, 1, 2))(q, k, v)
+    exp = jax.grad(lambda *a: (dense(*a) * weight).sum(), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, exp):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
+def test_flash_grouped_heads_without_a_mask_and_with_longer_keys():
+    q, k, v = _qkv(h=6, hkv=3, s=16, sk=48)
+    for causal, window in ((False, None), (True, None), (True, 20)):
+        got = flash_attention(q, k, v, causal=causal, window=window,
+                              block_q=8, block_k=16)
+        want = attention_reference(q, k, v, causal=causal, window=window)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_flash_window_walks_only_the_tiles_the_mask_admits():
+    from incubator_mxnet_tpu.parallel.flash_attention import _steps
+
+    # 8,192 keys in tiles of 512, queries in tiles of 256: a window of 2,048
+    # touches 6 key tiles at most, where the causal mask alone walks all 16
+    assert _steps(16, 256, 512, 2048) == 6 and _steps(16, 256, 512, None) == 16
+    assert _steps(4, 16, 16, 100) == 4
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(*_qkv(), causal=False, window=8)
+    with pytest.raises(ValueError, match="query heads"):
+        flash_attention(*_qkv(h=4, hkv=3))
+
+
+def test_flash_attention_op_takes_window_and_grouped_heads():
+    q, k, v = _qkv(s=32)
+    got = mx.nd.contrib.flash_attention(
+        mx.nd.NDArray(q), mx.nd.NDArray(k), mx.nd.NDArray(v), causal=True,
+        window=8, block_q=16, block_k=16)
+    want = attention_reference(q, k, v, causal=True, window=8)
+    np.testing.assert_allclose(got.asnumpy(), want, rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the expert layer
+# ---------------------------------------------------------------------------
+
+def _expert_weights(e=8, d=16, f=8, seed=0):
+    rng = np.random.RandomState(seed)
+    return dict(
+        router=jnp.asarray(rng.normal(size=(e, d)) * 0.5, jnp.float32),
+        bias=jnp.asarray(rng.normal(size=(e,)) * 0.01, jnp.float32),
+        w1=jnp.asarray(rng.normal(size=(e, d, f)) * 0.3, jnp.float32),
+        w3=jnp.asarray(rng.normal(size=(e, d, f)) * 0.3, jnp.float32),
+        w2=jnp.asarray(rng.normal(size=(e, f, d)) * 0.3, jnp.float32))
+
+
+def _layer(x, w, held, top_k=2):
+    """Router, then the held experts' part."""
+    first, count = held
+    mine = [w[k][first:first + count] for k in ("w1", "w3", "w2")]
+    weights, sel, counts = moe.moe_route(
+        x, w["router"], w["bias"], top_k=top_k, route_norm=True,
+        route_scale=2.826)
+    rows, sizes, row, order = moe.moe_dispatch(x, sel, experts_held=held)
+    assert rows.shape[0] == x.shape[0] * top_k   # room for every assignment
+    ys = moe.moe_experts(rows, *mine, sizes)
+    return moe.moe_combine(ys, weights, sizes, row, order), counts, sizes
+
+
+def _token_loop(x, w, held, top_k=2):
+    """Token by token, choice by choice: the held experts' part."""
+    first, count = held
+    x_, w_ = np.asarray(x, np.float64), {k: np.asarray(v, np.float64)
+                                         for k, v in w.items()}
+    out = np.zeros_like(x_)
+    for t, row in enumerate(x_):
+        scores = 1.0 / (1.0 + np.exp(-(w_["router"] @ row)))
+        chosen = np.argsort(-(scores + w_["bias"]), kind="stable")[:top_k]
+        weight = scores[chosen] / (scores[chosen].sum() + 1e-20) * 2.826
+        for e, c in zip(chosen, weight):
+            if first <= e < first + count:
+                gate = row @ w_["w1"][e]
+                h = gate / (1.0 + np.exp(-gate)) * (row @ w_["w3"][e])
+                out[t] += c * (h @ w_["w2"][e])
+    return out
+
+
+@pytest.mark.parametrize("held", [(0, 8), (2, 4), (7, 1)])
+def test_expert_layer_matches_a_loop_over_tokens(held):
+    w = _expert_weights()
+    x = jnp.asarray(np.random.RandomState(1).normal(size=(40, 16)),
+                    jnp.float32)
+    got, counts, sizes = _layer(x, w, held)
+    np.testing.assert_allclose(got, _token_loop(x, w, held), rtol=1e-4,
+                               atol=1e-5)
+    assert float(counts.sum()) == 40 * 2
+    np.testing.assert_array_equal(
+        np.asarray(sizes), np.asarray(counts)[held[0]:held[0] + held[1]])
+
+
+def test_expert_layer_drops_nothing_when_every_token_goes_to_one_expert():
+    w = _expert_weights()
+    # the selection bias forces experts 3 and 5 on every token
+    w["bias"] = w["bias"].at[jnp.array([3, 5])].set(10.0)
+    x = jnp.asarray(np.random.RandomState(2).normal(size=(40, 16)),
+                    jnp.float32)
+    got, counts, sizes = _layer(x, w, (2, 4))
+    assert np.asarray(counts).tolist() == [0, 0, 0, 40, 0, 40, 0, 0]
+    assert np.asarray(sizes).tolist() == [0, 40, 0, 40]
+    np.testing.assert_allclose(got, _token_loop(x, w, (2, 4)), rtol=1e-4,
+                               atol=1e-5)
+    # the bias chose, the scores weigh: the weights do not hold the 10.0
+    weights, _, _ = moe.moe_route(x, w["router"], w["bias"], top_k=2,
+                                  route_norm=True, route_scale=1.0)
+    np.testing.assert_allclose(weights.sum(-1), 1.0, rtol=1e-5)
+
+
+def test_expert_layer_gradients_match_dense_autodiff():
+    w = _expert_weights()
+    x = jnp.asarray(np.random.RandomState(3).normal(size=(24, 16)),
+                    jnp.float32)
+
+    def dense(x, w):
+        p = {"router_weight": w["router"], "bias": w["bias"],
+             "w1": w["w1"][2:6], "w3": w["w3"][2:6], "w2": w["w2"][2:6],
+             "shared_w1_weight": jnp.zeros((8, 16)),
+             "shared_w3_weight": jnp.zeros((8, 16)),
+             "shared_w2_weight": jnp.zeros((16, 8))}
+        return ref.expert_ffn(p, "", x, dict(_CFG, experts_held=(2, 4)))[0]
+
+    weight = jnp.cos(dense(x, w))
+    want = jax.grad(lambda x, w: (dense(x, w) * weight).sum(), (0, 1))(x, w)
+    got = jax.grad(lambda x, w: (_layer(x, w, (2, 4))[0] * weight).sum(),
+                   (0, 1))(x, w)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer():
+    """What each of eight chips computes of one expert layer (its own
+    experts' part, plus the shared expert that every chip computes alike),
+    with the shared expert counted once, is the uncut reference's layer."""
+    net, full = [], None
+    cfg = dict(_CFG, experts_held=(0, 16))
+    for chip in range(8):
+        held = (2 * chip, 2)
+        block = text.ExpertFFN(32, 16, 2, 16, experts_held=held,
+                               route_scale=2.826, prefix="moe_")
+        block.initialize(init=mx.init.Xavier())
+        net.append((held, block))
+    x = mx.nd.array(np.random.RandomState(4).normal(size=(2, 12, 32)))
+    rng = np.random.RandomState(5)
+    whole = {"router_weight": rng.normal(size=(16, 32)) * 0.3,
+             "bias": rng.normal(size=(16,)) * 0.01,
+             "w1": rng.normal(size=(16, 32, 16)) * 0.3,
+             "w3": rng.normal(size=(16, 32, 16)) * 0.3,
+             "w2": rng.normal(size=(16, 16, 32)) * 0.3,
+             "shared_w1_weight": rng.normal(size=(16, 32)) * 0.3,
+             "shared_w3_weight": rng.normal(size=(16, 32)) * 0.3,
+             "shared_w2_weight": rng.normal(size=(32, 16)) * 0.3}
+    whole = {k: jnp.asarray(v, jnp.float32) for k, v in whole.items()}
+    total = 0.0
+    for (first, count), block in net:
+        block(x)    # resolves the deferred shapes
+        for p in block.collect_params().values():
+            name = p.name[len(block.prefix):]
+            if name == "counts":
+                continue
+            value = whole[name]
+            p.set_data(value[first:first + count]
+                       if name in ("w1", "w3", "w2") else value)
+        total = total + block(x).asnumpy()
+    shared = ref.gated_ffn(x._data.reshape(-1, 32), whole["shared_w1_weight"],
+                           whole["shared_w3_weight"],
+                           whole["shared_w2_weight"]).reshape(2, 12, 32)
+    want, _ = ref.expert_ffn(whole, "", x._data.reshape(-1, 32), cfg)
+    np.testing.assert_allclose(total - 7 * np.asarray(shared),
+                               np.asarray(want).reshape(2, 12, 32),
+                               rtol=1e-4, atol=1e-5)
+
+
+
+
+def test_the_reference_follows_another_routers_choice_only_within_its_margin():
+    """The reference routes by its own scores; a token's forced set is
+    taken only where it is a top-k within ``eps`` of them, else refused."""
+    w = _expert_weights()
+    x = jnp.asarray(np.random.RandomState(6).normal(size=(24, 16)),
+                    jnp.float32)
+    cfg = dict(_CFG, experts_held=(0, 8))
+    weights, sel, facts = ref.route(x, w["router"], w["bias"], cfg)
+    assert float(facts["moved"]) == 0.0 and not facts["refused"].any()
+    scores = np.asarray(facts["scores"] + w["bias"])
+    # its own choice in another order: taken, nothing moved or refused
+    same = ref.route(x, w["router"], w["bias"], cfg, forced=sel[:, ::-1],
+                     eps=0.0)
+    np.testing.assert_allclose(same[0][:, ::-1], weights, rtol=1e-6)
+    assert float(same[2]["moved"]) == 0.0 and not same[2]["refused"].any()
+    # the second choice of six tokens swapped for each token's THIRD best:
+    # taken where the third is within eps of the second, else refused
+    third = np.argsort(-scores, -1)[:, 2]
+    other = sel.at[:6, 1].set(jnp.asarray(third[:6], sel.dtype))
+    gap = np.sort(scores, -1)[:6, -2] - np.sort(scores, -1)[:6, -3]
+    eps = float(np.sort(gap)[2] + np.sort(gap)[3]) / 2     # three within
+    _, taken, facts = ref.route(x, w["router"], w["bias"], cfg, forced=other,
+                                eps=eps)
+    assert float(facts["moved"]) == pytest.approx(6 / 48)
+    assert float(facts["refused"][2]) == pytest.approx(3 / 24)
+    for t in range(6):
+        want = other[t] if gap[t] <= eps else sel[t]
+        assert sorted(np.asarray(taken[t])) == sorted(np.asarray(want))
+    np.testing.assert_array_equal(taken[6:], other[6:])
+    # a margin no set meets: the reference's own choice everywhere
+    free = ref.route(x, w["router"], w["bias"], cfg, forced=other,
+                     eps=-jnp.inf)
+    np.testing.assert_array_equal(free[1], sel)
+    np.testing.assert_allclose(free[0], weights, rtol=1e-6)

@@ -353,7 +353,39 @@ def _hints():
         "_foreach": None,
         "_while_loop": None,
     }
+    h.update(_decoder_hints())
     return h
+
+
+def _decoder_hints():
+    """The decoder family's ops, drawn from a stream of their own so that
+    the draws of every other case stay what they were.  The expert layer
+    (parallel/moe.py): 6 tokens, 2 of 4 experts a token, experts 1-2 held,
+    rows sorted by expert."""
+    rng = np.random.RandomState(30)
+
+    def fn(*shape):
+        return rng.normal(0.0, 1.0, shape).astype(np.float32)
+
+    order = rng.permutation(12)
+    return {
+        "_contrib_moe_router": ([fn(6, 5), fn(4, 5), fn(4) * 0.01],
+                                {"top_k": 2, "route_scale": 2.0}),
+        "_contrib_moe_dispatch": (
+            [fn(6, 5), np.stack([rng.permutation(4)[:2]
+                                 for _ in range(6)]).astype(np.int32)],
+            {"experts_held": (1, 2)}),
+        "_contrib_moe_experts": (
+            [fn(12, 5), fn(2, 5, 3), fn(2, 5, 3), fn(2, 3, 5),
+             np.array([4, 3], np.int32)], {}),
+        "_contrib_moe_combine": (
+            [fn(12, 5), rng.uniform(0.3, 1.7, (6, 2)).astype(np.float32),
+             np.array([4, 3], np.int32),
+             np.argsort(order).reshape(6, 2).astype(np.int32),
+             order.astype(np.int32)], {}),
+        "_contrib_rms_norm": ([fn(3, 8), fn(8) + 2.0], {"eps": 1e-5}),
+        "_contrib_rotary": ([fn(2, 6, 8)], {"theta": 100.0}),
+    }
 
 
 # generic candidates tried in order when no hint exists
