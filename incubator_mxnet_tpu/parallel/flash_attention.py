@@ -21,10 +21,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as _np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import _backend
+from ..tracing import REMAT_KEEP
 
 __all__ = ["flash_attention"]
 
@@ -418,7 +420,12 @@ def _make_attn(scale, causal, block_q, block_k, interpret, window=None,
         return _fwd(qf, kf, vf, *static)[0]
 
     def _attn_fwd(qf, kf, vf):
+        # a block's remat region keeps what is named REMAT_KEEP: with the
+        # kernel's two results kept (lse in its compact (B*H, S) form), the
+        # recomputed forward has no reader for flash_fwd and drops it
         out, lse = _fwd(qf, kf, vf, *static)
+        out = checkpoint_name(out, REMAT_KEEP)
+        lse = checkpoint_name(lse, REMAT_KEEP)
         return out, (qf, kf, vf, out, lse)
 
     def _attn_bwd(res, g):

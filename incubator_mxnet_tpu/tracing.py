@@ -30,11 +30,13 @@ __all__ = ["TraceContext", "current_trace", "push_trace", "pop_trace",
 _STATE = threading.local()
 
 #: the ``jax.ad_checkpoint.checkpoint_name`` of what a block's remat region
-#: (``hybridize(remat=True)``) keeps instead of recomputing: discrete
-#: decisions such as a router's chosen experts.  The recomputed forward is
+#: (``hybridize(remat=True)``) keeps instead of recomputing.  Discrete
+#: decisions such as a router's chosen experts: the recomputed forward is
 #: another fusion with other roundings, and a decision made again near a tie
-#: comes out differently: the backward pass would then differentiate a
-#: forward that was never run.
+#: comes out differently, so the backward pass would differentiate a forward
+#: that was never run.  And what is dear to make and cheap to hold: flash
+#: attention's ``out`` and ``lse`` (docs/PROFILING.md has the list and the
+#: rule for adding to it: milliseconds spared per GB held, read on the chip).
 REMAT_KEEP = "remat_keep"
 
 def _pop_hooks() -> List[Any]:

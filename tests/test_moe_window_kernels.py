@@ -5,6 +5,8 @@ expert layer of a chip that holds a share of the experts
 one expert, its gradients, and the eight shares that add up to the uncut
 layer.  Pallas runs in interpret mode here; sizes are small enough that the
 file takes well under a minute."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,7 @@ import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu.gluon.model_zoo import text
 from incubator_mxnet_tpu.parallel import flash_attention, moe
 from incubator_mxnet_tpu.parallel.ring_attention import attention_reference
+from incubator_mxnet_tpu.tracing import REMAT_KEEP
 from perfbench.references import trinity_mini as ref
 
 #: what the plain reference's expert layer reads of a configuration
@@ -51,6 +54,90 @@ def test_flash_window_and_grouped_heads_forward_and_backward(window):
     exp = jax.grad(lambda *a: (dense(*a) * weight).sum(), (0, 1, 2))(q, k, v)
     for a, b in zip(got, exp):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
+def _projected(window, weight):
+    """Attention behind a projection of q, so that a remat region has
+    something to make again before the kernels."""
+    def f(q, k, v):
+        out = flash_attention(jnp.tanh(q @ weight), k, v, causal=True,
+                              window=window, block_q=16, block_k=16)
+        return jnp.sin(out).sum()
+    return f
+
+
+def _kernel_calls(fn, *args):
+    text_of = str(jax.make_jaxpr(jax.grad(fn, (0, 1, 2)))(*args))
+    return tuple(len(re.findall(r"name=%s\b" % kernel, text_of))
+                 for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+
+
+@pytest.mark.parametrize("window,hkv", [(8, 2), (None, 2), (24, 4)],
+                         ids=["window_grouped", "full_grouped",
+                              "window_own_heads"])
+def test_a_region_that_keeps_the_named_runs_flash_forward_once(window, hkv,
+                                                                capsys):
+    """Under ``jax.checkpoint`` with the policy of a block's remat region
+    (``HybridBlock._forward_remat``) the kernels' ``out`` and ``lse`` are
+    kept: the gradients are those of the call outside any region, from one
+    ``flash_fwd`` where a region without the policy runs two; and the ``lse``
+    kept is the compact (B*H, S) one, not the kernel's (B*H, S, 1), which the
+    TPU's tiled layout pads to 128 lanes."""
+    q, k, v = _qkv(hkv=hkv)
+    f = _projected(window, jnp.asarray(
+        np.random.RandomState(1).normal(size=(16, 16)) * 0.3, jnp.float32))
+    kept = jax.checkpoint(
+        f, policy=jax.checkpoint_policies.save_only_these_names(REMAT_KEEP))
+    want = jax.grad(f, (0, 1, 2))(q, k, v)
+    got = jax.grad(kept, (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+    assert _kernel_calls(f, q, k, v) == (1, 1, 1)
+    assert _kernel_calls(kept, q, k, v) == (1, 1, 1)
+    assert _kernel_calls(jax.checkpoint(f), q, k, v) == (2, 1, 1)
+    jax.ad_checkpoint.print_saved_residuals(kept, q, k, v)
+    saved = [line.split()[0] for line in capsys.readouterr().out.splitlines()
+             if "flash_attention" in line]
+    assert sorted(saved) == ["f32[4,64,16]", "f32[4,64]"], saved
+
+
+def test_outside_a_region_the_names_are_all_that_changed():
+    """Outside a remat region ``checkpoint_name`` is the identity: the
+    gradient's program is that of the kernels under an unnamed custom VJP,
+    equation for equation, but for the two ``name`` equations, of which the
+    second (``lse``) is two-dimensional."""
+    from incubator_mxnet_tpu.parallel.flash_attention import (_bwd, _fwd,
+                                                              _make_attn)
+
+    static = (0.25, True, 8, 4, 2, 16, 16, True)
+    q, k, v = (a[0] for a in _qkv())
+
+    @jax.custom_vjp
+    def unnamed(q, k, v):
+        return _fwd(q, k, v, *static)[0]
+
+    def unnamed_fwd(q, k, v):
+        out, lse = _fwd(q, k, v, *static)
+        return out, (q, k, v, out, lse)
+
+    unnamed.defvjp(unnamed_fwd, lambda res, g: _bwd(*static, res, g))
+    named = _make_attn(0.25, True, 16, 16, True, window=8, heads=4,
+                       kv_heads=2)
+
+    def grad_of(attn):
+        return jax.grad(lambda *a: jnp.sin(attn(*a)).sum(), (0, 1, 2))
+
+    def equations(attn):
+        return [(e.primitive.name, [str(o.aval) for o in e.outvars])
+                for e in jax.make_jaxpr(grad_of(attn))(q, k, v).eqns]
+
+    got, want = equations(named), equations(unnamed)
+    names = [e for e in got if e[0] == "name"]
+    assert names == [("name", ["float32[4,64,16]"]),
+                     ("name", ["float32[4,64]"])], names
+    assert [e for e in got if e[0] != "name"] == want
+    for a, b in zip(grad_of(named)(q, k, v), grad_of(unnamed)(q, k, v)):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_flash_grouped_heads_without_a_mask_and_with_longer_keys():

@@ -8,6 +8,7 @@ here; the file takes about a minute and a half, a third of it the bf16
 cell that tells float8 inputs apart."""
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -36,16 +37,21 @@ _CFG = dict(hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
 # the zoo's model against the plain reference
 # ---------------------------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def tiny():
-    """The tiny net with its shapes resolved abstractly, seeded weights by
-    the reference's names, and one batch."""
+def _tiny_net(recompute):
     net = text.afmoe_tiny(experts_held=_HELD, vocab_rows=_ROWS,
-                          recompute=True)
+                          recompute=recompute)
     net.initialize(init=mx.init.Xavier())
     with shape_only_init():
         jax.eval_shape(lambda x: pure_forward(net, [], [], x)[0],
                        jax.ShapeDtypeStruct((2, _SEQ), "int32"))
+    return net
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """The tiny net with its shapes resolved abstractly, seeded weights by
+    the reference's names, and one batch."""
+    net = _tiny_net(recompute=True)
     weights = tt.Weights(net, 3).by_name()
     # norm scales away from one, so that their gradients mean something
     key = jax.random.PRNGKey(5)
@@ -171,14 +177,11 @@ def test_reference_block_by_block_agrees_with_its_loss_differentiated_whole(
                                   p["layer1_moe_bias"])
 
 
-def test_a_recomputed_block_keeps_its_routers_choice(tiny):
-    """Inside a remat region the router's choice is kept, not made again:
-    made again from recomputed scores (another fusion, other roundings) a
-    near-tie falls the other way, and the backward pass differentiates a
-    routing the forward never ran (on the chip the worst expert matrix read
-    0.15-0.18 from the reference for that, PERF.md section 6, PR 30).  So
-    the gradient's program holds ONE top-k an expert layer."""
-    net, weights, x, y = tiny
+@pytest.fixture(scope="module")
+def gradient_program(tiny):
+    """The text of the gradient's program of the tiny net, every block of
+    which is a remat region."""
+    net, weights, x, _ = tiny
     names = tt.short_names(net)
     params = list(names)
 
@@ -186,11 +189,50 @@ def test_a_recomputed_block_keeps_its_routers_choice(tiny):
         logits, _ = pure_forward(net, params, vals, x, training=True)
         return jnp.sum(logits)
 
-    vals = [weights[names[p]] for p in params]
-    text_of = str(jax.make_jaxpr(jax.grad(loss))(vals))
     assert all(layer._flags.get("remat") for layer in net.layers)
+    text_of = str(jax.make_jaxpr(jax.grad(loss))(
+        [weights[names[p]] for p in params]))
     assert "checkpoint" in text_of or "remat" in text_of
-    assert text_of.count("top_k[") == 2, text_of.count("top_k[")
+    return text_of
+
+
+def test_a_recomputed_block_keeps_its_routers_choice(gradient_program):
+    """Inside a remat region the router's choice is kept, not made again:
+    made again from recomputed scores (another fusion, other roundings) a
+    near-tie falls the other way, and the backward pass differentiates a
+    routing the forward never ran (on the chip the worst expert matrix read
+    0.15-0.18 from the reference for that, PERF.md section 6, PR 30).  So
+    the gradient's program holds ONE top-k an expert layer."""
+    assert gradient_program.count("top_k[") == 2
+
+
+def test_a_recomputed_block_runs_attentions_forward_kernel_once(
+        gradient_program):
+    """The flash kernels name their two results ``tracing.REMAT_KEEP``, so a
+    block's remat region keeps ``out`` and ``lse`` and the recomputed forward,
+    left with no reader for them, drops the kernel: the gradient's program
+    holds ONE ``flash_fwd`` an attention layer (two before: a twentieth of
+    the step on the chip, PERF.md section 6, PR 31) and, as before, one of
+    each backward kernel."""
+    calls = {kernel: len(re.findall(r"name=%s\b" % kernel, gradient_program))
+             for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+    assert calls == dict.fromkeys(calls, len(_LAYERS)), calls
+
+
+def test_a_recomputed_net_has_the_gradients_of_the_one_that_keeps_everything(
+        tiny):
+    """The backward kernels of a recomputed block read the forward pass's own
+    ``out`` and ``lse`` beside a recomputed q, k and v: in float32 that is
+    the gradient of the net that recomputes nothing, to rounding."""
+    net, weights, x, y = tiny
+    plain = _tiny_net(recompute=False)
+    assert not any(layer._flags.get("remat") for layer in plain.layers)
+    loss, grads = _model_loss_and_grads(net, weights, x, y)
+    want, want_grads = _model_loss_and_grads(plain, weights, x, y)
+    assert abs(loss - want) <= 1e-6 * want
+    assert set(grads) == set(want_grads)
+    worst, leaf = _worst(grads, want_grads)
+    assert worst < 2e-5, (leaf, worst)
 
 
 def test_recompute_survives_a_plain_hybridize(tiny):
