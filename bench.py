@@ -35,8 +35,7 @@ import time
 BASELINE_IMG_S = 363.69  # V100 fp32 batch-128 training (perf.md:254)
 # The default train step — ONE definition: run_train defaults, argparse
 # help, the main() fallbacks and chip_smoke.py's train phase all read
-# these.  Stock BatchNorm and no passes: the composition a chip has run.
-DEFAULT_GHOST_BN = 0
+# these.  No passes: the composition a chip has run.
 DEFAULT_PASSES = ""
 DEFAULT_ZERO = 1  # ZeRO-1 on dp meshes (a no-op without --mesh-dp)
 # ResNet-50 at 224x224: ~4.09 GFLOPs forward per image; training step
@@ -142,9 +141,8 @@ def parse_passes(passes):
     return tuple(s.strip() for s in (passes or ()) if s.strip())
 
 
-def build_train_step(image_size=224, classes=1000, ghost_bn=DEFAULT_GHOST_BN,
-                     passes=DEFAULT_PASSES, mesh=None, zero=DEFAULT_ZERO,
-                     s2d_stem=False, multi_precision=True,
+def build_train_step(image_size=224, classes=1000, passes=DEFAULT_PASSES,
+                     mesh=None, zero=DEFAULT_ZERO, multi_precision=True,
                      loss_scale="dynamic", compute_dtype="bfloat16",
                      learning_rate=0.1, cost="report", seed=0):
     """The benchmark's train step, built in ONE place: ResNet-50 v1 from a
@@ -159,12 +157,7 @@ def build_train_step(image_size=224, classes=1000, ghost_bn=DEFAULT_GHOST_BN,
     from incubator_mxnet_tpu.parallel import make_train_step
 
     mx.random.seed(seed)
-    # ghost_bn > 0 swaps in the fused ghost-BN layers (parallel/
-    # fused_bn.py, explicit bn_group semantics); s2d_stem is the
-    # MODEL-level stem rewrite (the space_to_depth pass does the same to
-    # the stock stem at trace time)
-    net = vision.resnet50_v1(classes=classes, s2d_stem=s2d_stem,
-                             ghost_bn=ghost_bn)
+    net = vision.resnet50_v1(classes=classes)
     net.initialize(init=mx.init.Xavier())
     net.shape_init((1, 3, image_size, image_size))  # no eager pass
     if not isinstance(passes, dict):
@@ -198,8 +191,7 @@ def dp_mesh(n):
 
 def run_train(batch_size=256, image_size=224, chunks=8, chunk_iters=5,
               compute_dtype="bfloat16", data="synthetic",
-              record_format=".jpg", s2d_stem=False,
-              ghost_bn=DEFAULT_GHOST_BN, passes=DEFAULT_PASSES, mesh_dp=0,
+              record_format=".jpg", passes=DEFAULT_PASSES, mesh_dp=0,
               zero=DEFAULT_ZERO, multi_precision=True, loss_scale="dynamic",
               schedule_config=None):
     jax = setup_jax()
@@ -244,8 +236,8 @@ def run_train(batch_size=256, image_size=224, chunks=8, chunk_iters=5,
         log("dp=%d mesh (zero=%s)" % (mesh_dp, zero))
     t = time.time()
     net, step = build_train_step(
-        image_size=image_size, ghost_bn=ghost_bn, passes=pass_arg, mesh=mesh,
-        zero=zero, s2d_stem=s2d_stem, multi_precision=multi_precision,
+        image_size=image_size, passes=pass_arg, mesh=mesh,
+        zero=zero, multi_precision=multi_precision,
         loss_scale=loss_scale, compute_dtype=compute_dtype)
     log("build+param-init+shape_init %.1fs" % (time.time() - t))
     if sched_extra:
@@ -298,16 +290,16 @@ def run_train(batch_size=256, image_size=224, chunks=8, chunk_iters=5,
            pred["pred_img_per_sec"], rep.peak_bytes / 1e6))
 
     # UNFUSED reference prediction for a composed step: the lever-
-    # attribution delta (fused vs stock-BN byte count).  One abstract
-    # trace, no compile (~seconds).
-    if ghost_bn or pass_names:
+    # attribution delta (byte count with and without the passes).  One
+    # abstract trace, no compile (~seconds).
+    if pass_names:
         t = time.time()
         # same mesh/zero knobs as the fused step: the delta must
         # attribute the byte diet, not dp-sharding differences.
         # passes=() explicit: MXTPU_PASSES must not leak into the
         # unfused baseline the delta is judged by
         _, ref_step = build_train_step(
-            image_size=image_size, ghost_bn=0, passes=(), mesh=mesh,
+            image_size=image_size, passes=(), mesh=mesh,
             zero=zero, multi_precision=multi_precision,
             loss_scale=loss_scale, compute_dtype=compute_dtype, cost="off")
         xs = jax.ShapeDtypeStruct(
@@ -367,8 +359,7 @@ def run_train(batch_size=256, image_size=224, chunks=8, chunk_iters=5,
         log("chunk %d: %d iters in %.3fs -> %.1f img/s (step %.1f ms)"
             % (c, chunk_iters, dt, img_s, 1e3 * dt / chunk_iters))
         extra = {"batch": batch_size, "dtype": compute_dtype, "data": data,
-                 "s2d_stem": bool(s2d_stem),
-                 "bn": ("ghost%d" % ghost_bn) if ghost_bn else "batch",
+                 "bn": "batch",
                  "passes": list(pass_names),
                  "schedule_hash": step.schedule_hash,
                  "multi_precision": bool(multi_precision),
@@ -709,13 +700,6 @@ def main():
     ap.add_argument("--chunks", type=int, default=8)
     ap.add_argument("--data", default="synthetic",
                     choices=["synthetic", "recordio"])
-    ap.add_argument("--s2d-stem", action="store_true",
-                    help="space-to-depth stem conv (exact MODEL-level "
-                         "rewrite; the space_to_depth pass covers the "
-                         "stock stem at trace time)")
-    ap.add_argument("--ghost-bn", type=int, default=DEFAULT_GHOST_BN,
-                    help="fused ghost-BN group size (default %d; 0 = "
-                         "stock BatchNorm)" % DEFAULT_GHOST_BN)
     ap.add_argument("--passes", default=DEFAULT_PASSES,
                     help="comma-separated graftpass names for the train "
                          "step (default %r; '' = none)" % DEFAULT_PASSES)
@@ -770,8 +754,7 @@ def main():
     else:
         run_train(batch_size=args.batch or 256, image_size=args.image_size,
                   chunks=args.chunks, data=args.data,
-                  record_format=args.record_format, s2d_stem=args.s2d_stem,
-                  ghost_bn=args.ghost_bn, passes=args.passes,
+                  record_format=args.record_format, passes=args.passes,
                   mesh_dp=args.mesh_dp, zero=args.zero,
                   multi_precision=not args.no_multi_precision,
                   loss_scale=loss_scale,

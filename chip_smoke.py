@@ -7,7 +7,7 @@ One process, one chip, the entry points a user calls: ``vision.resnet50_v1``
 ``--seed``.  No number printed here is a result: times are information for
 whoever looks next, the checks are what the run is for.
 
-    python chip_smoke.py              # one chip: device, train, kernels, serve
+    python chip_smoke.py              # one chip: device, train, serve
     python chip_smoke.py --multichip  # four chips: dp=4 ZeRO-1 step vs one chip
 
 Contract with the driver: the last line of stdout is
@@ -32,14 +32,6 @@ T0 = time.time()
 
 #: what jax reports for every XLA program it builds or loads from its cache
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-#: bf16 tolerance of the ``kernels`` phase: relative L2 error per output.
-#: bf16 keeps 8 significant bits, so two independently rounded results of
-#: the same f32 arithmetic differ by about 1.6e-3 in this norm; a dropped
-#: or misrouted term shows as 1e-1 or more.  L2 and not max-norm: at 2e8
-#: elements a handful of ReLU masks flip on f32 rounding of a pre-activation
-#: next to zero, which moves single elements by a whole cotangent.
-KERNEL_REL_L2 = 1e-2
 
 #: first-step loss of the dp=4 step against the one-chip step.  The step is
 #: one GSPMD program over the GLOBAL batch, so BatchNorm reduces its
@@ -170,24 +162,6 @@ def _run_steps(step, x, y, n):
     return losses, ms
 
 
-def _site_plan(site):
-    """(plan as ``fused_bn.plan_describe`` gives it, one-line name)."""
-    import jax.numpy as jnp
-
-    from incubator_mxnet_tpu.parallel import fused_bn
-
-    shape, dtype, group, has_res, donate, dual = site
-    d = fused_bn.plan_describe(*shape, jnp.dtype(dtype).itemsize, group,
-                               has_res, dual)
-    return d, "%s res=%d donate=%d dual=%d fwd=%s bwd=%s" % (
-        "x".join(map(str, shape)), has_res, donate, dual, d["variant"],
-        d["bwd"])
-
-
-def _on_pallas(plan):
-    return plan["variant"] != "jnp" or plan["bwd"] != "jnp"
-
-
 def _check_losses(losses, what):
     import math
 
@@ -198,50 +172,30 @@ def _check_losses(losses, what):
           % (what, losses[0], losses[-3:]))
 
 
-def train(batch, image_size, steps, platform, ghost_bn=None, passes=None,
-          classes=1000, seed=0, **step_kwargs):
-    """The train step ``bench.py`` runs with no flags (``ghost_bn`` /
-    ``passes`` None = its defaults), AOT-compiled, 1 warm-up + ``steps``
-    steps on one fixed batch.  Returns what it measured and the BN sites
-    the trace went through.  ``step_kwargs`` reach
+def train(batch, image_size, steps, platform, passes=None, classes=1000,
+          seed=0, **step_kwargs):
+    """The train step ``bench.py`` runs with no flags (``passes`` None = its
+    default), AOT-compiled, 1 warm-up + ``steps`` steps on one fixed batch.
+    Returns what it measured.  ``step_kwargs`` reach
     ``bench.build_train_step`` (a toy-sized run needs a smaller
     ``learning_rate`` than the recipe's to see its loss fall)."""
     import jax
 
     import bench
-    from incubator_mxnet_tpu.parallel import fused_bn
 
-    ghost_bn = bench.DEFAULT_GHOST_BN if ghost_bn is None else ghost_bn
     passes = bench.DEFAULT_PASSES if passes is None else passes
-    log("train: resnet50_v1 classes=%d batch=%d %dpx bf16, ghost_bn=%d "
-        "passes=%r" % (classes, batch, image_size, ghost_bn, passes))
+    log("train: resnet50_v1 classes=%d batch=%d %dpx bf16, passes=%r"
+        % (classes, batch, image_size, passes))
     _, step = bench.build_train_step(image_size=image_size, classes=classes,
-                                     ghost_bn=ghost_bn, passes=passes,
-                                     seed=seed, **step_kwargs)
+                                     passes=passes, seed=seed, **step_kwargs)
     x, y = _batch(batch, image_size, classes, seed)
-    with fused_bn.record_sites() as sites:
-        times = step.aot_compile(x, y)
+    times = step.aot_compile(x, y)
     log("train: trace %.1fs, compile %.1fs" % (times["trace"],
                                                times["compile"]))
-
-    n_pallas = 0
-    for site in sites:
-        plan, name = _site_plan(site)
-        n_pallas += _on_pallas(plan)
-        log("train: BN site %s" % name)
-    n_calls = step.compiled.as_text().count("tpu_custom_call")
     mem = step.compiled.memory_analysis()
-    log("train: %d BN site(s), %d planned on Pallas, %d tpu_custom_call in "
-        "the compiled step; the compiler counts %.2f GB of arguments + "
-        "%.2f GB of temporaries"
-        % (len(sites), n_pallas, n_calls, mem.argument_size_in_bytes / 1e9,
-           mem.temp_size_in_bytes / 1e9))
-    if platform == "tpu":
-        # (on the cpu the same kernels run through the interpreter and
-        # leave no custom call behind)
-        check(n_calls > 0 or n_pallas == 0,
-              "train: %d site(s) planned on Pallas but the compiled step "
-              "has no tpu_custom_call" % n_pallas)
+    log("train: the compiler counts %.2f GB of arguments + %.2f GB of "
+        "temporaries"
+        % (mem.argument_size_in_bytes / 1e9, mem.temp_size_in_bytes / 1e9))
 
     counter = _compile_counter()
     first, warm_ms = _run_steps(step, x, y, 1)
@@ -271,112 +225,12 @@ def train(batch, image_size, steps, platform, ghost_bn=None, passes=None,
         % (med, 1e3 * batch / med, steps, jax.devices()[0].device_kind,
            len(params), len(state), platform,
            "not reported" if peak is None else "%.2f GB" % (peak / 1e9)))
-    return {"sites": sites, "losses": losses, "step_ms": med,
-            "trace_s": times["trace"], "compile_s": times["compile"],
-            "custom_calls": n_calls, "peak_bytes": peak}
+    return {"losses": losses, "step_ms": med, "trace_s": times["trace"],
+            "compile_s": times["compile"], "peak_bytes": peak}
 
 
 # ---------------------------------------------------------------------------
-# phase 3: kernels
-# ---------------------------------------------------------------------------
-
-def _rel_l2(a, b):
-    import jax.numpy as jnp
-
-    a = a.astype(jnp.float32).ravel()
-    b = b.astype(jnp.float32).ravel()
-    return jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b), 1e-30)
-
-
-def kernels(sites, seed=0, eps=1e-5):
-    """Every BN site whose plan says Pallas, in either direction: the
-    kernel's outputs and gradients against the jnp formulation
-    (``fused_bn._gbn_ref``) on the same inputs, within KERNEL_REL_L2.
-    Sites planned wholly on jnp are not kernels and are passed over;
-    with no site at all (the stock-BatchNorm default) the phase has
-    nothing to do, by construction.  ``sites`` is what ``train`` returns:
-    ``kernels(train(..., ghost_bn=16)["sites"])`` checks the ghost-BN
-    kernels from a scratch driver.
-    Per site, one program makes the inputs from the seed on the device and
-    a second runs both formulations on them and reduces to seven error
-    norms.  Two programs, because both paths must read the SAME rounded
-    bf16 values: inside one program XLA may keep the f32 draw for the jnp
-    path (excess precision) while the kernel's operand is rounded."""
-    import jax
-    import jax.numpy as jnp
-
-    from incubator_mxnet_tpu.parallel import fused_bn
-
-    bad, n_checked = [], 0
-    for i, site in enumerate(sites):
-        shape, dtype, group, has_res, donate, dual = site
-        d, name = _site_plan(site)
-        if not _on_pallas(d):
-            log("kernels: %s — jnp by plan, no kernel to check" % name)
-            continue
-
-        def make_inputs(key):
-            ks = jax.random.split(key, 6)
-
-            def draw(k):
-                return jax.random.normal(k, shape, jnp.float32).astype(dtype)
-
-            gamma = jax.random.uniform(ks[2], shape[1:2], jnp.float32,
-                                       0.5, 1.5)
-            beta = 0.2 * jax.random.normal(ks[3], shape[1:2], jnp.float32)
-            # w1, w2: fixed cotangents, one per output position of a
-            # dual exit
-            return (draw(ks[0]), gamma, beta, draw(ks[1]) if has_res else
-                    None, draw(ks[4]), draw(ks[5]) if dual else None)
-
-        def site_errors(x, gamma, beta, res, w1, w2):
-            def weighted(y, w):
-                return (y.astype(jnp.float32) * w.astype(jnp.float32)).sum()
-
-            def via_kernel(x, gamma, beta, res):
-                out = fused_bn.ghost_bn_act(x, gamma, beta, res, eps, "relu",
-                                            group, donate_residual=donate,
-                                            dual_out=dual)
-                loss = weighted(out[0], w1)
-                if dual:
-                    loss = loss + weighted(out[1], w2)
-                return loss, (out[0], out[-2], out[-1])
-
-            def via_jnp(x, gamma, beta, res):
-                y, m, v = fused_bn._gbn_ref(x, gamma, beta, res, eps, "relu",
-                                            d["group"])
-                loss = weighted(y, w1 if w2 is None else
-                                w1.astype(jnp.float32)
-                                + w2.astype(jnp.float32))
-                return loss, (y, m, v)
-
-            argnums = (0, 1, 2, 3) if has_res else (0, 1, 2)
-            (_, aux_g), grads_g = jax.value_and_grad(
-                via_kernel, argnums, has_aux=True)(x, gamma, beta, res)
-            (_, aux_w), grads_w = jax.value_and_grad(
-                via_jnp, argnums, has_aux=True)(x, gamma, beta, res)
-            names = ["y", "mean", "var", "dx", "dgamma", "dbeta", "dres"]
-            return {k: _rel_l2(a, b) for k, a, b in
-                    zip(names, aux_g + grads_g, aux_w + grads_w)}
-
-        inputs = jax.jit(make_inputs)(jax.random.PRNGKey(seed + i))
-        errs = {k: float(v)
-                for k, v in jax.jit(site_errors)(*inputs).items()}
-        worst = max(errs, key=errs.get)
-        n_checked += 1
-        ok = all(e <= KERNEL_REL_L2 for e in errs.values())
-        log("kernels: %s — %s (worst %s %.2e)"
-            % (name, "ok" if ok else "MISMATCH", worst, errs[worst]))
-        if not ok:
-            bad.append((name, errs))
-    log("kernels: %d site(s) checked against the jnp reference at rel-L2 "
-        "<= %g, %d mismatched" % (n_checked, KERNEL_REL_L2, len(bad)))
-    check(not bad, "kernels: %r" % (bad,))
-    return n_checked
-
-
-# ---------------------------------------------------------------------------
-# phase 4: serve
+# phase 3: serve
 # ---------------------------------------------------------------------------
 
 class _KeepFutures:
@@ -560,11 +414,8 @@ def main(argv=None):
         multichip(dp=4, batch=256, image_size=224, steps=3, platform="tpu",
                   seed=args.seed)
     else:
-        out = train(batch=256, image_size=224, steps=8, platform="tpu",
-                    seed=args.seed)
-        # the Pallas sites of the step that just ran: none while the
-        # default composition is stock BatchNorm
-        kernels(out["sites"], seed=args.seed)
+        train(batch=256, image_size=224, steps=8, platform="tpu",
+              seed=args.seed)
         serve(buckets=(16, 64), image_size=224, n_requests=64, qps=100.0,
               n_check=8, seed=args.seed)
     log("all phases passed")

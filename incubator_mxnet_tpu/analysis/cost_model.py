@@ -156,12 +156,11 @@ _RANDOM = {"random_bits", "random_wrap", "random_unwrap", "random_split",
 #: hand-written kernels (Pallas custom calls).  A custom call is a real
 #: pass barrier — XLA cannot fuse compute into or out of it — but by
 #: construction it reads each operand and writes each output exactly
-#: ONCE (the single-read contract the fused ghost-BN kernels exist
-#: for, parallel/fused_bn.py).  The old model filed these under
-#: "other"→elementwise, where the sibling co-fusion rule sometimes
-#: merged their reads with unrelated elementwise groups and the view
-#: transposes around them were sometimes charged as full passes —
-#: both wrong in opposite directions.
+#: ONCE (the flash attention kernels, parallel/flash_attention.py).
+#: The old model filed these under "other"→elementwise, where the
+#: sibling co-fusion rule sometimes merged their reads with unrelated
+#: elementwise groups and the view transposes around them were
+#: sometimes charged as full passes — both wrong in opposite directions.
 _CUSTOM = {"pallas_call", "tpu_custom_call", "custom_call"}
 
 #: classes: "mxu" "elem" "layout" "reduce" "sg" "coll" "concat" "random"
@@ -206,10 +205,8 @@ _CATEGORY = {"mxu": "conv", "elem": "elementwise", "layout": "elementwise",
 #: materialize (they read real buffers, not fused producers).  custom
 #: kernels belong here: XLA cannot fuse elementwise compute across a
 #: custom-call boundary — but NOT in _FORCES_LAYOUT below: pure layout
-#: views feeding a Pallas kernel are the documented bitcast discipline
-#: (parallel/fused_bn.py chooses its (L, N, C)/(L, C, N) views so the
-#: "transpose" is a relabeling of the conv's native TPU layout) and
-#: fold into the kernel's DMA, exactly like layout-into-MXU fusion.
+#: views feeding a Pallas kernel fold into the kernel's DMA, exactly
+#: like layout-into-MXU fusion.
 _FORCES_OPERANDS = ("mxu", "sg", "coll", "control", "custom")
 
 #: pure data movement feeding an MXU op is folded into its input by
@@ -306,11 +303,9 @@ def _eqn_flops(eqn) -> float:
         return float(max((_aval_elems(v.aval) for v in eqn.outvars),
                          default=0))
     if cls == "custom":
-        # elementwise-grade arithmetic per element touched: the shipped
-        # kernels (fused BN, flash attention bwd reductions) do a
-        # handful of VPU ops per element — they are byte-bound by
-        # design, so a coarse per-element figure keeps the compute
-        # roofline honest without decoding the kernel body
+        # elementwise-grade arithmetic per element touched: a coarse
+        # per-element figure keeps the compute roofline honest without
+        # decoding the kernel body
         return float(sum(_aval_elems(v.aval) for v in eqn.outvars)
                      + sum(_aval_elems(v.aval) for v in eqn.invars
                            if not isinstance(v, jex_core.Literal)))
@@ -423,8 +418,8 @@ class CostReport:
     opt_state_bytes_per_device: float = 0.0
     #: GL202 raw material, structurally: one (bytes, n_reads, shape,
     #: dtype) row per large intermediate read by 2+ fusable groups —
-    #: the model's accounting of the avoidable multi-pass traffic the
-    #: fused ghost-BN kernels remove (custom-kernel reads never count).
+    #: the model's accounting of the avoidable multi-pass traffic
+    #: (custom-kernel reads never count).
     #: The census keeps the worst 32 rows; ``multipass_extra_bytes``
     #: is the UNtruncated total of the repeats (bytes x (reads - 1)).
     rereads: List[Tuple[float, int, tuple, str]] = field(
@@ -916,9 +911,8 @@ class _Walker:
                             # compute traffic, while a second
                             # reduction/elementwise pass over a big
                             # intermediate is exactly the avoidable
-                            # multi-pass BN pattern (and a custom
-                            # kernel's own read is the single-read fix
-                            # GL202's hint prescribes, never counted)
+                            # multi-pass BN pattern (a custom
+                            # kernel's own read is never counted)
                             reread_count[leaf] += 1
                         else:
                             seen_cats[leaf] = set()  # pass barrier
@@ -1173,12 +1167,11 @@ def check_cost(report: CostReport,
             % (len(rereads), total_extra / 1e9, worst[2], worst[3],
                worst[1]),
             where="graftcost fusion model",
-            hint="a kernel that keeps the tensor resident (fused "
-                 "ghost-BN, docs/PERF.md lever 1) removes the repeat "
-                 "passes; when the repeats are DUPLICATE computations "
-                 "(BN stats traced twice), the cse_dead_aux graftpass "
-                 "merges them at trace time — passes=('cse_dead_aux',) "
-                 "/ MXTPU_PASSES (docs/PASSES.md)"))
+            hint="when the repeats are DUPLICATE computations (BN stats "
+                 "traced twice), the cse_dead_aux graftpass merges them "
+                 "at trace time — passes=('cse_dead_aux',) / "
+                 "MXTPU_PASSES (docs/PASSES.md); the rest is what XLA's "
+                 "own fusion leaves (no shipped kernel removes it)"))
     rf = report.roofline()
     if rf["comm_s"] > max(rf["compute_s"], rf["hbm_s"]) and rf["comm_s"] > 0:
         diags.append(Diagnostic(

@@ -101,11 +101,10 @@ from jax.extend import core as jex_core
 from .diagnostics import Diagnostic, LintError, LintReport, Severity
 
 __all__ = ["AmpBf16Pass", "Contract", "CseDeadAuxPass", "GraftPass",
-           "MaxPoolBwdMaskPass", "PASS_REGISTRY", "PassContext",
-           "PassManager", "PassReceipt", "PassResult", "PassSchedule",
-           "PassSite", "PipelineResult", "QuantizeWeightsPass",
-           "SpaceToDepthPass", "get_pass", "register_pass",
-           "resolve_passes", "resolve_schedule"]
+           "PASS_REGISTRY", "PassContext", "PassManager", "PassReceipt",
+           "PassResult", "PassSchedule", "PassSite", "PipelineResult",
+           "QuantizeWeightsPass", "SpaceToDepthPass", "get_pass",
+           "register_pass", "resolve_passes", "resolve_schedule"]
 
 
 # ---------------------------------------------------------------------------
@@ -1084,12 +1083,17 @@ class SpaceToDepthPass(GraftPass):
             p = eqn.params
             (pt, pb), (pl, pr) = [tuple(q) for q in p["padding"]]
             o, c, k, _ = w.shape
-            xp = jnp.pad(x, ((0, 0), (0, 0), (pt, pb), (pl, pr)))
+            # lax.pad, the bare primitive: jnp.pad traces as a nested jit,
+            # which the cost walk prices as a pass of its own (GL303 then
+            # refuses the rewrite) where XLA folds the pad into the conv
+            xp = jax.lax.pad(x, np.zeros((), x.dtype),
+                             ((0, 0, 0), (0, 0, 0), (pt, pb, 0), (pl, pr, 0)))
             n, _, h, wd = xp.shape
             z = xp.reshape(n, c, h // 2, 2, wd // 2, 2) \
                   .transpose(0, 1, 3, 5, 2, 4) \
                   .reshape(n, c * 4, h // 2, wd // 2)
-            wp = jnp.pad(w, ((0, 0), (0, 0), (0, 1), (0, 1)))
+            wp = jax.lax.pad(w, np.zeros((), w.dtype),
+                             ((0, 0, 0), (0, 0, 0), (0, 1, 0), (0, 1, 0)))
             kk = (k + 1) // 2
             w2 = wp.reshape(o, c, kk, 2, kk, 2) \
                    .transpose(0, 1, 3, 5, 2, 4) \
@@ -1107,120 +1111,6 @@ class SpaceToDepthPass(GraftPass):
         return PassResult(new_closed, hits=hits[0],
                           notes="%d stride-2 conv(s) rewritten to "
                                 "space-to-depth stride-1 form" % hits[0])
-
-
-# ---------------------------------------------------------------------------
-# shipped pass: mask-based max-pool backward
-# ---------------------------------------------------------------------------
-
-class MaxPoolBwdMaskPass(GraftPass):
-    """Replace ``select_and_scatter_add`` — XLA's max-pool backward —
-    with the shifted-window mask form: one strided view per in-window
-    offset, the winner being the FIRST argmax in row-major window scan
-    order, the gradient routed to it by an elementwise select/pad chain.
-
-    **On the v5e this is the SLOWER form** (PERF.md section 6, PR 28:
-    15.9 ms against 1.48 ms for ResNet-50's stem pool, 23.5 ms against
-    3.97 ms for VGG-16's five), and ``op.Pooling`` no longer builds it:
-    applied to a zoo net the pass now turns the faster backward into the
-    slower one, and its own cost receipt refuses it there (GL303, more
-    HBM traffic).  No cell and no default step runs it; ROADMAP 1.4
-    leaves its deletion to a ``simplicity`` issue.
-
-    First-argmax is exactly ``select_and_scatter_add``'s GE-select tie
-    rule (and the reference's pool.h unpool semantics), so the rewrite
-    is ``bit_exact``: contributions from distinct windows land on
-    disjoint-or-added positions, and on the exact-arithmetic dyadic
-    probe — which is FULL of ties, the hard case — addition is
-    associative, so a mis-routed mask (a shifted winner, a
-    tie-broadcast) shows up bitwise in the GL301 probe and is refused
-    with zero compiles.
-
-    The forward ``reduce_window_max`` this needs is re-emitted and
-    CSE-merged with the forward pass's own (both the jaxpr walker and
-    XLA dedup it), so the bwd costs reads of (X, out, gY) and the dX
-    write — no scatter, no padded operand materialization.
-
-    The rewrite applies to ANY traced program that carries the scatter
-    (``op.Pooling``, raw ``lax.reduce_window`` code, imported graphs),
-    with the PR-12 contract machinery vouching for it.
-    """
-
-    name = "maxpool_bwd_mask"
-    contract = Contract.bit_exact()
-    description = ("select_and_scatter_add (max-pool backward) -> "
-                   "shifted-window first-argmax mask (fused elementwise "
-                   "passes, no scatter; PERF.md lever c)")
-
-    site_aware = True
-
-    #: test-only fault knob (see ops.nn.shifted_window_unpool): a
-    #: non-zero shift mis-routes the gradient; the GL301 probe must
-    #: catch it.  Never set outside tests.
-    _shift_mask = 0
-
-    def enumerate_sites(self, closed_jaxpr, ctx) -> List[PassSite]:
-        sites, walk = [], _SiteWalk()
-        for eqn in closed_jaxpr.jaxpr.eqns:
-            if eqn.primitive.name != "select_and_scatter_add":
-                continue
-            sid = walk.sid("select_and_scatter_add")
-            if not self._match(eqn):
-                continue
-            fl, by = _eqn_weight(eqn)
-            sites.append(PassSite(
-                sid, detail="maxpool bwd %s window %s"
-                % (eqn.invars[1].aval.str_short(),
-                   "x".join(str(d) for d in
-                            eqn.params["window_dimensions"])),
-                flops=fl, hbm_bytes=by))
-        return sites
-
-    def _match(self, eqn) -> bool:
-        if eqn.primitive.name != "select_and_scatter_add":
-            return False
-        p = eqn.params
-        if getattr(p.get("select_prim"), "name", "") != "ge":
-            return False  # only the max-pool (GE-select) form
-        operand = eqn.invars[1].aval
-        return jnp.issubdtype(operand.dtype, jnp.floating)
-
-    def run(self, closed_jaxpr, ctx: PassContext) -> Optional[PassResult]:
-        import jax.numpy as _jnp
-        from jax import lax
-
-        from ..ops.nn import shifted_window_unpool
-
-        hits = [0]
-        shift = self._shift_mask
-        walk = _SiteWalk()
-
-        def rule(eqn, invals):
-            if eqn.primitive.name != "select_and_scatter_add":
-                return None
-            sid = walk.sid("select_and_scatter_add")
-            if not self._match(eqn) or not _site_on(ctx, sid):
-                return None
-            source, operand = invals
-            p = eqn.params
-            window = tuple(p["window_dimensions"])
-            strides = tuple(p["window_strides"])
-            padding = tuple(tuple(q) for q in p["padding"])
-            out = lax.reduce_window(operand, -_jnp.inf, lax.max,
-                                    window, strides, padding)
-            dx = shifted_window_unpool(operand, out, source, window,
-                                       strides, padding,
-                                       _shift_mask=shift)
-            hits[0] += 1
-            return [dx.astype(eqn.outvars[0].aval.dtype)]
-
-        new_closed = retrace(closed_jaxpr, rule)
-        if not hits[0]:
-            return None
-        return PassResult(new_closed, hits=hits[0],
-                          notes="%d select-and-scatter max-pool "
-                                "backward(s) rewritten to the "
-                                "shifted-window mask form" % hits[0])
 
 
 # ---------------------------------------------------------------------------
@@ -1318,7 +1208,6 @@ PASS_REGISTRY: Dict[str, Callable[[], GraftPass]] = {
     "quantize_int4": lambda: QuantizeWeightsPass(bits=4),
     "amp_bf16": AmpBf16Pass,
     "space_to_depth": SpaceToDepthPass,
-    "maxpool_bwd_mask": MaxPoolBwdMaskPass,
     "cse_dead_aux": CseDeadAuxPass,
 }
 

@@ -436,11 +436,7 @@ class HybridBlock(Block):
     def forward(self, x, *args):
         from ..symbol.symbol import Symbol
 
-        # a dual-output ghost block hands its successor a TUPLE of
-        # (conv_path, shortcut) — dispatch on its first element; tuple
-        # inputs skip the CachedOp fast path (eager trace handles them)
-        head = x[0] if isinstance(x, tuple) and x else x
-        if isinstance(head, Symbol):
+        if isinstance(x, Symbol):
             # symbolic trace (export/quantize path): params become vars and
             # nested blocks recurse through this same branch
             from .. import symbol as sym_mod

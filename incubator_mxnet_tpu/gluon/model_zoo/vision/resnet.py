@@ -21,102 +21,29 @@ def _conv3x3(channels, stride, in_channels):
                      use_bias=False, in_channels=in_channels)
 
 
-class _S2DStemConv(HybridBlock):
-    """Space-to-depth rewrite of the 7x7/s2 stem conv (exact same math).
-
-    The 7x7 stride-2 conv over 3 input channels wastes most of the MXU's
-    128 lanes and runs HBM-inefficiently (measured 330-460 GiB/s vs the
-    ~700 the rest of the net sustains — docs/PERF.md).  Packing 2x2 input
-    pixels into channels turns it into a dense 4x4 stride-1 conv over 12
-    channels: out[y,x] = sum_ky,kx w[ky,kx] * in_pad[2y+ky, 2x+kx] is
-    re-indexed with ky = 2*kY + dy so the kernel taps become (kY, dy)
-    pairs over the packed channel c*4 + dy*2 + dx.
-
-    The parameter keeps the stock (channels, 3, 7, 7) shape and the
-    rearrangement runs in-program where XLA folds it into the conv weights
-    at negligible cost.  NOTE: gluon name-based checkpoints do NOT
-    interchange directly with the plain-stem model (this block's prefix is
-    `_s2dstemconv*` and the global conv2dN counter shifts by one) — move
-    weights between the variants by position/shape, not by name.
-    """
-
-    def __init__(self, channels, in_channels=3, **kwargs):
-        super().__init__(**kwargs)
-        self._channels = channels
-        self._in_channels = in_channels
-        with self.name_scope():
-            self.weight = self.params.get(
-                "weight", shape=(channels, in_channels, 7, 7),
-                allow_deferred_init=True)
-
-    def hybrid_forward(self, F, x, weight):  # noqa: N803
-        # input: pad H/W by 3 (the conv's own padding), pack 2x2 -> channels
-        x = F.pad(x, mode="constant", constant_value=0.0,
-                  pad_width=(0, 0, 0, 0, 3, 3, 3, 3))            # (N,C,H+6,W+6)
-        x = F.reshape(x, shape=(0, 0, -4, -1, 2, -4, -1, 2))     # (N,C,Y,dy,X,dx)
-        x = F.transpose(x, axes=(0, 1, 3, 5, 2, 4))              # (N,C,dy,dx,Y,X)
-        x = F.reshape(F.reshape(x, shape=(0, -3, -2)),
-                      shape=(0, -3, -2))                         # (N,4C,Y,X)
-        # kernel: pad 7->8 taps, split each spatial tap into (kY, dy)
-        w = F.pad(weight, mode="constant", constant_value=0.0,
-                  pad_width=(0, 0, 0, 0, 0, 1, 0, 1))            # (O,C,8,8)
-        w = F.reshape(w, shape=(0, 0, -4, 4, 2, -4, 4, 2))       # (O,C,kY,dy,kX,dx)
-        w = F.transpose(w, axes=(0, 1, 3, 5, 2, 4))              # (O,C,dy,dx,kY,kX)
-        w = F.reshape(F.reshape(w, shape=(0, -3, -2)),
-                      shape=(0, -3, -2))                         # (O,4C,4,4)
-        return F.Convolution(x, w, num_filter=self._channels, kernel=(4, 4),
-                             stride=(1, 1), pad=(0, 0), no_bias=True)
-
-
 class BasicBlockV1(HybridBlock):
     def __init__(self, channels, stride, downsample=False, in_channels=0,
-                 ghost_bn=0, dual_out=False, **kwargs):
+                 **kwargs):
         super().__init__(**kwargs)
-        self._ghost_bn = ghost_bn
-        if ghost_bn:
-            self.conv1 = _conv3x3(channels, stride, in_channels)
-            self.gbn1 = GhostBNReLU(group=ghost_bn)
-            self.conv2 = _conv3x3(channels, 1, channels)
-            # a downsample-shortcut output is consumed ONLY by this
-            # block's fused add: the kernel may write Y over it
-            self.gbn2 = GhostBNReLU(group=ghost_bn,
-                                    donate_residual=downsample,
-                                    dual_out=dual_out)
-            self.body = None
-        else:
-            self.body = nn.HybridSequential()
-            self.body.add(_conv3x3(channels, stride, in_channels))
-            self.body.add(nn.BatchNorm())
-            self.body.add(nn.Activation("relu"))
-            self.body.add(_conv3x3(channels, 1, channels))
-            self.body.add(nn.BatchNorm())
+        self.body = nn.HybridSequential()
+        self.body.add(_conv3x3(channels, stride, in_channels))
+        self.body.add(nn.BatchNorm())
+        self.body.add(nn.Activation("relu"))
+        self.body.add(_conv3x3(channels, 1, channels))
+        self.body.add(nn.BatchNorm())
         if downsample:
             self.downsample = nn.HybridSequential()
             self.downsample.add(nn.Conv2D(channels, kernel_size=1,
                                           strides=stride, use_bias=False,
                                           in_channels=in_channels))
-            # ghost mode keeps the shortcut BN on the fused single-read
-            # path too (no activation on a downsample branch)
-            self.downsample.add(GhostBN(group=ghost_bn) if ghost_bn
-                                else nn.BatchNorm())
+            self.downsample.add(nn.BatchNorm())
         else:
             self.downsample = None
-        if self.body is not None:
-            self.register_child(self.body, "body")
+        self.register_child(self.body, "body")
         if self.downsample is not None:
             self.register_child(self.downsample, "downsample")
 
     def hybrid_forward(self, F, x):  # noqa: N803
-        if self._ghost_bn:
-            # a dual-output predecessor hands us (conv_path, shortcut):
-            # two positions of the SAME tensor whose cotangents the
-            # exit's fused bwd will merge (see GhostBNReLU dual_out)
-            x, shortcut = x if isinstance(x, tuple) else (x, x)
-            residual = shortcut
-            if self.downsample is not None:
-                residual = self.downsample(shortcut)
-            x = self.gbn1(self.conv1(x))
-            return self.gbn2(self.conv2(x), residual)
         residual = x
         x = self.body(x)
         if self.downsample is not None:
@@ -124,198 +51,32 @@ class BasicBlockV1(HybridBlock):
         return F.Activation(x + residual, act_type="relu")
 
 
-class GhostBNReLU(HybridBlock):
-    """Fused ghost-BN(+residual)+ReLU layer (TPU perf variant).
-
-    Same parameter set as ``nn.BatchNorm`` (gamma/beta/running_mean/
-    running_var); forward calls the fused Pallas op
-    (``ops.nn._contrib_GhostBNReLU`` / ``..AddReLU``, kernels in
-    ``parallel/fused_bn.py``) which computes statistics per ghost group in
-    training.  Running stats update from the op's batch-stat outputs (no
-    recompute).  Opt-in via ``ghost_bn=<group>`` on the model zoo resnets.
-
-    ``donate_residual=True`` marks the residual input of the fused
-    add variant as dead after this layer (a downsample-shortcut output
-    nothing else reads) so the kernel can write Y over its VMEM window
-    — never set it for identity shortcuts.  ``track_stats=False``
-    creates NO running-stat parameters and normalizes with ghost batch
-    statistics in every mode (the pipeline-parallel form: aux writes
-    cannot escape the pipelined scan, so a staged block must carry no
-    aux state).
-    """
-
-    _act = "relu"
-
-    def __init__(self, group=0, momentum=0.9, epsilon=1e-5, in_channels=0,
-                 donate_residual=False, track_stats=True, dual_out=False,
-                 **kwargs):
-        super().__init__(**kwargs)
-        self._group = group
-        self._momentum = momentum
-        self._epsilon = epsilon
-        self._donate_residual = bool(donate_residual)
-        self._track_stats = bool(track_stats)
-        self._dual_out = bool(dual_out)
-        shape = (in_channels,)
-        with self.name_scope():
-            self.gamma = self.params.get(
-                "gamma", grad_req="write", shape=shape, init="ones",
-                allow_deferred_init=True)
-            self.beta = self.params.get(
-                "beta", grad_req="write", shape=shape, init="zeros",
-                allow_deferred_init=True)
-            if self._track_stats:
-                self.running_mean = self.params.get(
-                    "running_mean", grad_req="null", shape=shape,
-                    init="zeros", allow_deferred_init=True)
-                self.running_var = self.params.get(
-                    "running_var", grad_req="null", shape=shape,
-                    init="ones", allow_deferred_init=True)
-
-    def infer_shape(self, x, *args):
-        c = x.shape[1]
-        ps = [self.gamma, self.beta]
-        if self._track_stats:
-            ps += [self.running_mean, self.running_var]
-        for p in ps:
-            p.shape = (c,)
-
-    def hybrid_forward(self, F, x, residual=None, *, gamma, beta,
-                       running_mean=None, running_var=None):  # noqa: N803
-        if not self._track_stats:
-            if residual is not None:
-                raise ValueError("track_stats=False has no fused residual "
-                                 "form yet; add the residual outside")
-            op = (F._contrib_GhostBNReLUNS if self._act == "relu"
-                  else F._contrib_GhostBNNS)
-            return op(x, gamma, beta, eps=self._epsilon, group=self._group)
-        if residual is None:
-            if self._act == "relu":
-                out, bm, bv = F._contrib_GhostBNReLU(
-                    x, gamma, beta, running_mean, running_var,
-                    eps=self._epsilon, momentum=self._momentum,
-                    group=self._group)
-            else:
-                out, bm, bv = F._contrib_GhostBN(
-                    x, gamma, beta, running_mean, running_var,
-                    eps=self._epsilon, momentum=self._momentum,
-                    group=self._group)
-        else:
-            if self._act != "relu":
-                raise ValueError(
-                    "the fused residual form is BN+add+ReLU; %s has no "
-                    "activation and no fused add variant — add the "
-                    "residual outside" % type(self).__name__)
-            if self._dual_out:
-                # block-exit join absorption: the same output in two
-                # positions (conv path / shortcut) so the downstream
-                # cotangents stay separate and the fused bwd sums them
-                # on the window load (no materialized add_any join)
-                out, out_sc, bm, bv = F._contrib_GhostBNAddReLUDual(
-                    x, residual, gamma, beta, running_mean, running_var,
-                    eps=self._epsilon, momentum=self._momentum,
-                    group=self._group,
-                    donate_residual=1 if self._donate_residual else 0)
-                self._commit_running(F, running_mean, running_var, bm, bv)
-                return out, out_sc
-            out, bm, bv = F._contrib_GhostBNAddReLU(
-                x, residual, gamma, beta, running_mean, running_var,
-                eps=self._epsilon, momentum=self._momentum,
-                group=self._group,
-                donate_residual=1 if self._donate_residual else 0)
-        self._commit_running(F, running_mean, running_var, bm, bv)
-        return out
-
-    def _commit_running(self, F, running_mean, running_var, bm, bv):
-        from .... import autograd, tracing
-        from ....ops import nn as _opsnn
-
-        if getattr(F, "__is_symbol__", False) or not _opsnn._is_train():
-            return  # symbolic path commits via the executor aux channel
-        if not self._track_stats:
-            return
-        with autograd.pause():
-            # shared running-stat formula (ops.nn._ghost_bn_aux_update) —
-            # identical math on the Gluon, TrainStep and Executor paths
-            upd = _opsnn._ghost_bn_aux_update(
-                [None, None, None, running_mean._data, running_var._data],
-                [None, bm._data, bv._data], momentum=self._momentum)
-            rm, rv = self.running_mean, self.running_var
-            tc = tracing.current_trace()
-            if tc is not None:
-                tc.write_aux(rm, upd[3])
-                tc.write_aux(rv, upd[4])
-            else:
-                rm._data._data = upd[3].astype(rm._data.dtype)
-                rv._data._data = upd[4].astype(rv._data.dtype)
-
-
-class GhostBN(GhostBNReLU):
-    """Fused ghost-BN WITHOUT activation — the downsample-branch norm
-    (a 1x1-conv shortcut is normalized but never rectified).  Keeping
-    the downsample BN on the fused ghost path removes the last stock
-    multi-pass BatchNorm from the ghost_bn ResNet's step program
-    (docs/PERF.md round 19: it was the remaining GL202 offender)."""
-
-    _act = "none"
-
-
 class BottleneckV1(HybridBlock):
     def __init__(self, channels, stride, downsample=False, in_channels=0,
-                 ghost_bn=0, dual_out=False, **kwargs):
+                 **kwargs):
         super().__init__(**kwargs)
-        self._ghost_bn = ghost_bn
-        if ghost_bn:
-            # fused-BN layout: conv -> GhostBNReLU pairs, bottleneck exit
-            # fused as GhostBN+add+ReLU (docs/PERF.md byte-cut plan)
-            self.conv1 = nn.Conv2D(channels // 4, kernel_size=1,
-                                   strides=stride, use_bias=False)
-            self.gbn1 = GhostBNReLU(group=ghost_bn)
-            self.conv2 = _conv3x3(channels // 4, 1, channels // 4)
-            self.gbn2 = GhostBNReLU(group=ghost_bn)
-            self.conv3 = nn.Conv2D(channels, kernel_size=1, strides=1,
-                                   use_bias=False)
-            # a downsample-shortcut output is consumed ONLY by this
-            # block's fused add: the kernel may write Y over it
-            self.gbn3 = GhostBNReLU(group=ghost_bn,
-                                    donate_residual=downsample,
-                                    dual_out=dual_out)
-            self.body = None
-        else:
-            self.body = nn.HybridSequential()
-            self.body.add(nn.Conv2D(channels // 4, kernel_size=1,
-                                    strides=stride))
-            self.body.add(nn.BatchNorm())
-            self.body.add(nn.Activation("relu"))
-            self.body.add(_conv3x3(channels // 4, 1, channels // 4))
-            self.body.add(nn.BatchNorm())
-            self.body.add(nn.Activation("relu"))
-            self.body.add(nn.Conv2D(channels, kernel_size=1, strides=1))
-            self.body.add(nn.BatchNorm())
+        self.body = nn.HybridSequential()
+        self.body.add(nn.Conv2D(channels // 4, kernel_size=1, strides=stride))
+        self.body.add(nn.BatchNorm())
+        self.body.add(nn.Activation("relu"))
+        self.body.add(_conv3x3(channels // 4, 1, channels // 4))
+        self.body.add(nn.BatchNorm())
+        self.body.add(nn.Activation("relu"))
+        self.body.add(nn.Conv2D(channels, kernel_size=1, strides=1))
+        self.body.add(nn.BatchNorm())
         if downsample:
             self.downsample = nn.HybridSequential()
             self.downsample.add(nn.Conv2D(channels, kernel_size=1,
                                           strides=stride, use_bias=False,
                                           in_channels=in_channels))
-            self.downsample.add(GhostBN(group=ghost_bn) if ghost_bn
-                                else nn.BatchNorm())
+            self.downsample.add(nn.BatchNorm())
         else:
             self.downsample = None
-        if self.body is not None:
-            self.register_child(self.body, "body")
+        self.register_child(self.body, "body")
         if self.downsample is not None:
             self.register_child(self.downsample, "downsample")
 
     def hybrid_forward(self, F, x):  # noqa: N803
-        if self._ghost_bn:
-            # a dual-output predecessor hands us (conv_path, shortcut)
-            x, shortcut = x if isinstance(x, tuple) else (x, x)
-            residual = shortcut
-            if self.downsample is not None:
-                residual = self.downsample(shortcut)
-            x = self.gbn1(self.conv1(x))
-            x = self.gbn2(self.conv2(x))
-            return self.gbn3(self.conv3(x), residual)
         residual = x
         x = self.body(x)
         if self.downsample is not None:
@@ -386,52 +147,32 @@ class BottleneckV2(HybridBlock):
 
 class ResNetV1(HybridBlock):
     def __init__(self, block, layers, channels, classes=1000, thumbnail=False,
-                 s2d_stem=False, ghost_bn=0, **kwargs):
+                 **kwargs):
         super().__init__(**kwargs)
         assert len(layers) == len(channels) - 1
         self.features = nn.HybridSequential()
         if thumbnail:
             self.features.add(_conv3x3(channels[0], 1, 0))
         else:
-            if s2d_stem:
-                self.features.add(_S2DStemConv(channels[0]))
-            else:
-                self.features.add(nn.Conv2D(channels[0], 7, 2, 3,
-                                            use_bias=False))
-            if ghost_bn:
-                self.features.add(GhostBNReLU(group=ghost_bn))
-            else:
-                self.features.add(nn.BatchNorm())
-                self.features.add(nn.Activation("relu"))
+            self.features.add(nn.Conv2D(channels[0], 7, 2, 3, use_bias=False))
+            self.features.add(nn.BatchNorm())
+            self.features.add(nn.Activation("relu"))
             self.features.add(nn.MaxPool2D(3, 2, 1))
         for i, num_layer in enumerate(layers):
             stride = 1 if i == 0 else 2
             self.features.add(self._make_layer(
                 block, num_layer, channels[i + 1], stride,
-                in_channels=channels[i], ghost_bn=ghost_bn,
-                last_stage=(i == len(layers) - 1)))
+                in_channels=channels[i]))
         self.features.add(nn.GlobalAvgPool2D())
         self.output = nn.Dense(classes, in_units=channels[-1])
 
     @staticmethod
-    def _make_layer(block, layers, channels, stride, in_channels=0,
-                    ghost_bn=0, last_stage=False):
-        # ghost mode: every block exit except the net's very last one is
-        # dual-output — the next block consumes (conv_path, shortcut)
-        # and the exit's fused bwd absorbs the residual-join add_any
-        # (docs/PERF.md round 20); the final block feeds the global pool
-        # and stays single-output
-        def kw(is_tail):
-            if not ghost_bn:
-                return {}
-            return {"ghost_bn": ghost_bn,
-                    "dual_out": not (last_stage and is_tail)}
+    def _make_layer(block, layers, channels, stride, in_channels=0):
         layer = nn.HybridSequential()
         layer.add(block(channels, stride, channels != in_channels,
-                        in_channels=in_channels, **kw(layers == 1)))
-        for j in range(layers - 1):
-            layer.add(block(channels, 1, False, in_channels=channels,
-                            **kw(j == layers - 2)))
+                        in_channels=in_channels))
+        for _ in range(layers - 1):
+            layer.add(block(channels, 1, False, in_channels=channels))
         return layer
 
     def hybrid_forward(self, F, x):  # noqa: N803

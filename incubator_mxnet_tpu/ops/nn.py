@@ -13,8 +13,6 @@ re-layouts internally for the TPU's native tiling.
 """
 from __future__ import annotations
 
-import itertools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -127,57 +125,6 @@ def _deconvolution(data, weight, bias=None, kernel=None, stride=None, dilate=Non
 # ---------------------------------------------------------------------------
 
 
-def shifted_window_unpool(data, out, g, window, strides, padding,
-                          _shift_mask=0):
-    """Shifted-window mask max-pool backward: route ``g`` to the FIRST
-    argmax of each window (row-major scan order) with elementwise passes
-    instead of XLA's ``select-and-scatter``.  Only the
-    ``maxpool_bwd_mask`` graftpass (analysis/passes.py) builds it;
-    ``op.Pooling`` does not (see ``_pooling``).
-
-    One shifted strided view of the padded input per in-window offset:
-    position p of the padded input contributes to window w iff
-    p = w*stride + offset.  The reference's active Pooling backward
-    (pool.h unpool_max_*_cpu) routes the WHOLE gradient to a single
-    argmax — the first match in row-major window scan order, which is
-    also ``select_and_scatter_add``'s GE-select tie rule, so the result
-    is BIT-exact vs XLA's own gradient (post-ReLU zero ties are common;
-    giving every tie the full gradient would inflate dX by the tie
-    count).  The price is one ``lax.pad`` of the input's shape per
-    offset, which the v5e runs at an eighth of its HBM rate.
-
-    ``_shift_mask`` is a test-only fault knob: a non-zero value offsets
-    the winner index, deliberately mis-routing the gradient — the
-    GL301 contract probe must refuse such a mask.
-    """
-    neg = np.asarray(-jnp.inf, data.dtype)[()]
-    xp = lax.pad(data, neg, [(lo, hi, 0) for lo, hi in padding])
-    offsets = list(itertools.product(*[range(k) for k in window]))
-    noff = len(offsets)
-    views = []
-    first = jnp.full(out.shape, noff, jnp.int32)
-    for lin, offset in enumerate(offsets):
-        # (out-1)*stride + window <= padded dim by reduce_window's output
-        # formula, so every shifted view is in bounds
-        limit = [o + (y - 1) * s + 1
-                 for o, y, s in zip(offset, out.shape, strides)]
-        xs = lax.slice(xp, offset, limit, strides)
-        views.append((offset, limit))
-        first = jnp.minimum(first, jnp.where(xs == out, jnp.int32(lin),
-                                             jnp.int32(noff)))
-    if _shift_mask:
-        first = (first + jnp.int32(_shift_mask)) % jnp.int32(noff)
-    dxp = jnp.zeros(xp.shape, g.dtype)
-    for lin, (offset, limit) in enumerate(views):
-        contrib = jnp.where(first == lin, g, jnp.zeros((), g.dtype))
-        dxp = dxp + lax.pad(contrib, np.asarray(0, g.dtype)[()], [
-            (o, d - l, s - 1)
-            for o, d, l, s in zip(offset, xp.shape, limit, strides)])
-    dx = lax.slice(dxp, [lo for lo, _ in padding],
-                   [d - hi for d, (_, hi) in zip(xp.shape, padding)])
-    return dx.astype(data.dtype)
-
-
 @register("Pooling", aliases=("pool",))
 def _pooling(data, kernel=None, pool_type="max", global_pool=False,
              cudnn_off=False, pooling_convention="valid", stride=None, pad=None,
@@ -214,12 +161,10 @@ def _pooling(data, kernel=None, pool_type="max", global_pool=False,
         init = (-jnp.inf if jnp.issubdtype(data.dtype, jnp.floating)
                 else np.asarray(jnp.iinfo(data.dtype).min, data.dtype)[()])
         # The backward is reduce_window's own transpose, ONE
-        # select-and-scatter a pool: the whole gradient of a window goes to
-        # its first maximum in scan order (pool.h unpool_max_*), and dx is
-        # written once.  Do not replace it with masks and pads
-        # (shifted_window_unpool): on the v5e that took 15.9 ms for
-        # ResNet-50's stem pool and 23.5 ms for VGG-16's five where this
-        # takes 1.48 and 3.97 ms (PERF.md section 6, PR 28).
+        # select-and-scatter a pool, dx written once: 1.48 ms for
+        # ResNet-50's stem pool and 3.97 ms for VGG-16's five on the v5e,
+        # where a backward of masks and pads took 15.9 and 23.5 ms
+        # (PERF.md section 6, PR 28).
         return lax.reduce_window(data, init, lax.max, window, strides, padding)
     if pool_type in ("avg", "sum"):
         summed = lax.reduce_window(data, 0.0 if jnp.issubdtype(
@@ -309,161 +254,6 @@ def _batch_norm_aux_update(in_vals, out_vals, momentum=0.9, axis=1,
 
 OPS["BatchNorm"].aux_update = _batch_norm_aux_update
 OPS["BatchNorm"].mutate_idx = (3, 4)
-
-
-def _ghost_bn_common(data, residual, gamma, beta, moving_mean, moving_var,
-                     eps, group, act="relu", donate_residual=False):
-    """Shared body for the fused ghost-BN ops.  Training: Pallas fused
-    kernel (parallel/fused_bn.py) with group statistics; eval: moving-stat
-    normalize (+add) (+relu) as plain jnp (XLA fuses it fine)."""
-    if _is_train():
-        from ..parallel.fused_bn import ghost_bn_act, ghost_bn_stats_merge
-
-        out, m, v = ghost_bn_act(data, gamma.astype(jnp.float32),
-                                 beta.astype(jnp.float32),
-                                 residual=residual, eps=eps, act=act,
-                                 group=group,
-                                 donate_residual=donate_residual)
-        bm, bv = ghost_bn_stats_merge(m, v)
-        return out, bm, bv
-    inv = lax.rsqrt(moving_var.astype(jnp.float32) + eps)
-    g32 = gamma.astype(jnp.float32)
-    scale = (g32 * inv).reshape(1, -1, 1, 1)
-    shift = (beta.astype(jnp.float32)
-             - moving_mean.astype(jnp.float32) * g32 * inv).reshape(1, -1, 1, 1)
-    y = data.astype(jnp.float32) * scale + shift
-    if residual is not None:
-        y = y + residual.astype(jnp.float32)
-    if act == "relu":
-        y = jnp.maximum(y, 0.0)
-    return (y.astype(data.dtype),
-            moving_mean.astype(jnp.float32), moving_var.astype(jnp.float32))
-
-
-@register("_contrib_GhostBNReLU", num_inputs=5, num_outputs=3,
-          mutate_idx=(3, 4))
-def _ghost_bn_relu(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
-                   momentum=0.9, group=0):
-    """Fused ghost-BN + ReLU (TPU Pallas; see parallel/fused_bn.py).
-
-    Outputs: (out, batch_mean, batch_var) — stats feed the running-average
-    aux update like BatchNorm's (``src/operator/nn/batch_norm.cc:493``
-    stateful forward), with group (ghost) statistics in training.
-    """
-    return _ghost_bn_common(data, None, gamma, beta, moving_mean, moving_var,
-                            float(eps), int(group))
-
-
-@register("_contrib_GhostBNAddReLU", num_inputs=6, num_outputs=3,
-          mutate_idx=(4, 5))
-def _ghost_bn_add_relu(data, residual, gamma, beta, moving_mean, moving_var,
-                       eps=1e-3, momentum=0.9, group=0, donate_residual=0):
-    """Fused ghost-BN + residual add + ReLU (the bottleneck-exit pattern).
-
-    ``donate_residual=1`` declares the residual tensor dead after this
-    op (a downsample-shortcut output, consumed by nothing else): the
-    Pallas fwd writes Y over its VMEM window, which is what lets the
-    56x56x256 block-0 exits fuse at batch 256.  NEVER set it for an
-    identity shortcut — the surrounding program still reads that
-    tensor.
-    """
-    return _ghost_bn_common(data, residual, gamma, beta, moving_mean,
-                            moving_var, float(eps), int(group),
-                            donate_residual=bool(int(donate_residual)))
-
-
-@register("_contrib_GhostBNAddReLUDual", num_inputs=6, num_outputs=4,
-          mutate_idx=(4, 5))
-def _ghost_bn_add_relu_dual(data, residual, gamma, beta, moving_mean,
-                            moving_var, eps=1e-3, momentum=0.9, group=0,
-                            donate_residual=0):
-    """Dual-output fused ghost-BN + residual add + ReLU.
-
-    Outputs ``(out, out_sc, batch_mean, batch_var)`` where ``out_sc`` is
-    the SAME tensor as ``out`` exposed in a second output position: a
-    block exit routes the next block's conv path through ``out`` and its
-    shortcut through ``out_sc``, so autodiff delivers the two cotangents
-    separately and the fused bwd kernel sums them on the VMEM window
-    load — the residual-join add_any (read 2x + write of a full exit
-    tensor per block) disappears from the step program (docs/PERF.md
-    round 20).  Same statistics, aux protocol and ``donate_residual``
-    semantics as ``_contrib_GhostBNAddReLU``.
-    """
-    if _is_train():
-        from ..parallel.fused_bn import ghost_bn_act, ghost_bn_stats_merge
-
-        out, out_sc, m, v = ghost_bn_act(
-            data, gamma.astype(jnp.float32), beta.astype(jnp.float32),
-            residual=residual, eps=float(eps), act="relu", group=int(group),
-            donate_residual=bool(int(donate_residual)), dual_out=True)
-        bm, bv = ghost_bn_stats_merge(m, v)
-        return out, out_sc, bm, bv
-    out, bm, bv = _ghost_bn_common(
-        data, residual, gamma, beta, moving_mean, moving_var, float(eps),
-        int(group), donate_residual=bool(int(donate_residual)))
-    return out, out, bm, bv
-
-
-@register("_contrib_GhostBN", num_inputs=5, num_outputs=3,
-          mutate_idx=(3, 4))
-def _ghost_bn_noact(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
-                    momentum=0.9, group=0):
-    """Fused ghost-BN WITHOUT activation (the downsample-branch BN: a
-    1x1-conv shortcut is normalized but not rectified).  Same group
-    statistics and aux protocol as ``_contrib_GhostBNReLU``."""
-    return _ghost_bn_common(data, None, gamma, beta, moving_mean,
-                            moving_var, float(eps), int(group), act="none")
-
-
-@register("_contrib_GhostBNReLUNS", num_inputs=3, num_outputs=1)
-def _ghost_bn_relu_nostats(data, gamma, beta, eps=1e-3, group=0):
-    """Stats-free fused ghost-BN + ReLU: no running-stat aux state at
-    all (the pipeline-parallel form — aux writes cannot escape the
-    pipelined scan, so a pipelined stage must carry none).  Normalizes
-    with ghost batch statistics in EVERY mode; eval-time consumers that
-    need moving averages want the stateful op instead."""
-    return _ghost_bn_nostats_common(data, gamma, beta, eps, group, "relu")
-
-
-@register("_contrib_GhostBNNS", num_inputs=3, num_outputs=1)
-def _ghost_bn_nostats(data, gamma, beta, eps=1e-3, group=0):
-    """Stats-free fused ghost-BN WITHOUT activation (the pipelined
-    downsample-branch form: normalized, never rectified, no aux
-    state)."""
-    return _ghost_bn_nostats_common(data, gamma, beta, eps, group, "none")
-
-
-def _ghost_bn_nostats_common(data, gamma, beta, eps, group, act):
-    from ..parallel.fused_bn import ghost_bn_act
-
-    out, _, _ = ghost_bn_act(data, gamma.astype(jnp.float32),
-                             beta.astype(jnp.float32), eps=float(eps),
-                             act=act, group=int(group))
-    return out
-
-
-def _ghost_bn_aux_update(in_vals, out_vals, momentum=0.9, **_):
-    m = float(momentum)
-    base = 3 if len(in_vals) == 5 else 4
-    old_m, old_v = in_vals[base], in_vals[base + 1]
-    return {base: (m * old_m.astype(jnp.float32)
-                   + (1 - m) * out_vals[1]).astype(old_m.dtype),
-            base + 1: (m * old_v.astype(jnp.float32)
-                       + (1 - m) * out_vals[2]).astype(old_v.dtype)}
-
-
-def _ghost_bn_aux_update_dual(in_vals, out_vals, momentum=0.9, **_):
-    # dual op output layout is (out, out_sc, bm, bv) — drop the extra
-    # output position so the shared formula sees (out, bm, bv)
-    return _ghost_bn_aux_update(in_vals,
-                                (out_vals[0],) + tuple(out_vals[2:]),
-                                momentum=momentum)
-
-
-OPS["_contrib_GhostBNReLU"].aux_update = _ghost_bn_aux_update
-OPS["_contrib_GhostBNAddReLU"].aux_update = _ghost_bn_aux_update
-OPS["_contrib_GhostBNAddReLUDual"].aux_update = _ghost_bn_aux_update_dual
-OPS["_contrib_GhostBN"].aux_update = _ghost_bn_aux_update
 
 
 @register("LayerNorm", aliases=("layer_norm",))

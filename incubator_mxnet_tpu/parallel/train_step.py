@@ -181,8 +181,8 @@ class FunctionalOptimizer:
         (``t=0`` would divide by zero — see the regression test in
         tests/test_zero_sharding.py).
 
-        sgd/adam updates are elementwise, so this applies unchanged to
-        ZeRO shards; lamb's trust ratio is a global weight/update norm
+        sgd/adam/adamw updates are elementwise, so this applies unchanged
+        to ZeRO shards; lamb's trust ratio is a global weight/update norm
         and is excluded from sharded application by the caller.
         """
         mp = self.multi_precision
@@ -311,9 +311,9 @@ class TrainStep:
                 raise ValueError(
                     "zero=1 shards the weight update over the %r mesh "
                     "axis — pass a mesh that has it" % batch_axis)
-            if opt.name not in ("sgd", "adam"):
+            if opt.name not in ("sgd", "adam", "adamw"):
                 raise ValueError(
-                    "zero=1 needs an elementwise update (sgd/adam); "
+                    "zero=1 needs an elementwise update (sgd/adam/adamw); "
                     "%r's trust ratio is a global norm over the whole "
                     "weight and cannot run on a 1/N shard" % opt.name)
         self._zero_pad0 = None  # per-gp-param padded leading dim, or None
@@ -992,7 +992,7 @@ class TrainStep:
                     return spmd_pipeline(stage_fn, local, mb,
                                          axis_name=pp_axis, remat=remat)
 
-                # pallas_call (the fused ghost-BN kernels a staged
+                # pallas_call (the flash attention kernels a staged
                 # block may contain) carries no replication-rule
                 # metadata; skip the replication checker like the
                 # zero-update leg does

@@ -1,6 +1,6 @@
 """``chip_smoke.py`` off the chip: its phases are plain functions of their
-sizes, so the same code runs here at toy sizes on the CPU (Pallas through
-the interpreter, four of conftest's eight virtual devices for the mesh);
+sizes, so the same code runs here at toy sizes on the CPU (four of
+conftest's eight virtual devices for the mesh);
 and the script itself, run as the driver runs it, must FAIL here — this
 machine has no accelerator."""
 import inspect
@@ -15,7 +15,6 @@ import pytest
 import bench
 import chip_smoke
 from incubator_mxnet_tpu import _backend
-from incubator_mxnet_tpu.parallel import fused_bn
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # a toy-sized ResNet-50 (BatchNorm over 8 values at its 1x1 stages) does
@@ -26,10 +25,6 @@ _TOY = dict(image_size=32, classes=10, learning_rate=0.005)
 def test_train_phase_tiny_on_cpu():
     out = chip_smoke.train(batch=8, steps=6, platform="cpu", **_TOY)
     assert len(out["losses"]) == 7 and out["compile_s"] > 0
-    # bench.py's default composition is stock BatchNorm: no ghost-BN site
-    # is traced, so the kernels phase has nothing to do, by construction
-    assert out["sites"] == [] and out["custom_calls"] == 0
-    assert chip_smoke.kernels(out["sites"]) == 0
 
 
 def test_placement_check_notices_state_off_the_device():
@@ -41,32 +36,6 @@ def test_placement_check_notices_state_off_the_device():
     chip_smoke._check_placed(here, "cpu", "state")
     with pytest.raises(chip_smoke.SmokeFailure, match="not on a tpu device"):
         chip_smoke._check_placed(here, "tpu", "state")
-
-
-_SITES = [
-    ((16, 128, 4, 4), "bfloat16", 8, False, False, False),
-    ((16, 128, 4, 4), "bfloat16", 8, True, True, True),
-    ((256, 32, 4, 4), "float32", 8, False, False, False),
-    # N <= 128, C < 128 with the group capped below N: jnp by plan
-    ((8, 16, 4, 4), "float32", 4, False, False, False),
-]
-
-
-def test_kernels_phase_checks_pallas_sites_and_passes_over_jnp_ones():
-    assert fused_bn.plan_describe(8, 16, 4, 4, 4, 4)["variant"] == "jnp"
-    assert chip_smoke.kernels(_SITES) == 3
-
-
-def test_kernels_phase_catches_a_kernel_that_disagrees(monkeypatch):
-    ref = fused_bn._gbn_ref
-
-    def off_by_a_tenth(*args):
-        y, m, v = ref(*args)
-        return y * 1.1, m, v
-
-    monkeypatch.setattr(fused_bn, "_gbn_ref", off_by_a_tenth)
-    with pytest.raises(chip_smoke.SmokeFailure, match="kernels"):
-        chip_smoke.kernels(_SITES[:1])
 
 
 def test_serve_phase_tiny_on_cpu():
@@ -90,13 +59,13 @@ def test_a_mesh_wider_than_the_devices_is_an_error_not_a_smaller_mesh():
 
 def test_smoke_and_bench_share_one_definition(monkeypatch):
     """``train`` with no composition arguments builds what ``bench.py``
-    builds with no flags: both read bench.DEFAULT_GHOST_BN/DEFAULT_PASSES
+    builds with no flags: both read bench.DEFAULT_PASSES and bench.DEFAULT_ZERO
     and go through bench.build_train_step."""
     seen = {}
     for fn in (bench.run_train, bench.build_train_step):
         params = inspect.signature(fn).parameters
-        assert params["ghost_bn"].default == bench.DEFAULT_GHOST_BN
         assert params["passes"].default == bench.DEFAULT_PASSES
+        assert params["zero"].default == bench.DEFAULT_ZERO
 
     def spy(**kwargs):
         seen.update(kwargs)
@@ -105,8 +74,8 @@ def test_smoke_and_bench_share_one_definition(monkeypatch):
     monkeypatch.setattr(bench, "build_train_step", spy)
     with pytest.raises(KeyboardInterrupt):
         chip_smoke.train(batch=8, steps=1, platform="cpu", **_TOY)
-    assert seen["ghost_bn"] == bench.DEFAULT_GHOST_BN
     assert seen["passes"] == bench.DEFAULT_PASSES
+    assert "zero" not in seen and "mesh" not in seen
 
 
 def _run_here(script, *args):

@@ -21,15 +21,10 @@ Contracts under test:
   distinct CompileCache entries; identical schedule → cross-process
   hit at ZERO XLA compiles;
 - ``autotune_train_schedules``: 100 % ledger accounting, rejected
-  candidates carry ``zero_compile=True`` with zero compiles spent, and
-  on the bench ResNet the searched winner strictly beats the
-  hand-built PR-14 ``space_to_depth,maxpool_bwd_mask`` composition on
-  predicted bytes/img — all through ``analyze_cost``-grade abstract
-  traces, no XLA compile.
+  candidates carry ``zero_compile=True`` with zero compiles spent —
+  all through ``analyze_cost``-grade abstract traces, no XLA compile.
 
-Budget discipline: the ResNet leg is abstract-trace only (the same
-scale test_fused_step_composed.py already pays); everything else runs
-on the tiny dense nets.
+Everything runs on the tiny dense nets.
 """
 import json
 import os
@@ -51,13 +46,10 @@ from incubator_mxnet_tpu.analysis.passes import (PassContext, PassManager,
                                                  PassSchedule, get_pass,
                                                  resolve_schedule)
 from incubator_mxnet_tpu.gluon import nn
-from incubator_mxnet_tpu.gluon.model_zoo import vision
 from incubator_mxnet_tpu.parallel import aot, make_train_step
 from incubator_mxnet_tpu.parallel.distributed import collectives_supported
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-BENCH_PASSES = ("space_to_depth", "maxpool_bwd_mask")  # the PR-14 pair
 
 
 def _mlp_program(seed=7):
@@ -332,63 +324,3 @@ def test_schedule_search_rejects_over_budget_zero_compile():
     assert rejected and all(c.zero_compile for c in rejected)
     assert all("GL201" in (c.reason or "") for c in rejected)
     assert res.winner is None and res.winner_config() is None
-
-
-# ---------------------------------------------------------------------------
-# the acceptance leg: bench ResNet, searched vs the hand-built PR-14 pair
-# ---------------------------------------------------------------------------
-
-def _resnet_workload(img=112, classes=1000):
-    def make_net(knobs):
-        mx.random.seed(0)
-        # ghost_bn=16: the bench default (DEFAULT_GHOST_BN) — the
-        # config where maxpool_bwd_mask has its rewrite target
-        net = vision.resnet50_v1(classes=classes, ghost_bn=16)
-        net.initialize(init=mx.init.Zero())  # shapes only
-        net.shape_init((1, 3, img, img))
-        return net
-
-    def make_batch(knobs):
-        b = int(knobs.get("batch", 32))
-        return (jax.ShapeDtypeStruct((b, 3, img, img), np.float32),
-                jax.ShapeDtypeStruct((b,), np.float32))
-
-    return make_net, make_batch, gluon.loss.SoftmaxCrossEntropyLoss()
-
-
-def test_searched_schedule_beats_pr14_composition_on_bench_resnet():
-    """A searched per-site schedule strictly beats the hand-built PR-14
-    ``space_to_depth,maxpool_bwd_mask`` composition on predicted
-    bytes/img for the bench ResNet — ranked from ONE abstract site
-    table, zero XLA compiles spent on the whole search."""
-    B, IMG = 32, 112
-    mk, mb, loss_fn = _resnet_workload(img=IMG)
-    knobs = {"batch": B}
-
-    # the hand-built composition, costed exactly as bench does: the
-    # pass-rewritten program through analyze_cost (no compile)
-    net = mk(knobs)
-    pr14 = make_train_step(net, loss_fn, optimizer="sgd",
-                           learning_rate=0.1, momentum=0.9, wd=1e-4,
-                           lint="off", cost="off", passes=BENCH_PASSES)
-    x, y = mb(knobs)
-    pr14_rep = pr14.analyze_cost(x, y, device="tpu-v5e")
-    pr14_bytes_img = pr14_rep.hbm_bytes / B
-
-    c0 = aot.XLA_COMPILES.count
-    res = autotune_train_schedules(
-        mk, mb, loss_fn,
-        passes=BENCH_PASSES + ("cse_dead_aux", "amp_bf16"),
-        knobs=dict(knobs), device="tpu-v5e", budget_compiles=0)
-    assert aot.XLA_COMPILES.count == c0  # the search never compiled
-    assert all(c.zero_compile for c in res.candidates)
-    predicted = [c for c in res.candidates if c.status == "predicted"]
-    assert predicted
-    best = min(predicted, key=lambda c: c.pred["hbm_bytes"])
-    best_bytes_img = best.pred["hbm_bytes"] / B
-    # strict byte win over the hand-built pair
-    assert best_bytes_img < pr14_bytes_img, (best_bytes_img,
-                                             pr14_bytes_img)
-    # and the winner is a real schedule bench/serve can load
-    sched = PassSchedule.from_dict(best.knobs["schedule"])
-    assert sched.hash() == best.knobs["schedule_hash"]

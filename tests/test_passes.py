@@ -204,55 +204,6 @@ def test_space_to_depth_bit_exact_and_bytes_drop():
     assert not res1.changed and not res1.receipts[0].changed
 
 
-def test_maxpool_bwd_mask_bit_exact_and_wrong_mask_refused():
-    """ISSUE 14 lever (c): the select-and-scatter max-pool backward
-    becomes the shifted-window first-argmax mask — BIT-exact vs XLA's
-    own gradient (first-argmax IS the GE-select tie rule; the dyadic
-    probe is full of exact ties, the hard case), predicted bytes drop,
-    and a deliberately-wrong mask (winner index shifted by one) is
-    refused by the GL301 probe with zero compiles spent."""
-    from jax import lax
-
-    from incubator_mxnet_tpu.analysis.passes import (MaxPoolBwdMaskPass,
-                                                     eval_closed)
-    from incubator_mxnet_tpu.parallel import aot
-
-    def mp_loss(x):
-        y = lax.reduce_window(x, -jnp.inf, lax.max, (1, 1, 3, 3),
-                              (1, 1, 2, 2),
-                              ((0, 0), (0, 0), (1, 1), (1, 1)))
-        return (y * 1.5).sum()
-
-    cj = jax.make_jaxpr(jax.grad(mp_loss))(
-        jax.ShapeDtypeStruct((2, 4, 9, 9), jnp.float32))
-    assert any(e.primitive.name == "select_and_scatter_add"
-               for e in cj.jaxpr.eqns), "precondition: the scatter form"
-    res = PassManager(["maxpool_bwd_mask"]).run(cj, PassContext())
-    r = res.receipts[0]
-    assert r.installed and r.hits == 1
-    assert r.probe["bitwise"] is True          # bit_exact incl. ties
-    assert r.hbm_bytes_after < r.hbm_bytes_before
-    assert not any(e.primitive.name == "select_and_scatter_add"
-                   for e in res.closed_jaxpr.jaxpr.eqns)
-    # golden parity on real floats WITH post-ReLU-style zero plateaus
-    # (tie-heavy): first-argmax routing must match jax's gradient
-    rng = np.random.RandomState(0)
-    xv = np.maximum(rng.normal(size=(2, 4, 9, 9)), 0.0).astype(np.float32)
-    ref = np.asarray(eval_closed(cj, [xv])[0])
-    got = np.asarray(eval_closed(res.closed_jaxpr, [xv])[0])
-    np.testing.assert_array_equal(got, ref)
-
-    # the deliberately-wrong mask: winner index shifted by one — the
-    # GL301 contract probe refuses it, zero compiles spent
-    bad = MaxPoolBwdMaskPass()
-    bad._shift_mask = 1
-    before = aot.XLA_COMPILES.count
-    with pytest.raises(LintError) as ei:
-        PassManager([bad]).run(cj, PassContext())
-    assert "GL301" in str(ei.value)
-    assert aot.XLA_COMPILES.count == before
-
-
 def test_quantize_int8_engine_parity_and_zero_recompiles():
     """The refactored int8 tier: the quantize pass over the shared AOT
     build path — parity within 2 % of output scale, argmax identical,

@@ -45,9 +45,17 @@ def _batch(batch=16):
     return x, y
 
 
-def _opt_kw(optimizer):
-    return dict(optimizer="sgd", learning_rate=0.1, momentum=0.9) \
-        if optimizer == "sgd" else dict(optimizer="adam", learning_rate=0.01)
+_OPT_KW = {
+    "sgd": dict(optimizer="sgd", learning_rate=0.1, momentum=0.9),
+    "adam": dict(optimizer="adam", learning_rate=0.01),
+    # no momentum: the one optimizer with an EMPTY state under ZeRO
+    "sgd_plain": dict(optimizer="sgd", learning_rate=0.1, momentum=0.0),
+    "adamw": dict(optimizer="adamw", learning_rate=0.01, wd=0.1),
+    # the CNN cells' own update (vgg16_train_dp4: momentum, weight decay,
+    # float32 master weights of bf16 parameters, ZeRO-1)
+    "sgd_master": dict(optimizer="sgd", learning_rate=0.1, momentum=0.9,
+                       wd=5e-4, multi_precision=True),
+}
 
 
 def _state_bytes(opt_state, per_device):
@@ -66,32 +74,41 @@ def _state_bytes(opt_state, per_device):
 def _run_parity(optimizer, axes, pipeline=False, widths=(FEAT,) * 4,
                 seed=3):
     """zero=1 vs the unsharded single-device step: 3 steps, losses and
-    final params to 1e-5; returns the zero step for state assertions."""
+    final params to 1e-5; returns the zero step for state assertions.
+    Master weights are kept of bf16 parameters."""
     x, y = _batch()
-    s_ref = make_train_step(_build(seed, widths), LOSS(), **_opt_kw(optimizer))
+    opt_kw = _OPT_KW[optimizer]
+    dtype = "bfloat16" if opt_kw.get("multi_precision") else None
+    s_ref = make_train_step(_build(seed, widths, dtype), LOSS(), **opt_kw)
     ref = [float(s_ref(x, y).asscalar()) for _ in range(3)]
     ndev = int(np.prod(list(axes.values())))
     mesh = make_mesh(axes, devices=jax.devices()[:ndev])
     kw = dict(pipeline_stages=4, num_micro=4) if pipeline else {}
-    s_z = make_train_step(_build(seed, widths), LOSS(), **_opt_kw(optimizer),
+    s_z = make_train_step(_build(seed, widths, dtype), LOSS(), **opt_kw,
                           mesh=mesh, zero=1, lint="error", **kw)
     got = [float(s_z(x, y).asscalar()) for _ in range(3)]
     np.testing.assert_allclose(ref, got, rtol=1e-5, atol=1e-6)
     for p1, p2 in zip(s_ref.net.collect_params().values(),
                       s_z.net.collect_params().values()):
-        np.testing.assert_allclose(p1.data().asnumpy(), p2.data().asnumpy(),
-                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(
+            p1.data().asnumpy().astype(np.float32),
+            p2.data().asnumpy().astype(np.float32), rtol=1e-5, atol=1e-5)
     return s_z
 
 
-@pytest.mark.parametrize("optimizer", ["sgd", "adam"])
+@pytest.mark.parametrize("optimizer", sorted(_OPT_KW))
 def test_zero1_parity_and_state_bytes_dp(optimizer):
     """dp=8: parity to 1e-5 AND per-device opt-state bytes ~1/8 of the
-    global (every leading dim here divides, so exactly 1/8)."""
+    global (every leading dim here divides, so exactly 1/8).  Every
+    elementwise update shards (lamb's is not one:
+    test_zero1_validation_errors)."""
     step = _run_parity(optimizer, {"dp": 8})
     per_dev = _state_bytes(step._opt_state, per_device=True)
     total = _state_bytes(step._opt_state, per_device=False)
     assert per_dev * 8 == total, (per_dev, total)
+    if optimizer == "sgd_plain":
+        assert step._opt_state == []
+        return
     # and the dp sharding is real: N shards per leaf, 1/N rows each
     leaf = jax.tree_util.tree_leaves(step._opt_state)[0]
     assert len(leaf.addressable_shards) == 8

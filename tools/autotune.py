@@ -115,8 +115,7 @@ def _resnet50_workload(image_size=224, classes=1000):
 
     def make_net(knobs):
         mx.random.seed(0)
-        net = vision.resnet50_v1(classes=classes,
-                                 ghost_bn=int(knobs.get("bn_group", 0)))
+        net = vision.resnet50_v1(classes=classes)
         net.initialize(init=mx.init.Xavier())
         net.shape_init((1, 3, image_size, image_size))
         return net

@@ -74,7 +74,7 @@ def _parse_bytes(s):
     return float(s)
 
 
-def _build_model(name, feat=16, layers=4, ghost_bn=0):
+def _build_model(name, feat=16, layers=4):
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import nd
     from incubator_mxnet_tpu.gluon import nn
@@ -102,84 +102,11 @@ def _build_model(name, feat=16, layers=4, ghost_bn=0):
     if name == "resnet50":
         from incubator_mxnet_tpu.gluon.model_zoo import vision
 
-        # ghost_bn > 0: the fused ghost-BN perf variant (Pallas
-        # kernels + GhostBN downsample branches; parallel/fused_bn.py)
-        # — the round-19 byte table's fused rows come from here
-        net = vision.resnet50_v1(classes=1000, ghost_bn=ghost_bn)
+        net = vision.resnet50_v1(classes=1000)
         net.initialize(init=mx.init.Zero())
         net.shape_init((1, 3, 224, 224))
         return net, (3, 224, 224), "conv"
     raise SystemExit("unknown --model %r (dense, conv-bn, resnet50)" % name)
-
-
-#: ResNet-50 v1 BN-layer inventory: (body C, exit C, spatial, blocks)
-#: per stage.  conv1 of each stage's first block carries the stride, so
-#: every BN in a stage sees the same H = W = spatial.
-_R50_STAGES = [
-    (64, 256, 56, 3),
-    (128, 512, 28, 4),
-    (256, 1024, 14, 6),
-    (512, 2048, 7, 3),
-]
-
-
-def _resnet50_kernel_plans(batch, itemsize, group):
-    """Per-layer fused-BN kernel-plan table for the resnet50 workload:
-    which variant (whole-L fused / lane-fold / spatial-tiled / jnp
-    fallback) each distinct BN layer selects at the real VMEM budget,
-    with the padded window bytes and fold factor the feasibility check
-    charged.  Mirrors the model zoo's dual_out wiring: every residual
-    block exit is a dual-cotangent site except the LAST stage's tail
-    block (resnet.py::_make_layer).  A downsample block's exit donates
-    its residual; that saves an HBM buffer, never a VMEM window, so it
-    plans like the stage's other exits and shares their row."""
-    from incubator_mxnet_tpu.parallel.fused_bn import plan_describe
-
-    rows = [("stem", 64, 112, 1, False, False)]
-    last = len(_R50_STAGES) - 1
-    for i, (bc, ec, hw, k) in enumerate(_R50_STAGES):
-        s = "stage%d" % (i + 1)
-        rows.append((s + ".body", bc, hw, 2 * k, False, False))
-        rows.append((s + ".shortcut", ec, hw, 1, False, False))
-        if i == last:
-            rows.append((s + ".exit", ec, hw, k - 1, True, True))
-            rows.append((s + ".exit.tail", ec, hw, 1, True, False))
-        else:
-            rows.append((s + ".exit", ec, hw, k, True, True))
-    out = []
-    for layer, c, hw, count, res, dual in rows:
-        d = plan_describe(batch, c, hw, hw, itemsize, group, res, dual)
-        out.append({"layer": layer, "count": count,
-                    "shape": "%dx%dx%dx%d" % (batch, c, hw, hw),
-                    "residual": res, **d})
-    return out
-
-
-def _print_kernel_plans(plans, batch, itemsize, group, fmt):
-    import json as _json
-
-    if fmt == "json":
-        print(_json.dumps({"version": 1, "batch": batch,
-                           "itemsize": itemsize, "bn_group": group,
-                           "layers": plans}, indent=2))
-        return
-    print("resnet50 fused ghost-BN kernel plans — batch %d, itemsize %d, "
-          "bn_group %d" % (batch, itemsize, group))
-    hdr = ("layer", "count", "shape", "res", "dual", "variant", "bwd",
-           "fold", "l_tile", "l_tile_bwd", "window_mb")
-
-    def cell(p, h):
-        if h == "res":
-            return "res" if p["residual"] else "-"
-        if h == "dual":
-            return "dual" if p["dual"] else "-"
-        return str(p.get(h, "-"))
-    widths = [max(len(h), max((len(cell(p, h)) for p in plans),
-                              default=0)) for h in hdr]
-    print("  ".join("%-*s" % (w, h) for w, h in zip(widths, hdr)))
-    for p in plans:
-        print("  ".join("%-*s" % (w, cell(p, h))
-                        for w, h in zip(widths, hdr)))
 
 
 #: measured hlo_stats category (tools/profile_step.py) -> predicted
@@ -307,24 +234,11 @@ def main(argv=None) -> int:
     ap.add_argument("--no-donate", action="store_true")
     ap.add_argument("--compute-dtype", default=None,
                     help="e.g. bfloat16 (default: f32)")
-    ap.add_argument("--ghost-bn", "--bn-group", dest="ghost_bn", type=int,
-                    default=0, metavar="GROUP",
-                    help="resnet50 only: fused ghost-BN variant with "
-                         "this bn_group cap (0 = stock BatchNorm) — the "
-                         "PERF.md fused byte table without a chip")
-    ap.add_argument("--kernel-plans", action="store_true",
-                    help="resnet50 only: print the per-layer fused-BN "
-                         "kernel-plan table (variant / window bytes / "
-                         "fold factor per distinct BN layer at the real "
-                         "VMEM budget) instead of the cost report; "
-                         "honors --batch, --compute-dtype and "
-                         "--ghost-bn (group defaults to the bench "
-                         "workload's 16)")
     ap.add_argument("--passes", default=None,
                     help="comma-separated graftpass names applied to the "
                          "step before costing (the autotune post-pass "
                          "analyze_cost path), e.g. "
-                         "space_to_depth,maxpool_bwd_mask")
+                         "space_to_depth,cse_dead_aux")
     ap.add_argument("--device", default="tpu-v5e",
                     help="roofline device-spec registry key")
     ap.add_argument("--hbm-budget", default=None,
@@ -353,18 +267,6 @@ def main(argv=None) -> int:
         os.environ["XLA_FLAGS"] = \
             "--xla_force_host_platform_device_count=%d" % max(ndev, 2)
 
-    if args.kernel_plans:
-        if args.model != "resnet50":
-            raise SystemExit("--kernel-plans applies to --model resnet50 "
-                             "only")
-        import jax.numpy as _jnp
-
-        itemsize = _jnp.dtype(args.compute_dtype or "float32").itemsize
-        group = args.ghost_bn or 16
-        plans = _resnet50_kernel_plans(args.batch, itemsize, group)
-        _print_kernel_plans(plans, args.batch, itemsize, group, args.fmt)
-        return 0
-
     import jax
     import jax.numpy as jnp
 
@@ -375,9 +277,7 @@ def main(argv=None) -> int:
     if args.device not in DEVICE_SPECS:
         raise SystemExit("unknown --device %r (registry: %s)"
                          % (args.device, sorted(DEVICE_SPECS)))
-    if args.ghost_bn and args.model != "resnet50":
-        raise SystemExit("--ghost-bn applies to --model resnet50 only")
-    net, in_shape, kind = _build_model(args.model, ghost_bn=args.ghost_bn)
+    net, in_shape, kind = _build_model(args.model)
     budget = _parse_bytes(args.hbm_budget)
 
     mesh = None

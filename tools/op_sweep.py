@@ -65,10 +65,6 @@ def _hints():
         "BatchNorm_v1": ([x4, _f(C), _fn(C), _fn(C), _f(C)], {}),
         "_contrib_SyncBatchNorm": ([x4, _f(C), _fn(C), _fn(C), _f(C)],
                                    {"key": "sweep"}),
-        # stats-free fused ghost-BN (the pipeline-parallel form): no
-        # moving-stat inputs, ghost group over the batch
-        "_contrib_GhostBNReLUNS": ([x4, _f(C), _fn(C)], {"group": 2}),
-        "_contrib_GhostBNNS": ([x4, _f(C), _fn(C)], {"group": 2}),
         "LayerNorm": ([_fn(B, 6), _f(6), _fn(6)], {}),
         "GroupNorm": ([x4, _f(C), _fn(C)], {"num_groups": 2}),
         "InstanceNorm": ([x4, _f(C), _fn(C)], {}),
