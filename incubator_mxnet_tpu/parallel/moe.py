@@ -27,11 +27,24 @@ grouped product per matrix over those rows (``lax.ragged_dot``: an
 expert's matrix meets only its own rows); ``_contrib_moe_combine`` sums
 each token's weighted results.  What absent experts would add is left out.
 The row buffer holds every assignment there can be (tokens x top-k), so no
-token is ever dropped, whatever the router does; the grouped product's
-work follows the rows that are in a group.  (A smaller buffer for the usual
-step and the full one by ``lax.cond`` for the rest was tried on the chip, PR
-30: 6 % of a step faster, and the ``conditional`` stands in the device trace
-as one op OVER its own ops, so that no sum of ops is the step's time.)
+token is ever dropped, whatever the router does, and what is done with it
+stops at ``n = sum(sizes)``, the rows that are in a group, a number only the
+step itself knows: the grouped product's work follows the groups, and three
+of the four passes that move rows (back to tokens, and both transposes) are
+the Pallas kernels of ``moe_rows.py``, whose grid covers the whole buffer
+and whose steps past ``n`` do nothing.  A kernel is one op in the device
+trace whatever its grid does, which a ``lax.cond`` between a small buffer
+and the full one is not (tried on the chip, PR 30: 6 % of a step faster,
+and the ``conditional`` stands in the trace as one op OVER its own ops, so
+that no sum of ops is the step's time).  The fourth pass, the rows into
+expert order, stays XLA's gather over every row: the buffer it fills is
+what a caller may take a statistic of (a float8 scale a tensor), so all of
+it is written.  Past ``n`` the product's result and both cotangents hold
+whatever the memory held before, NaN included; nothing reads them
+(``tests/test_moe_window_kernels.py`` sets them to NaN).  Scalars are moved
+between the assignments' order and the rows' by sorts that carry them:
+XLA's gather of tokens x top-k scalars takes ten times a sort's time on a
+TPU.
 """
 from __future__ import annotations
 
@@ -43,7 +56,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import _backend
 from ..tracing import REMAT_KEEP
+from . import moe_rows
 
 __all__ = ["moe_ffn", "moe_ffn_sharded", "load_balancing_loss",
            "moe_route", "moe_dispatch", "moe_experts", "moe_combine"]
@@ -184,22 +199,58 @@ def moe_route(x, router_weight, select_bias, top_k=1, route_norm=True,
     return w * route_scale, sel, jnp.sum(chosen, (0, 1))
 
 
+def _lowered_once(mover):
+    """``mover`` under ``jax.jit`` (the interpreter's answer is part of its
+    key).  A ``pallas_call`` is traced and lowered anew wherever it is
+    called, a tenth of a second each time, and a step runs every mover in
+    every expert layer, forward, recomputed and backward, with the same
+    shapes; a jitted function is traced once a process and lowered once a
+    program, and called (the decoder cell's ``setup_s``: +6 s without)."""
+    def keyed(interpret, *args):
+        return mover(*args)
+
+    keyed.__name__ = mover.__name__
+    keyed = jax.jit(keyed, static_argnums=0)
+
+    @functools.wraps(mover)
+    def call(*args):
+        return keyed(_backend.pallas_interpret(), *args)
+
+    return call
+
+
+def _in_row_order(row, values):
+    """(T, K) values by assignment, as (R,) by row: ``row`` is a permutation,
+    and a sort by it that carries the values costs a tenth of XLA's gather
+    of T * K scalars."""
+    return jax.lax.sort((row.reshape(-1), values.reshape(-1)),
+                        num_keys=1)[1]
+
+
+@_lowered_once
+def _rows_to_tokens(g, row, n):
+    # each token's gradient is the sum over ITS held assignments' rows (the
+    # transpose of x[token] would be a scatter-add over the rows with
+    # repeated targets); of g only the rows below n are read
+    return moe_rows.tokens_from_rows(g, row, n)
+
+
 @jax.custom_vjp
-def _gather_rows(x, order, row, held):
+def _gather_rows(x, order, row, n):
+    # XLA's gather, over every row of the buffer: what a kernel that stops
+    # at n spares here (0.25 against 0.42 ms on the chip) it spares by
+    # leaving the rows past n unwritten, and whoever takes a statistic of
+    # the whole buffer (a float8 scale a tensor) would read NaN there
     return x[order // row.shape[1]]
 
 
-def _gather_rows_fwd(x, order, row, held):
-    return _gather_rows(x, order, row, held), (row, held)
+def _gather_rows_fwd(x, order, row, n):
+    return _gather_rows(x, order, row, n), (row, n)
 
 
 def _gather_rows_bwd(res, g):
-    # each token's gradient is the sum over ITS assignments' rows: a gather
-    # and a reduction over K, where the transpose of x[token] would be a
-    # scatter-add over the rows with repeated targets
-    row, held = res
-    dx = jnp.sum(jnp.where(held[..., None], g[row], 0).astype(jnp.float32), 1)
-    return dx.astype(g.dtype), None, None, None
+    row, n = res
+    return _rows_to_tokens(g, row, n), None, None, None
 
 
 _gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
@@ -223,7 +274,7 @@ def moe_dispatch(x, sel, experts_held=(0, 1)):
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
     row = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
     sizes = jnp.sum(_one_hot(key, count), 0).astype(jnp.int32)
-    return _gather_rows(x, order, row, held), sizes, row, order
+    return _gather_rows(x, order, row, jnp.sum(sizes)), sizes, row, order
 
 
 def moe_experts(rows, w1, w3, w2, sizes):
@@ -242,32 +293,35 @@ def moe_experts(rows, w1, w3, w2, sizes):
     return dot(h, w2)
 
 
-def _pick(ys, row, held):
-    """(T, K, d) float32: each held assignment's row of ``ys``, else 0 (a
-    select, not a product with a zero weight: on a TPU the rows that are in
-    no group hold whatever the buffer held before, NaN included)."""
-    return jnp.where(held[..., None], ys[row], 0).astype(jnp.float32)
+@_lowered_once
+def _weighted_rows_to_tokens(ys, weights, row, n):
+    return moe_rows.tokens_from_rows(ys, row, n, weights)
+
+
+@_lowered_once
+def _weighted_tokens_to_rows(ys, weights, row, order, n, g):
+    # each row below n takes its token's gradient times its weight, and in
+    # the same pass its product with its own row of ys, which is the
+    # weight's gradient; the rows past n's block are not written
+    dys, dots = moe_rows.rows_from_tokens(
+        g, order // row.shape[1], n, _in_row_order(row, weights), ys)
+    # back by assignment: order is row's inverse
+    dw = _in_row_order(order, dots).reshape(row.shape)
+    return dys, jnp.where(row < n, dw, 0.0)
 
 
 @jax.custom_vjp
-def _weighted_sum(ys, weights, row, order, held):
-    return jnp.sum(weights[..., None] * _pick(ys, row, held),
-                   1).astype(ys.dtype)
+def _weighted_sum(ys, weights, row, order, n):
+    return _weighted_rows_to_tokens(ys, weights, row, n)
 
 
-def _weighted_sum_fwd(ys, weights, row, order, held):
-    return (_weighted_sum(ys, weights, row, order, held),
-            (ys, weights, row, order, held))
+def _weighted_sum_fwd(ys, weights, row, order, n):
+    return (_weighted_sum(ys, weights, row, order, n),
+            (ys, weights, row, order, n))
 
 
 def _weighted_sum_bwd(res, g):
-    # gathers again: each row takes its token's gradient times its weight
-    ys, weights, row, order, held = res
-    k = row.shape[1]
-    w_row = jnp.where(held, weights, 0.0).reshape(-1)[order]
-    dys = w_row[:, None] * g[order // k].astype(jnp.float32)
-    dw = jnp.sum(g[:, None, :].astype(jnp.float32) * _pick(ys, row, held), -1)
-    return dys.astype(ys.dtype), dw, None, None, None
+    return _weighted_tokens_to_rows(*res, g) + (None, None, None)
 
 
 _weighted_sum.defvjp(_weighted_sum_fwd, _weighted_sum_bwd)
@@ -278,7 +332,7 @@ def moe_combine(ys, weights, sizes, row, order):
     below ``sum(sizes)``) of weight times that row of ``ys``; (T, d) in
     ``ys``'s dtype."""
     return _weighted_sum(ys, weights.astype(jnp.float32), row, order,
-                         row < jnp.sum(sizes))
+                         jnp.sum(sizes))
 
 
 from ..ops.registry import register as _register_op  # noqa: E402

@@ -130,6 +130,40 @@ def test_grouped_expert_product_is_the_compilers_own_kernel(one_chip, mosaic):
     assert not re.search(r" (dot|convolution)\(", text)
 
 
+def test_dispatch_and_combine_are_row_movers_that_stop_at_n(one_chip, mosaic):
+    """Dispatch and combine at the cell's shapes (8,192 tokens, 8 choices,
+    hidden 2,048, bf16), forward and backward: the pass back to tokens and
+    both transposes are Pallas kernels (``moe_slabs``, ``moe_rows``,
+    ``moe_tokens``: a row travels as 4 KiB of 32-bit words,
+    ``parallel/moe_rows.py``), the one gather left is the rows into expert
+    order, no float32 array of the 65,536-row buffer's size is left, and the
+    dynamic extent stays inside the kernels: no ``while`` or ``conditional``
+    in the program."""
+    from incubator_mxnet_tpu.parallel import moe
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss(x, ys, weights, sel):
+        rows, sizes, row, order = moe.moe_dispatch(x, sel,
+                                                   experts_held=(0, 16))
+        out = moe.moe_combine(ys + rows, weights, sizes, row, order)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        shape(8192, 2048), shape(65536, 2048),
+        shape(8192, 8, dtype=jnp.float32),
+        shape(8192, 8, dtype=jnp.int32)).compile().as_text()
+    kernels = [re.search(r"(moe_[a-z]+)/pallas_call", line).group(1)
+               for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sorted(kernels) == ["moe_rows"] + ["moe_slabs"] * 3 + [
+        "moe_tokens"] * 2, kernels
+    assert re.findall(r"(\w+\[[\d,]+\])\S* gather\(", text) == [
+        "bf16[65536,2048]"]
+    assert not re.search(r" (while|conditional)\(", text)
+    assert not re.search(r"f32\[(65536,2048|8192,8,2048)\]", text)
+
+
 def test_stem_maxpool_has_no_pallas_form(one_chip, mosaic):
     """The stem max-pool (256,64,112,112) window 3x3 stride 2 is on jnp by
     construction: the argmax-carrying Pallas forward never compiled — W

@@ -3,8 +3,12 @@ kernels, against the masked dense ``attention_reference``), and the no-drop
 expert layer of a chip that holds a share of the experts
 (``parallel/moe.py``): against a loop over tokens, under a router forced onto
 one expert, its gradients, and the eight shares that add up to the uncut
-layer.  Pallas runs in interpret mode here; sizes are small enough that the
-file takes well under a minute."""
+layer; its Pallas row movers (``parallel/moe_rows.py``) against the
+``jax.numpy`` forms they replaced, kept here as the oracle, at every edge of
+``n``, and the layer with the buffer's tail poisoned.  Pallas runs in
+interpret mode here; sizes are small enough that the file takes about two
+minutes."""
+import functools
 import re
 
 import jax
@@ -14,7 +18,7 @@ import pytest
 
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu.gluon.model_zoo import text
-from incubator_mxnet_tpu.parallel import flash_attention, moe
+from incubator_mxnet_tpu.parallel import flash_attention, moe, moe_rows
 from incubator_mxnet_tpu.parallel.ring_attention import attention_reference
 from incubator_mxnet_tpu.tracing import REMAT_KEEP
 from perfbench.references import trinity_mini as ref
@@ -310,6 +314,151 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
                                rtol=1e-4, atol=1e-5)
 
 
+
+
+# ---------------------------------------------------------------------------
+# the row movers against the jax.numpy forms they replaced
+# ---------------------------------------------------------------------------
+
+_T, _K, _D = 64, 8, 16      # a buffer of 512 rows: two blocks of the row kernels
+
+
+def _pick(ys, row, held):
+    return jnp.where(held[..., None], ys[row], 0).astype(jnp.float32)
+
+
+def _oracle_rows_scaled(x, ys, weights, row, order, n):
+    """``_weighted_sum_bwd`` as it was, ``x`` the tokens' cotangent."""
+    held = row < n
+    w_row = jnp.where(held, weights, 0.0).reshape(-1)[order]
+    dys = w_row[:, None] * x[order // _K].astype(jnp.float32)
+    dw = jnp.sum(x[:, None, :].astype(jnp.float32) * _pick(ys, row, held), -1)
+    return dys.astype(ys.dtype), dw
+
+
+def _oracle_tokens_weighted(x, ys, weights, row, order, n):
+    """``_weighted_sum`` as it was."""
+    return jnp.sum(weights[..., None] * _pick(ys, row, row < n),
+                   1).astype(ys.dtype)
+
+
+def _oracle_tokens_plain(x, ys, weights, row, order, n):
+    """``_gather_rows_bwd`` as it was, ``ys`` the rows' cotangent."""
+    return jnp.sum(_pick(ys, row, row < n), 1).astype(ys.dtype)
+
+
+def _mover_rows_scaled(x, ys, weights, row, order, n):
+    return moe._weighted_sum_bwd((ys, weights, row, order, n), x)[:2]
+
+
+def _mover_tokens_weighted(x, ys, weights, row, order, n):
+    return moe._weighted_sum(ys, weights, row, order, n)
+
+
+def _mover_tokens_plain(x, ys, weights, row, order, n):
+    return moe._gather_rows_bwd((row, n), ys)[0]
+
+
+#: each mover beside the form it replaced, compiled once for every n
+_MOVERS = {"rows_scaled": (_mover_rows_scaled, _oracle_rows_scaled),
+           "tokens_weighted": (_mover_tokens_weighted,
+                               _oracle_tokens_weighted),
+           "tokens_plain": (_mover_tokens_plain, _oracle_tokens_plain)}
+_MOVERS = {name: tuple(map(jax.jit, pair)) for name, pair in _MOVERS.items()}
+
+
+@functools.lru_cache(maxsize=None)
+def _buffers(dtype):
+    rng = np.random.RandomState(6)
+    order = jnp.asarray(rng.permutation(_T * _K), jnp.int32)
+    return (jnp.asarray(rng.normal(size=(_T, _D)), dtype),
+            jnp.asarray(rng.normal(size=(_T * _K, _D)), dtype),
+            jnp.asarray(rng.uniform(size=(_T, _K)), jnp.float32),
+            jnp.argsort(order).astype(jnp.int32).reshape(_T, _K), order)
+
+
+@pytest.mark.parametrize("n", [0, 1, 255, 256, 257, _T * _K])
+@pytest.mark.parametrize("name", sorted(_MOVERS))
+def test_a_row_mover_is_the_form_it_replaced(name, n):
+    """At no row, one row, a block less one, a block, a block and one, and
+    every row of the buffer.  What a kernel does not write the interpreter
+    fills with NaN: the rows are compared below ``n``, the tokens whole.
+    bfloat16 rows come out bit for bit; a bfloat16 token is a float32 sum
+    rounded once, and may fall to the next value with the order of the
+    sum."""
+    mover, oracle = _MOVERS[name]
+    ulp = 2.0 ** -7 if name.startswith("tokens") else 0
+    for dtype, tol in ((jnp.float32, 1e-6), (jnp.bfloat16, ulp)):
+        args = _buffers(dtype) + (jnp.int32(n),)
+        got, want = mover(*args), oracle(*args)
+        if name == "rows_scaled":
+            held = np.asarray(args[3]) < n
+            np.testing.assert_allclose(np.where(held, got[1], 0), want[1],
+                                       rtol=1e-5, atol=1e-6)
+            assert np.all(np.asarray(got[1])[~held] == 0)
+            got, want = got[0], want[0]
+        if name.startswith("rows"):
+            edge = min(-(-n // 256) * 256, _T * _K)
+            tail = np.asarray(got[n:edge], np.float32)
+            assert np.all(tail == 0), "zeros up to the end of n's block"
+            got, want = got[:n], want[:n]
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), rtol=tol,
+                                   atol=tol)
+
+
+def test_slabs_past_the_last_held_row_are_not_written():
+    """The grid covers the whole buffer and its steps past ``n`` do nothing:
+    their slabs are what the buffer held (the interpreter's fill for words
+    never written, 0), the live block's are the rows."""
+    x = _buffers(jnp.float32)[1]
+    slabs = np.asarray(jax.jit(moe_rows.to_slabs)(x, jnp.int32(3)))
+    words = slabs.reshape(_T * _K, -1)[:, :_D].view(np.float32)
+    np.testing.assert_array_equal(words[:256], np.asarray(x[:256]))
+    assert np.asarray(x[256:]).all() and (words[256:] == 0).all()
+
+
+@jax.custom_vjp
+def _poison_tail(a, n):
+    return _poisoned(a, n)
+
+
+def _poisoned(a, n):
+    return jnp.where((jnp.arange(a.shape[0]) < n)[:, None], a, jnp.nan)
+
+
+_poison_tail.defvjp(lambda a, n: (_poisoned(a, n), n),
+                    lambda n, g: (_poisoned(g, n), None))
+
+
+@pytest.mark.parametrize("held", [(2, 4), (0, 8)])
+def test_nothing_reads_the_buffers_tail(held):
+    """The rows past ``sum(sizes)`` of the row buffer, of the grouped
+    product's result and of both their cotangents set to NaN: the layer's
+    output and every gradient come out finite and the same."""
+    w = _expert_weights()
+    x = jnp.asarray(np.random.RandomState(7).normal(size=(40, 16)),
+                    jnp.float32)
+    first, count = held
+
+    def layer(x, w, poison):
+        mine = [w[k][first:first + count] for k in ("w1", "w3", "w2")]
+        weights, sel, _ = moe.moe_route(x, w["router"], w["bias"], top_k=2,
+                                        route_norm=True, route_scale=2.826)
+        rows, sizes, row, order = moe.moe_dispatch(x, sel, experts_held=held)
+        rows = poison(rows, jnp.sum(sizes))
+        ys = poison(moe.moe_experts(rows, *mine, sizes), jnp.sum(sizes))
+        return moe.moe_combine(ys, weights, sizes, row, order)
+
+    def both(poison):
+        return jax.value_and_grad(
+            lambda x, w: jnp.sum(jnp.sin(layer(x, w, poison))), (0, 1))(x, w)
+
+    got, want = both(_poison_tail), both(lambda a, n: a)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_array_equal(a, b)
 
 
 def test_the_reference_follows_another_routers_choice_only_within_its_margin():

@@ -206,6 +206,30 @@ def test_a_recomputed_block_keeps_its_routers_choice(gradient_program):
     assert gradient_program.count("top_k[") == 2
 
 
+def test_an_expert_layer_moves_the_row_buffer_whole_only_into_expert_order(
+        gradient_program):
+    """Combine, and the transposes of dispatch and combine, move rows through
+    the Pallas kernels whose work stops at the last held row
+    (``parallel/moe_rows.py``): of the gathers and broadcasts whose result
+    has tokens x top-k rows of the hidden width, in either of the forms they
+    had, (T * K, d) and (T, K, d), the gradient's program holds only the
+    rows into expert order, forward and recomputed, of its two expert
+    layers.  Each layer calls, forward and recomputed, the weighted sum back
+    to tokens, and backward its transpose and the row gather's; the three
+    are jitted, so the program holds each ONCE and calls it (a
+    ``pallas_call`` is traced and lowered anew at every call site)."""
+    tokens, top_k, hidden = 2 * _SEQ, _CFG["num_experts_per_tok"], \
+        _CFG["hidden_size"]
+    whole = r"\[(?:%d,%d|%d,%d,%d)\] = (gather|broadcast_in_dim)\b" % (
+        tokens * top_k, hidden, tokens, top_k, hidden)
+    assert re.findall(whole, gradient_program) == ["gather"] * 4
+    calls = {mover: len(re.findall(r"jit\[\s*name=%s\b" % mover,
+                                   gradient_program))
+             for mover in ("_weighted_rows_to_tokens",
+                           "_weighted_tokens_to_rows", "_rows_to_tokens")}
+    assert list(calls.values()) == [4, 2, 2], calls
+
+
 def test_a_recomputed_block_runs_attentions_forward_kernel_once(
         gradient_program):
     """The flash kernels name their two results ``tracing.REMAT_KEEP``, so a
