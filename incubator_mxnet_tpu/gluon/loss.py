@@ -10,7 +10,8 @@ __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
            "SigmoidBCELoss", "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
            "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss",
            "SquaredHingeLoss", "LogisticLoss", "TripletLoss",
-           "PoissonNLLLoss", "CosineEmbeddingLoss"]
+           "PoissonNLLLoss", "CosineEmbeddingLoss",
+           "MultiTokenCrossEntropyLoss"]
 
 
 def _apply_weighting(F, loss, weight=None, sample_weight=None):  # noqa: N803
@@ -111,6 +112,34 @@ class SoftmaxCrossEntropyLoss(Loss):
 
 
 SoftmaxCELoss = SoftmaxCrossEntropyLoss
+
+
+class MultiTokenCrossEntropyLoss(Loss):
+    """The loss of a decoder with one prediction module, whose net returns
+    ``(logits, mtp_logits)``, each ``(B, S, rows)``, for labels ``(B, S)``
+    of next tokens: ``mean_i CE(logits_i, y_i) + mtp_weight * mean_{i < S-1}
+    CE(mtp_logits_i, y_{i+1})``.  The module's last position has no target
+    (its input wrapped around the sequence) and is masked out."""
+
+    def __init__(self, mtp_weight=0.3, batch_axis=0, **kwargs):
+        super().__init__(None, batch_axis, **kwargs)
+        self._mtp_weight = mtp_weight
+        with self.name_scope():
+            self.next_token = SoftmaxCrossEntropyLoss(
+                batch_axis=batch_axis, prefix="next_")
+            self.next_but_one = SoftmaxCrossEntropyLoss(
+                batch_axis=batch_axis, prefix="next2_")
+
+    def hybrid_forward(self, F, pred, label):  # noqa: N803
+        logits, mtp_logits = pred
+        s = label.shape[1]
+        # the mean is over all S positions: the S - 1 that count weigh
+        # S / (S - 1) each, so that no logit array is sliced
+        keep = F.array([[[s / (s - 1.0)]] * (s - 1) + [[0.0]]])
+        after = F.concat(F.slice_axis(label, axis=1, begin=1, end=None),
+                         F.slice_axis(label, axis=1, begin=0, end=1), dim=1)
+        return self.next_token(logits, label) \
+            + self._mtp_weight * self.next_but_one(mtp_logits, after, keep)
 
 
 class KLDivLoss(Loss):

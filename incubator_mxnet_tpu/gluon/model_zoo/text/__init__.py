@@ -1,3 +1,6 @@
 """``mx.gluon.model_zoo.text`` — decoder families over token ids."""
+from . import afmoe, glm4_moe_lite
 from .afmoe import *  # noqa: F401,F403
-from .afmoe import __all__  # noqa: F401
+from .glm4_moe_lite import *  # noqa: F401,F403
+
+__all__ = afmoe.__all__ + glm4_moe_lite.__all__

@@ -510,13 +510,14 @@ from ..ops.registry import register as _register_op  # noqa: E402
 
 @_register_op("_contrib_flash_attention", num_inputs=3)
 def _flash_attention_op(q, k, v, causal=False, scale=None, block_q=None,
-                        block_k=None, window=None):
+                        block_k=None, window=None, use_pallas=None):
     """Fused attention op (the TPU answer to
-    _contrib_interleaved_matmul_selfatt_* in transformer.cc); ``window`` and
-    fewer key/value heads than query heads as ``flash_attention`` takes
-    them."""
+    _contrib_interleaved_matmul_selfatt_* in transformer.cc); ``window``,
+    fewer key/value heads than query heads and ``use_pallas`` as
+    ``flash_attention`` takes them."""
     return flash_attention(
         q, k, v, causal=bool(causal), scale=scale,
         block_q=None if block_q is None else int(block_q),
         block_k=None if block_k is None else int(block_k),
-        window=None if window is None else int(window))
+        window=None if window is None else int(window),
+        use_pallas=None if use_pallas is None else bool(use_pallas))

@@ -110,6 +110,36 @@ def test_flash_window_and_grouped_heads_compile_for_v5e(one_chip, mosaic,
     assert not re.search(r"\[(\d+,)*8192,8192\]", text)
 
 
+@pytest.mark.parametrize("tiles", [(512, 1024), (1024, 1024)],
+                         ids=["fits", "refused"])
+def test_flash_at_head_size_256_compiles_for_v5e_at_the_tiles_it_uses(
+        one_chip, mosaic, tiles):
+    """Latent attention's shape (``text.LatentAttention``): 8,192 tokens, 20
+    ungrouped heads of 256, no window, through ``use_pallas=True`` (without
+    it this shape takes XLA's attention on a chip, which holds 20 x 8,192^2
+    scores).  The blocks and accumulators are twice those of head size 128:
+    the other decoder's 1024 x 1024 tiles overrun the scoped VMEM in the
+    dk/dv kernel, the family's own 512 x 1024 fit."""
+    from incubator_mxnet_tpu.gluon.model_zoo import text
+
+    q = jax.ShapeDtypeStruct((1, 20, 8192, 256), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash.flash_attention(
+            q, k, v, causal=True, block_q=tiles[0], block_k=tiles[1],
+            use_pallas=True).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
+    if tiles != (text.LatentAttention.BLOCK_Q, text.LatentAttention.BLOCK_K):
+        with pytest.raises(Exception, match="vmem"):
+            lowered.compile()
+        return
+    compiled_text = lowered.compile().as_text()
+    assert compiled_text.count("tpu_custom_call") == 3
+    assert not re.search(r"\[(\d+,)*8192,8192\]", compiled_text)
+
+
 def test_grouped_expert_product_is_the_compilers_own_kernel(one_chip, mosaic):
     """``lax.ragged_dot`` over the held experts at the published widths
     becomes XLA's grouped-product kernel (a custom call it names
