@@ -52,9 +52,11 @@ class LatentAttention(HybridBlock):
     all the heads share; ``k_h = [k_nope_h | kr]``; softmax scale
     ``1 / sqrt(nope + rope)``."""
 
-    #: tiles of the three flash kernels at a head size of 256 (cut to the
-    #: sequence where it is shorter); read on the chip, PERF.md section 6
-    BLOCK_Q, BLOCK_K = 512, 1024
+    #: tiles of the flash kernels at a head size of 256 (cut to the sequence
+    #: where it is shorter); read on the chip, PERF.md section 6, PR 35: the
+    #: forward kernel takes 5.54 ms a layer with them and 5.98 at 512 x 1024,
+    #: the backward kernel 11.2 at either
+    BLOCK_Q, BLOCK_K = 1024, 1024
 
     def __init__(self, hidden, heads, q_lora_rank, kv_lora_rank, nope, rope,
                  v_head_dim, rope_theta=10000.0, eps=1e-5, **kwargs):

@@ -259,6 +259,47 @@ def _fwd(q, k, v, scale, causal, window, heads, kv_heads, block_q, block_k,
 # ---------------------------------------------------------------------------
 # backward kernels
 # ---------------------------------------------------------------------------
+#
+# One kernel makes dq, dk and dv: a tile's scores, probabilities and score
+# gradients are made once and feed all three sums (five products a tile).
+# The key tile is the outer loop and the query tiles that see it the inner
+# one, so dk and dv of a tile are finished when its walk ends, while dq
+# collects a term from every key tile: dq of the whole sequence of the
+# current query head waits in VMEM, in float32, and is written once.  Where
+# that does not fit (_fused_bwd_vmem against _vmem_budget: at tiles of
+# 1024 x 1024 from 131,072 tokens at head size 128, from 65,536 where eight
+# query heads share a key/value head, from 32,768 at head size 256), dq and
+# dk/dv come from a kernel each, which need only tiles.
+
+#: VMEM of the one core the backward kernels are written for, the TPU v5e's
+#: (a core with less refuses what they ask for: they are v5e-only as they
+#: stand), and what a kernel gets of it without asking
+_VMEM_BYTES = 128 * 2 ** 20
+_VMEM_UNASKED = 16 * 2 ** 20
+
+
+def _vmem_budget():
+    """Bytes of VMEM a kernel here may ask for: three quarters of the
+    core's, the rest is left to the compiler's own buffers."""
+    return _VMEM_BYTES * 3 // 4
+
+
+def _fused_bwd_vmem(s, sk, d, bq, bk, group, itemsize):
+    """Bytes of VMEM ``flash_bwd`` asks for: the float32 sums (dq of the
+    sequence; dk and dv of a key tile, or of the sequence where a group of
+    query heads shares them), every block twice for the pipeline (lse and
+    delta are (bq, 1) float32, which the tiled layout pads to 128 lanes; dq
+    leaves as one block of the sequence), and six float32 (bq, bk) tiles:
+    scores, probabilities, dp, ds, and two for the mask and the copies in
+    the compute dtype (the v5e compiler reuses them down to under three at
+    8,192 x 128 and 8,192 x 256: it takes 28 MiB at either; the rest is
+    the margin).  A head narrower than 128 fills whole lanes all the same,
+    and no kernel asks for less than it would get unasked."""
+    d = _cdiv(d, 128) * 128
+    sums = 4 * d * (s + 2 * (bk if group == 1 else sk))
+    blocks = 2 * (itemsize * d * (2 * bq + 4 * bk + s) + 2 * 4 * 128 * bq)
+    return max(_VMEM_UNASKED, sums + blocks + 6 * 4 * bq * bk)
+
 
 def _p_and_ds(q, k, v, do, lse, delta, qi, ki, *, scale, causal, window,
               block_q, block_k, offset):
@@ -272,6 +313,53 @@ def _p_and_ds(q, k, v, do, lse, delta, qi, ki, *, scale, causal, window,
     p = jnp.where(s > jnp.float32(_NEG_INF / 2), p, jnp.float32(0.0))
     dp = _dot(do, v, (1, 1))
     return p, p * (dp - delta) * scale
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *, nq, nk, steps,
+                group, **tile):
+    """Grid (key/value head, query head of its group, key tile, query tile
+    that sees it).  ``dq_scr`` is the sequence's dq of the current query
+    head; ``dk_scr`` / ``dv_scr`` are the current key tile's sums when each
+    query head has its own keys, else the sequence's, because a key tile's
+    sums then outlive the walk over the group's query heads."""
+    g, kj, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    bq, bk = tile["block_q"], tile["block_k"]
+    first, last = _q_range(kj, bq, bk, tile["offset"], tile["causal"],
+                           tile["window"], nq)
+    qi = first + step
+    k_rows = pl.ds(0, bk) if group == 1 else \
+        pl.ds(pl.multiple_of(kj * bk, bk), bk)
+
+    @pl.when((kj == 0) & (step == 0))
+    def _new_query_head():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    @pl.when((g == 0) & (step == 0))
+    def _new_key_tile():
+        dk_scr[k_rows, :] = jnp.zeros((bk, dk_scr.shape[1]), jnp.float32)
+        dv_scr[k_rows, :] = jnp.zeros((bk, dv_scr.shape[1]), jnp.float32)
+
+    @pl.when(qi <= last)
+    def _compute():
+        q, k, do = q_ref[0], k_ref[0], do_ref[0]
+        p, ds = _p_and_ds(q, k, v_ref[0], do, lse_ref[0], delta_ref[0], qi,
+                          kj, **tile)
+        ds = ds.astype(q.dtype)
+        dv_scr[k_rows, :] = dv_scr[k_rows, :] + _dot(
+            p.astype(do.dtype), do, (0, 0))
+        dk_scr[k_rows, :] = dk_scr[k_rows, :] + _dot(ds, q, (0, 0))
+        q_rows = pl.ds(pl.multiple_of(qi * bq, bq), bq)
+        dq_scr[q_rows, :] = dq_scr[q_rows, :] + _dot(ds, k, (1, 0))
+
+    @pl.when((g == group - 1) & (step == steps - 1))
+    def _key_tile_done():
+        dk_ref[0] = dk_scr[k_rows, :].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[k_rows, :].astype(dv_ref.dtype)
+
+    @pl.when((kj == nk - 1) & (step == steps - 1))
+    def _query_head_done():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -336,12 +424,78 @@ def _bwd(scale, causal, window, heads, kv_heads, block_q, block_k, interpret,
     bkv, sk, _ = k.shape
     g = _geometry(q, k, block_q, block_k, heads, kv_heads, causal, window)
     bq, bk, nq, nk = g["bq"], g["bk"], g["nq"], g["nk"]
+    group, q_steps = g["group"], g["q_steps"]
     tile = dict(scale=scale, causal=causal, window=window, block_q=bq,
                 block_k=bk, offset=g["offset"])
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)             # (bh, s, 1)
-    lse3 = lse[:, :, None]                              # (bh, s, 1)
+    operands = (q, k, v, do, lse[:, :, None], delta)    # lse as (bh, s, 1)
+    vmem = _fused_bwd_vmem(s, sk, d, bq, bk, group, q.dtype.itemsize)
+    if vmem > _vmem_budget():
+        return _bwd_by_tiles(operands, g, tile, interpret)
 
+    def q_map(b, h, j, t):
+        first, last = _q_range(j, bq, bk, g["offset"], causal, window, nq)
+        return b * group + h, _tile(first, last, t), _I0
+
+    def k_map(b, h, j, t):
+        return b, j, _I0
+
+    def dk_map(b, h, j, t):
+        # a key tile's sums are written during the group's last query head;
+        # before it the index stands still, so nothing unwritten goes out
+        return b, jnp.where(h == group - 1, j, _I0), _I0
+
+    k_rows = bk if group == 1 else sk
+    return tuple(pl.pallas_call(
+        functools.partial(_bwd_kernel, nq=nq, nk=nk, steps=q_steps,
+                          group=group, **tile),
+        grid=(bkv, group, nk, q_steps),
+        in_specs=[
+            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bk, d), k_map),
+            pl.BlockSpec((1, bk, d), k_map),
+            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bq, 1), q_map),
+            pl.BlockSpec((1, bq, 1), q_map),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, s, d), lambda b, h, j, t: (b * group + h, _I0,
+                                                        _I0)),
+            pl.BlockSpec((1, bk, d), dk_map),
+            pl.BlockSpec((1, bk, d), dk_map),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+            jax.ShapeDtypeStruct((bkv, sk, d), k.dtype),
+            jax.ShapeDtypeStruct((bkv, sk, d), v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((s, d), jnp.float32),
+            pltpu.VMEM((k_rows, d), jnp.float32),
+            pltpu.VMEM((k_rows, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
+        interpret=interpret,
+        name="flash_bwd",
+    )(*operands))
+
+
+def _bwd_by_tiles(operands, g, tile, interpret):
+    """The long-sequence form: dq from a kernel that walks a query tile's
+    key tiles, dk and dv from one that walks a key tile's query tiles.
+    Each makes the tile's probabilities for itself (seven products a tile),
+    and neither holds more than tiles."""
+    q, k = operands[:2]
+    bh, s, d = q.shape
+    bkv, sk, _ = k.shape
+    bq, bk, nq, nk = g["bq"], g["bk"], g["nq"], g["nk"]
+    causal, window = tile["causal"], tile["window"]
+    # what flash_bwd would ask for a sequence of one tile: at tiles of
+    # 1024 x 1024 more than a kernel gets unasked (31 MiB at head size 128,
+    # 36 at 256)
+    params = pltpu.CompilerParams(vmem_limit_bytes=_fused_bwd_vmem(
+        bq, bk, d, bq, bk, 1, q.dtype.itemsize))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, nk=nk, steps=g["k_steps"], **tile),
         grid=(bh, nq, g["k_steps"]),
@@ -356,17 +510,17 @@ def _bwd(scale, causal, window, heads, kv_heads, block_q, block_k, interpret,
         out_specs=pl.BlockSpec((1, bq, d), g["q_map"]),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dq",
-    )(q, k, v, do, lse3, delta)
+    )(*operands)
 
     group, q_steps = g["group"], g["q_steps"]
 
     def q_map(b, j, t):
         first, last = _q_range(j, bq, bk, g["offset"], causal, window, nq)
-        head = _div(b, kv_heads) * heads + _rem(b, kv_heads) * group \
-            + _div(t, q_steps)
-        return head, _tile(first, last, _rem(t, q_steps)), _I0
+        return b * group + _div(t, q_steps), \
+            _tile(first, last, _rem(t, q_steps)), _I0
 
     def k_map(b, j, t):
         return b, j, _I0
@@ -389,15 +543,16 @@ def _bwd(scale, causal, window, heads, kv_heads, block_q, block_k, interpret,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bkv, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bkv, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((bkv, sk, d), k.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q, k, v, do, lse3, delta)
+    )(*operands)
     return dq, dk, dv
 
 

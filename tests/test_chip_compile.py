@@ -66,6 +66,13 @@ def _qkv(one_chip):
                                 sharding=one_chip)
 
 
+def _kernels(compiled_text):
+    """The names of the program's Pallas kernels, sorted."""
+    return sorted(re.search(r"(flash_\w+)\)*/pallas_call", line).group(1)
+                  for line in compiled_text.splitlines()
+                  if "tpu_custom_call" in line)
+
+
 def test_flash_attention_fwd_compiles_for_v5e(one_chip, mosaic):
     q = _qkv(one_chip)
     compiled = jax.jit(lambda q, k, v: flash.flash_attention(
@@ -82,8 +89,24 @@ def test_flash_attention_fwd_bwd_compiles_for_v5e(one_chip, mosaic):
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, q, q).compile()
-    # forward (residuals), dq, and dk/dv kernels
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    # forward (residuals), and the one backward kernel for dq, dk and dv
+    assert _kernels(compiled.as_text()) == ["flash_bwd", "flash_fwd"]
+
+
+def _lowered_grad(one_chip, heads, kv_heads, d, tokens=8192, **kw):
+    """The gradient of causal flash attention over 8,192 tokens (or
+    ``tokens``) in bfloat16, lowered for the described chip and not yet
+    compiled."""
+    q = jax.ShapeDtypeStruct((1, heads, tokens, d), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, kv_heads, tokens, d), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash.flash_attention(q, k, v, causal=True,
+                                     **kw).astype(jnp.float32).sum()
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k)
 
 
 @pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
@@ -91,53 +114,104 @@ def test_flash_window_and_grouped_heads_compile_for_v5e(one_chip, mosaic,
                                                         window):
     """The decoder cell's attention at its real size: 8,192 tokens, 32 query
     heads over 4 key/value heads of 128, tiles of 1024 x 1024, with and
-    without the window of 2,048; forward, dq and dk/dv kernels.  With a
-    window or grouped heads the Pallas kernels are the default path."""
-    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
-                             sharding=one_chip)
-    k = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
-                             sharding=one_chip)
-
-    def loss(q, k, v):
-        return flash.flash_attention(
-            q, k, v, causal=True, window=window, block_q=1024,
-            block_k=1024).astype(jnp.float32).sum()
-
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        q, k, k).compile().as_text()
-    assert text.count("tpu_custom_call") == 3
+    without the window of 2,048; the forward kernel and the one backward
+    kernel, which holds dq, dk and dv of the sequence in VMEM (12 MiB of
+    float32) and asks for what that takes.  With a window or grouped heads
+    the Pallas kernels are the default path."""
+    text = _lowered_grad(one_chip, 32, 4, 128, window=window, block_q=1024,
+                         block_k=1024).compile().as_text()
+    assert _kernels(text) == ["flash_bwd", "flash_fwd"]
     # no (S, S) score array in the program
     assert not re.search(r"\[(\d+,)*8192,8192\]", text)
 
 
-@pytest.mark.parametrize("tiles", [(512, 1024), (1024, 1024)],
-                         ids=["fits", "refused"])
+@pytest.mark.parametrize("tiles", [(1024, 1024), (512, 1024), (2048, 512)],
+                         ids=["its_own", "as_before_pr35", "refused"])
 def test_flash_at_head_size_256_compiles_for_v5e_at_the_tiles_it_uses(
         one_chip, mosaic, tiles):
     """Latent attention's shape (``text.LatentAttention``): 8,192 tokens, 20
     ungrouped heads of 256, no window, through ``use_pallas=True`` (without
     it this shape takes XLA's attention on a chip, which holds 20 x 8,192^2
-    scores).  The blocks and accumulators are twice those of head size 128:
-    the other decoder's 1024 x 1024 tiles overrun the scoped VMEM in the
-    dk/dv kernel, the family's own 512 x 1024 fit."""
+    scores).  The blocks and sums are twice those of head size 128.  The
+    backward kernel asks for its VMEM, so the other decoder's 1024 x 1024
+    tiles fit here too and are the family's (the dk/dv kernel it replaced
+    overran the 16 MiB a kernel gets unasked with them, which held the
+    family to 512 x 1024); what is refused first is the FORWARD kernel,
+    which asks for nothing, at 2048 x 512."""
     from incubator_mxnet_tpu.gluon.model_zoo import text
 
-    q = jax.ShapeDtypeStruct((1, 20, 8192, 256), jnp.bfloat16,
-                             sharding=one_chip)
-
-    def loss(q, k, v):
-        return flash.flash_attention(
-            q, k, v, causal=True, block_q=tiles[0], block_k=tiles[1],
-            use_pallas=True).astype(jnp.float32).sum()
-
-    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
-    if tiles != (text.LatentAttention.BLOCK_Q, text.LatentAttention.BLOCK_K):
-        with pytest.raises(Exception, match="vmem"):
+    assert (text.LatentAttention.BLOCK_Q,
+            text.LatentAttention.BLOCK_K) == (1024, 1024)
+    lowered = _lowered_grad(one_chip, 20, 20, 256, block_q=tiles[0],
+                            block_k=tiles[1], use_pallas=True)
+    if tiles == (2048, 512):
+        with pytest.raises(Exception, match="vmem.*flash_fwd"):
             lowered.compile()
         return
     compiled_text = lowered.compile().as_text()
-    assert compiled_text.count("tpu_custom_call") == 3
+    assert _kernels(compiled_text) == ["flash_bwd", "flash_fwd"]
     assert not re.search(r"\[(\d+,)*8192,8192\]", compiled_text)
+
+
+def _asked(lowered):
+    """Bytes of VMEM each Pallas kernel of a lowered program asks for."""
+    return {name: int(size) for size, name in re.findall(
+        r'size[^:]*: (\d+)\}\]\}", kernel_name = "(flash_\w+)"',
+        lowered.as_text())}
+
+
+@pytest.mark.parametrize("heads,kv_heads,d", [(32, 4, 128), (20, 20, 256)],
+                         ids=["32_over_4_heads_of_128", "20_heads_of_256"])
+def test_flash_backward_asks_for_its_vmem_from_the_shapes(one_chip, mosaic,
+                                                          heads, kv_heads, d):
+    """At both decoder cells' shapes (tiles 1024 x 1024) the one backward
+    kernel asks the compiler for exactly what ``_fused_bwd_vmem`` counts
+    from the shapes (the lowered call's ``scoped_memory_configs``), which
+    is under the budget of three quarters of the chip's 128 MiB and enough:
+    the compile passes.  The forward kernel asks for nothing."""
+    lowered = _lowered_grad(one_chip, heads, kv_heads, d, block_q=1024,
+                            block_k=1024, use_pallas=True)
+    want = flash._fused_bwd_vmem(8192, 8192, d, 1024, 1024,
+                                 heads // kv_heads, 2)
+    assert _asked(lowered) == {"flash_bwd": want}
+    # 45 and 50 MiB, where the compiler takes 28 and under 40
+    assert 40 * 2 ** 20 < want < flash._vmem_budget() * 5 // 8
+    assert flash._vmem_budget() == 96 * 2 ** 20
+    lowered.compile()
+
+
+@pytest.mark.parametrize("heads,kv_heads,d,window,tokens,fused_mib", [
+    (20, 20, 256, None, 16384, 66), (20, 20, 256, None, 32768, None),
+    (32, 32, 128, None, 65536, 94), (32, 32, 128, None, 131072, None),
+    (32, 4, 128, None, 32768, 93), (32, 4, 128, None, 65536, None),
+    (32, 4, 128, 2048, 32768, 93), (32, 4, 128, 2048, 65536, None)],
+    ids=str)
+def test_flash_backward_compiles_on_both_sides_of_its_vmem_budget(
+        one_chip, mosaic, heads, kv_heads, d, window, tokens, fused_mib):
+    """Each caller's shape (tiles 1024 x 1024) at the longest power of two
+    whose sums fit the budget, where the one kernel asks for 66 to 94 MiB
+    of the chip's 128 and the compiler grants it, and at the next, where dq
+    and dk/dv come from the two tile kernels.  Those ask too (what the fused
+    kernel would for a sequence of one tile: 31 MiB at head size 128, 36 at
+    256): unasked, ``flash_bwd_dkv`` at 1024 x 1024 and head size 256 overran
+    the 16 MiB a kernel gets, which is what held that family to 512 x 1024
+    before PR 35."""
+    mib = 2 ** 20
+    lowered = _lowered_grad(one_chip, heads, kv_heads, d, tokens,
+                            window=window, block_q=1024, block_k=1024,
+                            use_pallas=True)
+    if fused_mib:
+        assert _asked(lowered) == {"flash_bwd": fused_mib * mib}
+        assert fused_mib * mib <= flash._vmem_budget()
+        kernels = ["flash_bwd", "flash_fwd"]
+    else:
+        tile = (31 if d == 128 else 36) * mib
+        assert _asked(lowered) == {"flash_bwd_dq": tile,
+                                   "flash_bwd_dkv": tile}
+        kernels = ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    text = lowered.compile().as_text()
+    assert _kernels(text) == kernels
+    assert not re.search(r"\[(\d+,)*%d,%d\]" % (tokens, tokens), text)
 
 
 def test_grouped_expert_product_is_the_compilers_own_kernel(one_chip, mosaic):

@@ -1,5 +1,7 @@
-"""Window and grouped heads in the flash kernels (forward and both backward
-kernels, against the masked dense ``attention_reference``), and the no-drop
+"""Window and grouped heads in the flash kernels (forward, the one backward
+kernel that makes dq, dk and dv, and the two-kernel form a sequence falls
+back to whose sums do not fit VMEM, against the masked dense
+``attention_reference``), and the no-drop
 expert layer of a chip that holds a share of the experts
 (``parallel/moe.py``): against a loop over tokens, under a router forced onto
 one expert, its gradients, and the eight shares that add up to the uncut
@@ -71,9 +73,11 @@ def _projected(window, weight):
 
 
 def _kernel_calls(fn, *args):
+    """How often the gradient's program calls ``flash_fwd``, ``flash_bwd``
+    and either of the two kernels ``flash_bwd`` replaced."""
     text_of = str(jax.make_jaxpr(jax.grad(fn, (0, 1, 2)))(*args))
     return tuple(len(re.findall(r"name=%s\b" % kernel, text_of))
-                 for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+                 for kernel in ("flash_fwd", "flash_bwd", r"flash_bwd_d\w+"))
 
 
 @pytest.mark.parametrize("window,hkv", [(8, 2), (None, 2), (24, 4)],
@@ -96,9 +100,9 @@ def test_a_region_that_keeps_the_named_runs_flash_forward_once(window, hkv,
     got = jax.grad(kept, (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
-    assert _kernel_calls(f, q, k, v) == (1, 1, 1)
-    assert _kernel_calls(kept, q, k, v) == (1, 1, 1)
-    assert _kernel_calls(jax.checkpoint(f), q, k, v) == (2, 1, 1)
+    assert _kernel_calls(f, q, k, v) == (1, 1, 0)
+    assert _kernel_calls(kept, q, k, v) == (1, 1, 0)
+    assert _kernel_calls(jax.checkpoint(f), q, k, v) == (2, 1, 0)
     jax.ad_checkpoint.print_saved_residuals(kept, q, k, v)
     saved = [line.split()[0] for line in capsys.readouterr().out.splitlines()
              if "flash_attention" in line]
@@ -142,6 +146,108 @@ def test_outside_a_region_the_names_are_all_that_changed():
     assert [e for e in got if e[0] != "name"] == want
     for a, b in zip(grad_of(named)(q, k, v), grad_of(unnamed)(q, k, v)):
         np.testing.assert_array_equal(a, b)
+
+
+#: (query heads, key/value heads, queries, keys, head size, causal, window,
+#: block_q, block_k): what the callers differ in, which the backward kernel
+#: reads from the shapes
+_BACKWARD_CASES = {
+    "window_grouped": (4, 2, 64, 64, 16, True, 24, 16, 32),
+    "full_grouped_wide_query_tile": (8, 2, 64, 64, 16, True, None, 32, 16),
+    "own_heads": (4, 4, 64, 64, 16, True, None, 16, 16),
+    "longer_keys": (6, 3, 16, 48, 16, True, None, 8, 16),
+    "longer_keys_window": (6, 3, 16, 48, 16, True, 20, 8, 16),
+    "longer_keys_no_mask": (6, 3, 16, 48, 16, False, None, 8, 16),
+    "tiles_that_do_not_divide": (3, 1, 60, 60, 8, True, 7, 16, 25),
+    "head_size_256": (2, 2, 32, 32, 256, True, None, 16, 8),
+}
+
+
+def _backward_case(name):
+    h, hkv, s, sk, d, causal, window, bq, bk = _BACKWARD_CASES[name]
+    q, k, v = _qkv(h=h, hkv=hkv, s=s, sk=sk, d=d, seed=3)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               block_q=bq, block_k=bk)
+
+    def dense(q, k, v):
+        return attention_reference(q, k, v, causal=causal, window=window)
+
+    weight = jnp.cos(dense(q, k, v))
+    return ((q, k, v), lambda *a: (flash(*a) * weight).sum(),
+            lambda *a: (dense(*a) * weight).sum())
+
+
+@pytest.fixture
+def no_vmem(monkeypatch):
+    """The budget the backward pass holds the fused kernel's VMEM against,
+    shrunk to nothing: every sequence is then a long one."""
+    import importlib
+
+    module = importlib.import_module(
+        "incubator_mxnet_tpu.parallel.flash_attention")
+    monkeypatch.setattr(module, "_vmem_budget", lambda: 0)
+    module._make_attn.cache_clear()
+    yield
+    module._make_attn.cache_clear()
+
+
+@pytest.mark.parametrize("case", sorted(_BACKWARD_CASES))
+def test_flash_backward_is_one_kernel_with_the_dense_gradients(case):
+    args, flash, dense = _backward_case(case)
+    assert _kernel_calls(flash, *args) == (1, 1, 0)
+    got = jax.grad(flash, (0, 1, 2))(*args)
+    want = jax.grad(dense, (0, 1, 2))(*args)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=5e-5, atol=5e-5)
+
+
+@pytest.mark.parametrize("case", ["window_grouped", "own_heads",
+                                  "longer_keys_window",
+                                  "tiles_that_do_not_divide"])
+def test_flash_backward_of_a_sequence_whose_sums_do_not_fit(case, request):
+    """Where dq of the sequence (and, under grouped heads, dk and dv) would
+    overrun the VMEM budget, dq and dk/dv come from a kernel each, which hold
+    tiles only: the same tiles summed in the same order, so in float32 the
+    same gradients bit for bit."""
+    args, flash, dense = _backward_case(case)
+    fused = jax.grad(flash, (0, 1, 2))(*args)
+    request.getfixturevalue("no_vmem")
+    assert _kernel_calls(flash, *args) == (1, 0, 2)
+    by_tiles = jax.grad(flash, (0, 1, 2))(*args)
+    for a, b, c in zip(by_tiles, fused, jax.grad(dense, (0, 1, 2))(*args)):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_allclose(a, c, rtol=5e-5, atol=5e-5)
+
+
+def test_what_the_backward_kernel_asks_of_vmem_follows_the_shapes():
+    """Float32 sums (dq of the sequence; dk and dv of a tile, or of the
+    sequence under grouped heads), every block twice, six score tiles: both
+    decoder cells' shapes ask for about half the budget.  At tiles of
+    1024 x 1024 the longest power of two that still fits is 65,536 tokens at
+    head size 128 (32,768 where eight query heads share a key/value head)
+    and 16,384 at head size 256; the next falls back to the two tile
+    kernels, which ask for what the fused one would for one tile."""
+    from incubator_mxnet_tpu.parallel.flash_attention import (
+        _VMEM_BYTES, _fused_bwd_vmem, _vmem_budget)
+
+    mib = 2 ** 20
+    assert _vmem_budget() == 96 * mib == _VMEM_BYTES * 3 // 4
+    # 8,192 x (32 over 4 heads of 128), tiles 1024 x 1024: three sums of
+    # 4 MiB, blocks 2 x 4.5 MiB, tiles 24 MiB
+    assert _fused_bwd_vmem(8192, 8192, 128, 1024, 1024, 8, 2) == 45 * mib
+    # 8,192 x 20 heads of 256, tiles 1024 x 1024: 8 + 2 x 1, 2 x 8, 24 MiB
+    assert _fused_bwd_vmem(8192, 8192, 256, 1024, 1024, 1, 2) == 50 * mib
+    longest = {(d, group): max(
+        s for s in (2 ** n for n in range(10, 22))
+        if _fused_bwd_vmem(s, s, d, 1024, 1024, group, 2) <= _vmem_budget())
+        for d, group in ((128, 1), (128, 8), (256, 1))}
+    assert longest == {(128, 1): 65536, (128, 8): 32768,
+                       (256, 1): 16384}, longest
+    # the first that falls back at head size 256, and its tile kernels
+    assert _fused_bwd_vmem(32768, 32768, 256, 1024, 1024, 1, 2) == 98 * mib
+    assert _fused_bwd_vmem(1024, 1024, 256, 1024, 1024, 1, 2) == 36 * mib
 
 
 def test_flash_grouped_heads_without_a_mask_and_with_longer_keys():
