@@ -123,16 +123,23 @@ class GatedFFN(HybridBlock):
 class ExpertFFN(HybridBlock):
     """The expert layer of a chip that holds ``experts_held = (first,
     count)`` of ``num_experts`` experts of width ``width``, plus the shared
-    expert.  ``bias`` (selection only) and ``counts`` (the last step's
-    assignments per expert, over all the experts; written through the
-    trace's aux channel as BatchNorm's running statistics are) are not
-    trained.  No assignment is dropped.  ``keep_choices`` adds ``chosen``,
-    the last step's chosen experts of every token (tokens x top-k, as
-    floats), written the same way: a comparison with a reference can then
-    be made under the step's own routing decisions."""
+    expert where the family has one (``shared``).  ``score`` is the router's
+    scoring rule, ``centred`` the block of tokens over whose mean the
+    selection is centred (0: not), and ``act`` the experts' gate activation,
+    as ``parallel/moe.py`` names them; the shared expert is gated-SiLU.  The
+    block takes the experts' input and, optionally, a second tensor of the
+    same shape that the ROUTER reads instead (a family whose router stands
+    before the attention).  ``bias`` (selection only) and ``counts`` (the
+    last step's assignments per expert, over all the experts; written
+    through the trace's aux channel as BatchNorm's running statistics are)
+    are not trained.  No assignment is dropped.  ``keep_choices`` adds
+    ``chosen``, the last step's chosen experts of every token (tokens x
+    top-k, as floats), written the same way: a comparison with a reference
+    can then be made under the step's own routing decisions."""
 
     def __init__(self, hidden, num_experts, top_k, width, experts_held=None,
                  route_norm=True, route_scale=1.0, keep_choices=False,
+                 shared=True, score="sigmoid", act="silu", centred=0,
                  **kwargs):
         super().__init__(**kwargs)
         self._held = tuple(experts_held or (0, num_experts))
@@ -142,7 +149,10 @@ class ExpertFFN(HybridBlock):
                              % (experts_held, num_experts))
         self._width = width
         self._route = dict(top_k=top_k, route_norm=bool(route_norm),
-                           route_scale=float(route_scale))
+                           route_scale=float(route_scale), score=score)
+        if centred:
+            self._route["centred"] = int(centred)
+        self._act = act
         with self.name_scope():
             get = self.params.get
             self.router_weight = get("router_weight", shape=(num_experts, 0),
@@ -160,7 +170,8 @@ class ExpertFFN(HybridBlock):
                           allow_deferred_init=True)
             self.w2 = get("w2", shape=(count, width, 0),
                           allow_deferred_init=True)
-            self.shared = GatedFFN(hidden, width, prefix="shared_")
+            self.shared = GatedFFN(hidden, width, prefix="shared_") \
+                if shared else None
 
     def infer_shape(self, x, *args):
         d, count = x.shape[-1], self._held[1]
@@ -171,21 +182,24 @@ class ExpertFFN(HybridBlock):
             self.chosen.shape = (math.prod(x.shape[:-1]),
                                  self.chosen.shape[1])
 
-    def hybrid_forward(self, F, x, router_weight, bias, counts, w1, w3, w2,  # noqa: N803
-                       chosen=None):
+    def hybrid_forward(self, F, x, route_on=None, *, router_weight, bias,  # noqa: N803
+                       counts, w1, w3, w2, chosen=None):
         tokens = x.reshape((-1, x.shape[-1]))
         weights, sel, load = F.contrib.moe_router(
-            tokens, router_weight, bias, **self._route)
+            tokens if route_on is None
+            else route_on.reshape((-1, route_on.shape[-1])),
+            router_weight, bias, **self._route)
         rows, sizes, row, order = F.contrib.moe_dispatch(
             tokens, sel, experts_held=self._held)
-        ys = F.contrib.moe_experts(rows, w1, w3, w2, sizes)
+        ys = F.contrib.moe_experts(rows, w1, w3, w2, sizes, act=self._act)
         y = F.contrib.moe_combine(ys, weights, sizes, row, order)
         tc = tracing.current_trace()
         if tc is not None and tc.training:
             tc.write_aux(self.counts, load._data)
             if self.chosen is not None:
                 tc.write_aux(self.chosen, sel._data.astype("float32"))
-        return y.reshape(x.shape) + self.shared(x)
+        y = y.reshape(x.shape)
+        return y if self.shared is None else y + self.shared(x)
 
 
 class AfmoeLayer(HybridBlock):

@@ -19,13 +19,15 @@ MoE systems' overflow path.
 The second half of the file is the NO-DROP expert layer of a chip that holds
 a share of the experts (``experts_held = (first, count)``), as four ops of
 the registry: ``_contrib_moe_router`` routes every token over ALL the
-experts (sigmoid scores, top-k with a selection-only bias, normalise and
-scale) and counts the assignments per expert; ``_contrib_moe_dispatch``
-sorts the assignments that fall on the experts held here by expert and
-gathers their tokens' rows into that order; ``_contrib_moe_experts`` is ONE
-grouped product per matrix over those rows (``lax.ragged_dot``: an
-expert's matrix meets only its own rows); ``_contrib_moe_combine`` sums
-each token's weighted results.  What absent experts would add is left out.
+experts (by the model's scoring rule: sigmoid scores, top-k with a
+selection-only bias, normalise and scale; or top-k of the logits and a
+softmax over the chosen; the selection optionally centred over blocks
+of tokens) and counts the assignments per expert;
+``_contrib_moe_dispatch`` sorts the assignments that fall on the experts
+held here by expert and gathers their tokens' rows into that order;
+``_contrib_moe_experts`` is ONE grouped product per matrix over those rows
+(``lax.ragged_dot``: an expert's matrix meets only its own rows);
+``_contrib_moe_combine`` sums each token's weighted results.  What absent experts would add is left out.
 The row buffer holds every assignment there can be (tokens x top-k), so no
 token is ever dropped, whatever the router does, and what is done with it
 stops at ``n = sum(sizes)``, the rows that are in a group, a number only the
@@ -172,21 +174,41 @@ def _one_hot(idx, n):
 
 
 def moe_route(x, router_weight, select_bias, top_k=1, route_norm=True,
-              route_scale=1.0):
+              route_scale=1.0, score="sigmoid", centred=0):
     """Token-choice routing over all the experts.
 
     x: (T, d); router_weight: (E, d); select_bias: (E,), added to the scores
-    for the SELECTION only.  Scores are ``sigmoid(x Wr^T)`` in float32.
-    Returns ``(weights (T, K) float32, chosen experts (T, K) int32,
-    assignments per expert (E,) float32)``; the weights are the chosen
-    scores, normalised over the chosen if ``route_norm``, times
-    ``route_scale``.
+    for the SELECTION only; with ``centred`` = n the selection also reads
+    each expert's score LESS ITS MEAN over the block of n consecutive tokens
+    the token lies in (T a multiple of n), a selection bias that follows the
+    scores along the sequence and as they move, so that what neighbouring
+    tokens share in an expert's score chooses nothing.  The weights never
+    see either.  ``score`` is the model's scoring rule, in float32 either
+    way.  ``"sigmoid"``: the scores are ``sigmoid(x Wr^T)``
+    and the weights the chosen scores, normalised over the chosen if
+    ``route_norm``.  ``"softmax"``: the scores are the logits ``x Wr^T``
+    themselves and the weights a softmax over the CHOSEN logits (they add up
+    to one, so ``route_norm`` changes nothing and is not applied).  Returns
+    ``(weights (T, K) float32, chosen experts (T, K) int32, assignments per
+    expert (E,) float32)``, the weights times ``route_scale``.
     """
-    scores = jax.nn.sigmoid(jnp.dot(
+    if score not in ("sigmoid", "softmax"):
+        raise ValueError("moe_route: score=%r" % (score,))
+    if centred and x.shape[0] % centred:
+        raise ValueError("moe_route: %d tokens in blocks of centred=%d"
+                         % (x.shape[0], centred))
+    scores = jnp.dot(
         x.astype(jnp.float32), router_weight.astype(jnp.float32).T,
-        precision=jax.lax.Precision.HIGHEST))
+        precision=jax.lax.Precision.HIGHEST)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(scores)
+    select = scores
+    if centred:
+        blocks = scores.reshape((-1, centred, scores.shape[-1]))
+        select = (blocks - jnp.mean(blocks, 1, keepdims=True)).reshape(
+            scores.shape)
     _, sel = jax.lax.top_k(
-        scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)),
+        select + jax.lax.stop_gradient(select_bias.astype(jnp.float32)),
         top_k)
     # a remat region keeps the choice: made again from the recomputed
     # scores, a near-tie falls the other way and the backward pass would
@@ -194,7 +216,9 @@ def moe_route(x, router_weight, select_bias, top_k=1, route_norm=True,
     sel = checkpoint_name(sel.astype(jnp.int32), REMAT_KEEP)
     chosen = _one_hot(sel, scores.shape[-1])             # (T, K, E)
     w = jnp.einsum("tke,te->tk", chosen, scores)
-    if route_norm:
+    if score == "softmax":
+        w = jax.nn.softmax(w, -1)
+    elif route_norm:
         w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
     return w * route_scale, sel, jnp.sum(chosen, (0, 1))
 
@@ -277,11 +301,16 @@ def moe_dispatch(x, sel, experts_held=(0, 1)):
     return _gather_rows(x, order, row, jnp.sum(sizes)), sizes, row, order
 
 
-def moe_experts(rows, w1, w3, w2, sizes):
-    """Gated-SiLU feed-forward of each expert over its own rows, as grouped
-    products: rows (R, d) sorted by expert, ``sizes`` (G,) rows each;
-    w1, w3: (G, d, f); w2: (G, f, d).  What comes out for the rows past
-    ``sum(sizes)`` is not defined."""
+#: the gate's activation of a gated feed-forward, by the name a model gives it
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def moe_experts(rows, w1, w3, w2, sizes, act="silu"):
+    """Gated feed-forward of each expert over its own rows, ``(act(x W1) *
+    (x W3)) W2`` with ``act`` the gate's activation (``"silu"`` or
+    ``"relu"``), as grouped products: rows (R, d) sorted by expert,
+    ``sizes`` (G,) rows each; w1, w3: (G, d, f); w2: (G, f, d).  What comes
+    out for the rows past ``sum(sizes)`` is not defined."""
     # for operands narrower than float32 the precision is pinned: the TPU's
     # grouped-product kernel refuses them under a process-wide
     # jax_default_matmul_precision of "highest"
@@ -289,7 +318,7 @@ def moe_experts(rows, w1, w3, w2, sizes):
         jax.lax.ragged_dot, group_sizes=sizes,
         precision=None if rows.dtype == jnp.float32
         else jax.lax.Precision.DEFAULT)
-    h = jax.nn.silu(dot(rows, w1)) * dot(rows, w3)
+    h = _GATES[act](dot(rows, w1)) * dot(rows, w3)
     return dot(h, w2)
 
 

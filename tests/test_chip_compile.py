@@ -214,6 +214,31 @@ def test_flash_backward_compiles_on_both_sides_of_its_vmem_budget(
     assert not re.search(r"\[(\d+,)*%d,%d\]" % (tokens, tokens), text)
 
 
+@pytest.mark.parametrize("window", [4096, None], ids=["window", "full"])
+def test_flash_at_28_over_4_heads_and_16384_tokens_compiles_for_v5e(
+        one_chip, mosaic, window):
+    """The third decoder cell's attention at its real size
+    (``text.GroupedAttention``): 16,384 tokens, 28 query heads over 4
+    key/value heads of 128 (a group of SEVEN: the kernels divide by a number
+    that is no power of two, and dk, dv outlive seven query heads), tiles of
+    1024 x 1024, under the window of 4,096 and without one.  The one
+    backward kernel holds dq, dk and dv of the sequence in VMEM (24 MiB of
+    float32) and asks for what ``_fused_bwd_vmem`` counts from the shapes:
+    61 MiB of the 96 MiB budget."""
+    from incubator_mxnet_tpu.gluon.model_zoo import text
+
+    assert (text.GroupedAttention.BLOCK_Q,
+            text.GroupedAttention.BLOCK_K) == (1024, 1024)
+    lowered = _lowered_grad(one_chip, 28, 4, 128, 16384, window=window,
+                            block_q=1024, block_k=1024, use_pallas=True)
+    want = flash._fused_bwd_vmem(16384, 16384, 128, 1024, 1024, 7, 2)
+    assert _asked(lowered) == {"flash_bwd": want}
+    assert 60 * 2 ** 20 < want < 62 * 2 ** 20 < flash._vmem_budget()
+    compiled_text = lowered.compile().as_text()
+    assert _kernels(compiled_text) == ["flash_bwd", "flash_fwd"]
+    assert not re.search(r"\[(\d+,)*16384,16384\]", compiled_text)
+
+
 def test_grouped_expert_product_is_the_compilers_own_kernel(one_chip, mosaic):
     """``lax.ragged_dot`` over the held experts at the published widths
     becomes XLA's grouped-product kernel (a custom call it names
@@ -234,38 +259,46 @@ def test_grouped_expert_product_is_the_compilers_own_kernel(one_chip, mosaic):
     assert not re.search(r" (dot|convolution)\(", text)
 
 
-def test_dispatch_and_combine_are_row_movers_that_stop_at_n(one_chip, mosaic):
-    """Dispatch and combine at the cell's shapes (8,192 tokens, 8 choices,
-    hidden 2,048, bf16), forward and backward: the pass back to tokens and
-    both transposes are Pallas kernels (``moe_slabs``, ``moe_rows``,
-    ``moe_tokens``: a row travels as 4 KiB of 32-bit words,
-    ``parallel/moe_rows.py``), the one gather left is the rows into expert
-    order, no float32 array of the 65,536-row buffer's size is left, and the
-    dynamic extent stays inside the kernels: no ``while`` or ``conditional``
-    in the program."""
+@pytest.mark.parametrize("tokens,top_k,d,held", [
+    (8192, 8, 2048, 16), (8192, 4, 2048, 8), (16384, 6, 2560, 8)],
+    ids=["8_of_128_at_2048", "4_of_64_at_2048", "6_of_64_at_2560"])
+def test_dispatch_and_combine_are_row_movers_that_stop_at_n(
+        one_chip, mosaic, tokens, top_k, d, held):
+    """Dispatch and combine at each decoder cell's shapes (8,192 tokens of
+    hidden 2,048 under 8 and 4 choices; 16,384 tokens of hidden 2,560 under
+    6: 98,304 rows that travel as slabs of 4,096 values; bf16), forward and
+    backward: the pass back to tokens and both transposes are Pallas kernels
+    (``moe_slabs``, ``moe_rows``, ``moe_tokens``: a row travels as a slab of
+    32-bit words, ``parallel/moe_rows.py``), the one gather left is the rows
+    into expert order, no float32 array of the row buffer's size is left,
+    and the dynamic extent stays inside the kernels: no ``while`` or
+    ``conditional`` in the program."""
     from incubator_mxnet_tpu.parallel import moe
+
+    rows_ = tokens * top_k
 
     def shape(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     def loss(x, ys, weights, sel):
         rows, sizes, row, order = moe.moe_dispatch(x, sel,
-                                                   experts_held=(0, 16))
+                                                   experts_held=(0, held))
         out = moe.moe_combine(ys + rows, weights, sizes, row, order)
         return out.astype(jnp.float32).sum()
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        shape(8192, 2048), shape(65536, 2048),
-        shape(8192, 8, dtype=jnp.float32),
-        shape(8192, 8, dtype=jnp.int32)).compile().as_text()
+        shape(tokens, d), shape(rows_, d),
+        shape(tokens, top_k, dtype=jnp.float32),
+        shape(tokens, top_k, dtype=jnp.int32)).compile().as_text()
     kernels = [re.search(r"(moe_[a-z]+)/pallas_call", line).group(1)
                for line in text.splitlines() if "tpu_custom_call" in line]
     assert sorted(kernels) == ["moe_rows"] + ["moe_slabs"] * 3 + [
         "moe_tokens"] * 2, kernels
     assert re.findall(r"(\w+\[[\d,]+\])\S* gather\(", text) == [
-        "bf16[65536,2048]"]
+        "bf16[%d,%d]" % (rows_, d)]
     assert not re.search(r" (while|conditional)\(", text)
-    assert not re.search(r"f32\[(65536,2048|8192,8,2048)\]", text)
+    assert not re.search(r"f32\[(%d,%d|%d,%d,%d)\]"
+                         % (rows_, d, tokens, top_k, d), text)
 
 
 def test_stem_maxpool_has_no_pallas_form(one_chip, mosaic):
