@@ -34,11 +34,15 @@ stops at ``n = sum(sizes)``, the rows that are in a group, a number only the
 step itself knows: the grouped product's work follows the groups, and three
 of the four passes that move rows (back to tokens, and both transposes) are
 the Pallas kernels of ``moe_rows.py``, whose grid covers the whole buffer
-and whose steps past ``n`` do nothing.  A kernel is one op in the device
-trace whatever its grid does, which a ``lax.cond`` between a small buffer
-and the full one is not (tried on the chip, PR 30: 6 % of a step faster,
-and the ``conditional`` stands in the trace as one op OVER its own ops, so
-that no sum of ops is the step's time).  The fourth pass, the rows into
+and whose steps past ``n`` do nothing.  They take the rows and the tokens
+at the model's own width; a row is padded to whole (8, 128) tiles of words
+only where a DMA moves it (a slab: 2,560 bf16 values in 8 KiB), inside the
+kernels, so nothing of the buffer's size is padded or cut around them.  A
+kernel is one op in the device trace whatever its grid does, which a
+``lax.cond`` between a small buffer and the full one is not (tried on the
+chip, PR 30: 6 % of a step faster, and the ``conditional`` stands in the
+trace as one op OVER its own ops, so that no sum of ops is the step's
+time).  The fourth pass, the rows into
 expert order, stays XLA's gather over every row: the buffer it fills is
 what a caller may take a statistic of (a float8 scale a tensor), so all of
 it is written.  Past ``n`` the product's result and both cotangents hold

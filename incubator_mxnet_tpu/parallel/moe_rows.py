@@ -6,11 +6,19 @@ A row that is scattered can be moved by one DMA only if it lies in a
 leading, untiled dimension: Mosaic slices the tiled second-minor dimension
 of a ``(rows, d)`` array in steps of 8.  So a row travels as a SLAB, ``sub``
 x 128 words of 32 bits that hold its ``d`` values (bf16: value ``c`` in the
-low half of word ``c`` and value ``c + dp / 2`` in the high half), 4 KiB
-that lie together in HBM:
+low half of word ``c`` and value ``c + sub * 128`` in the high half), a
+whole number of (8, 128) tiles that lie together in HBM: 4 KiB for 2,048
+bf16 values, 8 KiB for 2,560.  Only the slab is that wide.  The arrays of
+rows and of tokens go into the kernels and come out of them at their own
+width (up to whole tiles of 128 lanes, which every model's width is; the
+tests' 16 is padded by the one ``_pad`` below), and the loops over a
+slab's word-rows know from that static width which tiles a word-row holds
+(``_each_word_row``): no pass over a whole buffer pads it to the slab's
+width for a kernel or cuts the result back.
 
 * :func:`to_slabs` turns the first ``n`` rows of a ``(rows, d)`` array into
-  slabs; grid steps past ``n`` do nothing and write nothing back.  The two
+  slabs; grid steps past ``n`` do nothing and write nothing back, and what
+  a slab has past its row's width is zero bits or not written.  The two
   movers below make the slabs of their source themselves.
 * :func:`rows_from_tokens` is ``out[r] = scale[r] * src[tok[r]]`` for ``r <
   n``, one DMA a row into a dense block, scaled in float32, and beside it
@@ -74,16 +82,20 @@ def _carrier(dtype):
 
 
 def _geometry(d, dtype):
-    """(values a word holds, padded width, word-rows of 128 lanes a row's
-    slab has) for rows of ``d`` values that travel as ``dtype``.  A slab is
-    a whole number of (8, 128) tiles, so that a row's DMA is aligned: a
-    width that is no multiple of 1,024 words (2,048 two-byte values, 1,024
-    float32) is padded up to the next one, so a row of 2,048 bfloat16
-    values travels as it is, one of 1,536 as 2,048 and one of 2,560 as
-    4,096 (3,072 in float32): 60 % more bytes a moved row."""
+    """(values a word holds, the width ``w`` of the arrays the kernels take,
+    word-rows of 128 lanes a row's slab has) for rows of ``d`` values that
+    travel as ``dtype``.  ``w`` is ``d`` up to whole tiles of 128 lanes:
+    every cell's own width, so no array is padded for a kernel.  Only the
+    SLAB is wider: a whole number of (8, 128) tiles of words, so that a
+    row's DMA is aligned, which is 1,024 words (2,048 two-byte values,
+    1,024 float32) or a multiple.  A row of 2,048 bfloat16 values fills its
+    slab; one of 1,536 lies in a slab of 2,048 and one of 2,560 in a slab
+    of 4,096 (3,072 in float32), 60 % more bytes than the row where a DMA
+    moves it and nowhere else: the words past ``w`` are never written and
+    never read (``_each_word_row``)."""
     pack = 4 // _carrier(dtype).itemsize
-    dp = _up(d, _LANES * _SUB * pack)
-    return pack, dp, dp // pack // _LANES
+    w = _up(d, _LANES)
+    return pack, w, _up(w, _LANES * _SUB * pack) // pack // _LANES
 
 
 def _bits(x):
@@ -99,33 +111,50 @@ def _lanes(tile):
     return pl.ds(pl.multiple_of(tile * np.int32(_LANES), _LANES), _LANES)
 
 
-def _word(x_ref, s, sub, pack):
-    """Word-row ``s`` of every row's slab, from a (rows, dp) block."""
+def _word(x_ref, s, sub, pack, high):
+    """Word-row ``s`` of every row's slab, from a (rows, w) block; without
+    ``high`` a two-byte word's upper half is zero bits."""
     lo = _bits(x_ref[:, _lanes(s)])
     if pack == 1:
         return lo
+    if not high:
+        return lo >> _SIXTEEN
     hi = _bits(x_ref[:, _lanes(s + np.int32(sub))])
     return (lo >> _SIXTEEN) | (hi & _HIGH)
 
 
-def _values(word, s, sub, pack):
+def _values(word, s, sub, pack, high):
     """((tile, its (rows, 128) float32 values), ...) of a slab's word-row."""
     if pack == 1:
         return ((s, _f32(word)),)
-    return ((s, _f32(word << _SIXTEEN)),
-            (s + np.int32(sub), _f32(word & _HIGH)))
+    low = (s, _f32(word << _SIXTEEN))
+    return (low, (s + np.int32(sub), _f32(word & _HIGH))) if high else (low,)
 
 
-def _each_word_row(sub, body):
-    """``body(s)`` for every word-row of a slab, as ONE traced loop body: a
-    kernel unrolled over them takes eight times as long to trace and to
-    lower, at every start of a process."""
+def _each_word_row(sub, pack, w, body):
+    """``body(s, high)`` for every word-row ``s`` of a slab that holds a tile
+    of the ``w`` columns: tile ``s`` in the words' low halves (all of a
+    float32 word) and, where ``high``, tile ``s + sub`` in the high halves of
+    two-byte values.  Of 2,560 bfloat16 values (20 tiles, ``sub`` 16)
+    word-rows 0-3 hold two tiles, 4-15 one; a slab that its row fills
+    (``w`` = 2,048 bfloat16) is one range.  ONE traced loop body a range: a
+    kernel unrolled over the word-rows takes eight times as long to trace
+    and to lower, at every start of a process."""
+    tiles = w // _LANES
+    both = max(tiles - sub, 0) if pack == 2 else 0
+    for first, end, high in ((0, both, True), (both, min(sub, tiles), False)):
+        if first < end:
+            _count(first, end, functools.partial(body, high=high))
+
+
+def _count(first, end, body):
+    """``body(s)`` for ``s`` in the static range [first, end)."""
     def step(s):
         body(s)
         return s + np.int32(1)
 
     # a while loop: under x64 a fori_loop with static bounds counts in int64
-    jax.lax.while_loop(lambda s: s < np.int32(sub), step, _I0)
+    jax.lax.while_loop(lambda s: s < np.int32(end), step, np.int32(first))
 
 
 def _gather(idx_ref, first, count, slab_ref, buf, sem, sub):
@@ -172,17 +201,19 @@ def _n(n):
 def _slab_kernel(n_ref, x_ref, o_ref, *, rows, sub, pack):
     @pl.when(pl.program_id(0) * np.int32(rows) < n_ref[0])
     def _():
-        def one(s):
-            o_ref[pl.ds(s, rows, stride=sub), :] = _word(x_ref, s, sub, pack)
+        def one(s, high):
+            o_ref[pl.ds(s, rows, stride=sub), :] = _word(x_ref, s, sub, pack,
+                                                         high)
 
-        _each_word_row(sub, one)
+        _each_word_row(sub, pack, x_ref.shape[1], one)
 
 
 def to_slabs(x, n):
     """The first ``n`` rows of ``x`` (rows, d) as slabs: (rows' * sub, 128)
     uint32, row ``r``'s slab at word-rows ``[r * sub, (r + 1) * sub)``.  The
-    slabs of rows from the end of ``n``'s block on are not written."""
-    pack, dp, sub = _geometry(x.shape[1], x.dtype)
+    slabs of rows from the end of ``n``'s block on are not written, nor a
+    slab's words past the row's own width."""
+    pack, w, sub = _geometry(x.shape[1], x.dtype)
     rows = _block(x.shape[0], _ROWS)
     rp = _up(x.shape[0], rows)
 
@@ -193,12 +224,12 @@ def to_slabs(x, n):
         functools.partial(_slab_kernel, rows=rows, sub=sub, pack=pack),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(rp // rows,),
-            in_specs=[pl.BlockSpec((rows, dp), block)],
+            in_specs=[pl.BlockSpec((rows, w), block)],
             out_specs=pl.BlockSpec((rows * sub, _LANES), block)),
         out_shape=jax.ShapeDtypeStruct((rp * sub, _LANES), jnp.uint32),
         interpret=_backend.pallas_interpret(),
         name="moe_slabs",
-    )(_n(n), _pad(x.astype(_carrier(x.dtype)), rp, dp))
+    )(_n(n), _pad(x.astype(_carrier(x.dtype)), rp, w))
 
 
 # ---------------------------------------------------------------------------
@@ -219,15 +250,15 @@ def _rows_kernel(n_ref, tok_ref, slab_ref, scale_ref, ys_ref, o_ref, dot_ref,
         live = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) < count
         dots[...] = jnp.zeros(dots.shape, jnp.float32)
 
-        def one(s):
+        def one(s, high):
             word = buf[pl.ds(s, rows, stride=sub), :]
-            for tile, v in _values(word, s, sub, pack):
+            for tile, v in _values(word, s, sub, pack, high):
                 v = jnp.where(live, v, jnp.float32(0))
                 dots[...] += v * ys_ref[:, _lanes(tile)].astype(jnp.float32)
                 o_ref[:, _lanes(tile)] = (v * scale_ref[...]).astype(
                     o_ref.dtype)
 
-        _each_word_row(sub, one)
+        _each_word_row(sub, pack, o_ref.shape[1], one)
         dot_ref[...] = jnp.sum(dots[...], 1, keepdims=True)
 
 
@@ -237,7 +268,7 @@ def rows_from_tokens(src, tok, n, scale, ys):
     in ``ys``'s dtype, scaled in float32, and the sums (R,) float32.  Zeros
     from row ``n`` to the end of its block; later blocks are not written."""
     r, width = ys.shape
-    pack, dp, sub = _geometry(width, ys.dtype)
+    pack, w, sub = _geometry(width, ys.dtype)
     rows = _block(r, _ROWS)
     rp = _up(r, _INDICES if rows == _ROWS else rows)
     per = min(rp, _INDICES) // rows
@@ -248,7 +279,7 @@ def rows_from_tokens(src, tok, n, scale, ys):
     def line(i, n_ref):
         return (jax.lax.div(block(i, n_ref)[0], np.int32(per)),)
 
-    wide = pl.BlockSpec((rows, dp), block)
+    wide = pl.BlockSpec((rows, w), block)
     thin = pl.BlockSpec((rows, 1), block)
     carrier = _carrier(ys.dtype)
     out, dots = pl.pallas_call(
@@ -263,14 +294,14 @@ def rows_from_tokens(src, tok, n, scale, ys):
             scratch_shapes=[pltpu.VMEM((rows * sub, _LANES), jnp.uint32),
                             pltpu.VMEM((rows, _LANES), jnp.float32),
                             pltpu.SemaphoreType.DMA(())]),
-        out_shape=[jax.ShapeDtypeStruct((rp, dp), carrier),
+        out_shape=[jax.ShapeDtypeStruct((rp, w), carrier),
                    jax.ShapeDtypeStruct((rp, 1), jnp.float32)],
         interpret=_backend.pallas_interpret(),
         name="moe_rows",
     )(_n(n), jnp.pad(tok.astype(jnp.int32), (0, rp - r)),
       to_slabs(src.astype(ys.dtype), src.shape[0]),
       _pad(scale.astype(jnp.float32).reshape(r, 1), rp, 1),
-      _pad(ys.astype(carrier), rp, dp))
+      _pad(ys.astype(carrier), rp, w))
     return out[:r, :width].astype(ys.dtype), dots[:r, 0]
 
 
@@ -348,14 +379,14 @@ def _tokens_kernel(cnt_ref, row_ref, tokl_ref, *refs, tokens, sub, pack,
         hit = (token == tokl_ref[0, pl.ds(c, 1), :]) & (lane < m)
         w = w_ref[0, pl.ds(c, 1), :] if weighted else jnp.float32(1)
         parts = _parts(jnp.where(hit, w, jnp.float32(0)), pack, weighted)
-        def one(s):
+        def one(s, high):
             word = buf[pl.ds(s, _CHUNK, stride=sub), :]
-            for tile, v in _values(word, s, sub, pack):
+            for tile, v in _values(word, s, sub, pack, high):
                 # a stale slot may hold NaN, and 0 * NaN is NaN
                 v = jnp.where(slot < m, v, jnp.float32(0))
                 acc[:, _lanes(tile)] += _product(parts, v, pack)
 
-        _each_word_row(sub, one)
+        _each_word_row(sub, pack, acc.shape[1], one)
         return carry
 
     chunks = jax.lax.div(count + np.int32(_CHUNK - 1), np.int32(_CHUNK))
@@ -370,9 +401,9 @@ def tokens_from_rows(src, row, n, weights=None):
     rows below ``n`` are read.  Weighted and summed in float32, rounded
     once."""
     width, dtype = src.shape[1], src.dtype
-    pack, dp, sub = _geometry(width, dtype)
+    pack, w, sub = _geometry(width, dtype)
     t, k = row.shape
-    (tokl, rows, *w), counts = _token_lists(row, n, weights)
+    (tokl, rows, *ws), counts = _token_lists(row, n, weights)
     blocks, length = rows.shape
     tokens = length // k
     lp = _up(length, max(_CHUNK, _INDICES))
@@ -383,20 +414,20 @@ def tokens_from_rows(src, row, n, weights=None):
 
     meta = pl.BlockSpec((1,) + shape[1:], lambda i, c: (i, _I0, _I0))
     operands = [counts, listed(rows).reshape(-1)]
-    operands += [listed(a).reshape(shape) for a in [tokl] + w]
+    operands += [listed(a).reshape(shape) for a in [tokl] + ws]
     in_specs = [pl.BlockSpec((lp,), lambda i, c: (i,),
-                             memory_space=pltpu.SMEM)] + [meta] * (1 + len(w))
+                             memory_space=pltpu.SMEM)] + [meta] * (1 + len(ws))
     out = pl.pallas_call(
         functools.partial(_tokens_kernel, tokens=tokens, sub=sub, pack=pack,
-                          weighted=bool(w)),
+                          weighted=bool(ws)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(blocks,),
             in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((tokens, dp), lambda i, c: (i, _I0)),
+            out_specs=pl.BlockSpec((tokens, w), lambda i, c: (i, _I0)),
             scratch_shapes=[pltpu.VMEM((_CHUNK * sub, _LANES), jnp.uint32),
-                            pltpu.VMEM((tokens, dp), jnp.float32),
+                            pltpu.VMEM((tokens, w), jnp.float32),
                             pltpu.SemaphoreType.DMA(())]),
-        out_shape=jax.ShapeDtypeStruct((blocks * tokens, dp),
+        out_shape=jax.ShapeDtypeStruct((blocks * tokens, w),
                                        _carrier(dtype)),
         interpret=_backend.pallas_interpret(),
         name="moe_tokens",

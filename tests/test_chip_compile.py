@@ -271,8 +271,11 @@ def test_dispatch_and_combine_are_row_movers_that_stop_at_n(
     (``moe_slabs``, ``moe_rows``, ``moe_tokens``: a row travels as a slab of
     32-bit words, ``parallel/moe_rows.py``), the one gather left is the rows
     into expert order, no float32 array of the row buffer's size is left,
-    and the dynamic extent stays inside the kernels: no ``while`` or
-    ``conditional`` in the program."""
+    the dynamic extent stays inside the kernels (no ``while`` or
+    ``conditional`` in the program), and only the SLAB is wider than its
+    row: no array of rows or of tokens is padded to the slab's width for a
+    kernel or cut back after it, so at 2,560 the program holds no array of
+    4,096 columns and no ``pad`` of an array of rows or of tokens."""
     from incubator_mxnet_tpu.parallel import moe
 
     rows_ = tokens * top_k
@@ -299,6 +302,11 @@ def test_dispatch_and_combine_are_row_movers_that_stop_at_n(
     assert not re.search(r" (while|conditional)\(", text)
     assert not re.search(r"f32\[(%d,%d|%d,%d,%d)\]"
                          % (rows_, d, tokens, top_k, d), text)
+    slab = -(-d // 2048) * 2048
+    if slab != d:
+        assert not re.search(r"\[(%d|%d),%d\]" % (rows_, tokens, slab), text)
+        assert not re.search(r"\[(%d|%d),\d+\]\S* pad\(" % (rows_, tokens),
+                             text)
 
 
 def test_stem_maxpool_has_no_pallas_form(one_chip, mosaic):

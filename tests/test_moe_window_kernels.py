@@ -474,11 +474,11 @@ _MOVERS = {name: tuple(map(jax.jit, pair)) for name, pair in _MOVERS.items()}
 
 
 @functools.lru_cache(maxsize=None)
-def _buffers(dtype):
+def _buffers(dtype, d=_D):
     rng = np.random.RandomState(6)
     order = jnp.asarray(rng.permutation(_T * _K), jnp.int32)
-    return (jnp.asarray(rng.normal(size=(_T, _D)), dtype),
-            jnp.asarray(rng.normal(size=(_T * _K, _D)), dtype),
+    return (jnp.asarray(rng.normal(size=(_T, d)), dtype),
+            jnp.asarray(rng.normal(size=(_T * _K, d)), dtype),
             jnp.asarray(rng.uniform(size=(_T, _K)), jnp.float32),
             jnp.argsort(order).astype(jnp.int32).reshape(_T, _K), order)
 
@@ -492,15 +492,32 @@ def test_a_row_mover_is_the_form_it_replaced(name, n):
     bfloat16 rows come out bit for bit; a bfloat16 token is a float32 sum
     rounded once, and may fall to the next value with the order of the
     sum."""
+    _a_mover_against_its_oracle(name, n, _D)
+
+
+@pytest.mark.parametrize("n", [0, 257, _T * _K])
+@pytest.mark.parametrize("d", [256, 1536, 2560])
+@pytest.mark.parametrize("name", sorted(_MOVERS))
+def test_a_row_mover_at_a_width_of_whole_lanes_that_fills_no_slab(name, d, n):
+    """The same at widths the kernels take as they are (whole tiles of 128
+    lanes) and a slab is wider than: 256 (bfloat16: two of a slab's eight
+    word-rows hold a tile, in their low halves), 1,536 and 2,560 (bfloat16:
+    four word-rows hold two tiles, the rest one; float32: 12 of 16 and 20
+    of 24 word-rows hold one)."""
+    _a_mover_against_its_oracle(name, n, d)
+
+
+def _a_mover_against_its_oracle(name, n, d):
     mover, oracle = _MOVERS[name]
     ulp = 2.0 ** -7 if name.startswith("tokens") else 0
     for dtype, tol in ((jnp.float32, 1e-6), (jnp.bfloat16, ulp)):
-        args = _buffers(dtype) + (jnp.int32(n),)
+        args = _buffers(dtype, d) + (jnp.int32(n),)
         got, want = mover(*args), oracle(*args)
         if name == "rows_scaled":
             held = np.asarray(args[3]) < n
+            # a float32 sum of d products, in another order
             np.testing.assert_allclose(np.where(held, got[1], 0), want[1],
-                                       rtol=1e-5, atol=1e-6)
+                                       rtol=1e-5, atol=1e-6 * d / _D)
             assert np.all(np.asarray(got[1])[~held] == 0)
             got, want = got[0], want[0]
         if name.startswith("rows"):
@@ -514,15 +531,49 @@ def test_a_row_mover_is_the_form_it_replaced(name, n):
                                    atol=tol)
 
 
-def test_slabs_past_the_last_held_row_are_not_written():
+def _slab_values(slabs, rows, dtype):
+    """What the slabs hold, (rows, values a slab has room for) as bits: a
+    float32 word is a value, a two-byte value lies in the low half of word
+    ``c`` or the high half of word ``c - words / 2``."""
+    words = slabs.reshape(rows, -1)
+    if dtype == jnp.float32:
+        return words
+    return np.concatenate([words & 0xFFFF, words >> 16], 1).astype(np.uint16)
+
+
+@pytest.mark.parametrize("dtype,d,sub", [
+    (jnp.float32, _D, 8), (jnp.float32, 2560, 24), (jnp.bfloat16, 2560, 16)],
+    ids=["float32_16", "float32_2560", "bfloat16_2560"])
+def test_slabs_past_the_last_held_row_are_not_written(dtype, d, sub):
     """The grid covers the whole buffer and its steps past ``n`` do nothing:
     their slabs are what the buffer held (the interpreter's fill for words
-    never written, 0), the live block's are the rows."""
-    x = _buffers(jnp.float32)[1]
+    never written, 0), the live block's are the rows.  A row of 2,560
+    values lies in a slab of 4,096 (3,072 float32): what a slab has past
+    its row is zero bits or not written, which the interpreter makes zero
+    bits too."""
+    x = _buffers(dtype, d)[1]
     slabs = np.asarray(jax.jit(moe_rows.to_slabs)(x, jnp.int32(3)))
-    words = slabs.reshape(_T * _K, -1)[:, :_D].view(np.float32)
-    np.testing.assert_array_equal(words[:256], np.asarray(x[:256]))
-    assert np.asarray(x[256:]).all() and (words[256:] == 0).all()
+    assert slabs.shape == (_T * _K * sub, 128)
+    got = _slab_values(slabs, _T * _K, dtype)
+    want = np.asarray(x).view(got.dtype)
+    np.testing.assert_array_equal(got[:256, :d], want[:256])
+    assert want[256:].any(1).all() and not got[256:].any()
+    assert not got[:, d:].any()
+
+
+@pytest.mark.parametrize("dtype,d,loops", [
+    (jnp.bfloat16, 2048, 1), (jnp.bfloat16, 4096, 1), (jnp.float32, 1024, 1),
+    (jnp.bfloat16, 256, 1), (jnp.bfloat16, 1536, 2), (jnp.bfloat16, 2560, 2),
+    (jnp.float32, 2560, 1)])
+def test_a_slab_its_row_fills_is_walked_by_one_loop(dtype, d, loops):
+    """The word-rows of a slab are walked by one traced loop a RANGE (two
+    tiles a word-row, one, none): a width that fills its slab has one
+    range, and so has every float32 width; 1,536 and 2,560 bfloat16 values
+    have two.  Nothing is unrolled."""
+    text = str(jax.make_jaxpr(moe_rows.to_slabs)(
+        jax.ShapeDtypeStruct((_T * _K, d), dtype), jnp.int32(3)))
+    assert text.count("while[") == loops
+    assert "pad" not in text
 
 
 @jax.custom_vjp

@@ -454,7 +454,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer_at_6_of_64():
 # the row movers at 6 choices and rows of 2,560
 # ---------------------------------------------------------------------------
 
-_T, _K, _D = 64, 6, 2560    # 384 rows of 2560: a bf16 slab padded to 4096
+_T, _K, _D = 64, 6, 2560    # 384 rows of 2560: a bf16 slab has room for 4096
 
 
 def _pick(ys, row, held):
@@ -495,11 +495,12 @@ def test_a_row_mover_at_6_choices_and_rows_of_2560_is_the_form_it_replaced(
         name, n):
     """Rows of 2,560 values are no multiple of the 2,048 bfloat16 values
     (1,024 float32) that fill a slab of whole (8, 128) tiles of words: a
-    row travels as 4,096 (3,072), and the padding is the kernels' own."""
+    row travels in a slab of 16 word-rows (24), room for 4,096 (3,072),
+    and the arrays the kernels take and give stay 2,560 wide."""
     from incubator_mxnet_tpu.parallel import moe_rows
 
-    assert moe_rows._geometry(_D, jnp.bfloat16) == (2, 4096, 16)
-    assert moe_rows._geometry(_D, jnp.float32) == (1, 3072, 24)
+    assert moe_rows._geometry(_D, jnp.bfloat16) == (2, 2560, 16)
+    assert moe_rows._geometry(_D, jnp.float32) == (1, 2560, 24)
     rng = np.random.RandomState(6)
     order = jnp.asarray(rng.permutation(_T * _K), jnp.int32)
     mover, oracle = map(jax.jit, _MOVERS[name])
