@@ -16,9 +16,17 @@ Usage mirrors the reference frontend::
 """
 from __future__ import annotations
 
+import sys as _sys
+import time as _time
+
+_IMPORT_T0 = _time.monotonic()  # the span ``mx.import`` starts here
+_JAX_WAS_IMPORTED = "jax" in _sys.modules
+
 __version__ = "0.1.0"
 
 import jax as _jax
+
+_IMPORT_JAX_T1 = _time.monotonic()
 
 # float64/int64 are first-class dtypes in the reference (mshadow base.h);
 # enable x64 so Cast/astype honor them. All framework defaults remain
@@ -85,6 +93,14 @@ from . import library
 from . import resource
 from . import tensorboard
 from . import torch_bridge
+
+# the set-up timeline (profiler.Setup): jax's own events from here on, and
+# this import as its first span
+profiler._listen_to_jax()
+profiler.setup_span(
+    "mx.import.jax", _IMPORT_T0, _IMPORT_JAX_T1,
+    parent=profiler.setup_span("mx.import", _IMPORT_T0, _time.monotonic(),
+                               jax_was_imported=_JAX_WAS_IMPORTED))
 
 # MXNET_PROFILER_AUTOSTART / MXNET_PROFILER_MODE (env_var.md): begin
 # profiling at import so short scripts get a trace without code changes

@@ -26,7 +26,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from .. import autograd, rng, tracing
+from .. import autograd, profiler, rng, tracing
 from ..ndarray import NDArray
 from ..ndarray import ndarray as _nd_mod
 from .parameter import DeferredInitializationError, Parameter, ParameterDict
@@ -580,7 +580,7 @@ class HybridBlock(Block):
         resolved concrete shapes.  Inference mode: no aux state (BN running
         stats) is touched.
         """
-        from .parameter import shape_only_init
+        from .parameter import _bulk_materialize, shape_only_init
 
         specs = [jax.ShapeDtypeStruct(tuple(s), jnp.dtype(dtype))
                  for s in input_shapes]
@@ -592,12 +592,12 @@ class HybridBlock(Block):
                 out, is_leaf=lambda o: isinstance(o, NDArray))
             return [o._data if isinstance(o, NDArray) else o for o in flat]
 
-        with shape_only_init():
-            jax.eval_shape(probe, *specs)
-        # shapes are now resolved; run all real initializers in one program
-        from .parameter import _bulk_materialize
-
-        _bulk_materialize(list(self.collect_params().values()))
+        with profiler.Setup("mx.block.shape_init"):
+            with shape_only_init():
+                jax.eval_shape(probe, *specs)
+            # shapes are now resolved; run all real initializers in one
+            # program
+            _bulk_materialize(list(self.collect_params().values()))
         return self
 
     def export(self, path, epoch=0):

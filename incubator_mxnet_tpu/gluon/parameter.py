@@ -12,7 +12,7 @@ from typing import Dict, List, Optional
 import jax.numpy as jnp
 import numpy as np
 
-from .. import autograd, tracing
+from .. import autograd, profiler, tracing
 from ..base import np_dtype
 from ..context import Context, cpu, current_context
 from ..ndarray import NDArray
@@ -44,9 +44,12 @@ def shape_only_init():
     global PRNG (initializers run eagerly afterwards)."""
     prev = getattr(_SHAPE_ONLY, "on", False)
     _SHAPE_ONLY.on = True
+    span = profiler.Setup("mx.params.shape_only")
+    span.start()
     try:
         yield
     finally:
+        span.stop()
         _SHAPE_ONLY.on = prev
 
 
@@ -220,38 +223,42 @@ def _bulk_materialize(params) -> None:
                and p._shape_known()]
     if not pending:
         return
-    recipes = []
-    for p in pending:
-        init, ctx, default_init = p._deferred_init
-        init = init or p.init or default_init or _initmod.Uniform()
-        if isinstance(init, str):
-            init = _initmod.registry_create(init)
-        recipes.append((p, init))
-
-    def make():
-        outs = []
-        for p, init in recipes:
-            data = _nd_mod.zeros(p.shape, dtype=np_dtype(p.dtype))
-            init(_initmod.InitDesc(p.name, attrs={}), data)
-            g = (jnp.zeros(p.shape, np_dtype(p.dtype))
-                 if p._grad_req != "null" else None)
-            outs.append((data._data, g))
-        return outs
-
-    try:
-        outs = jax.jit(make)()
-    except Exception:
+    nbytes = sum(int(np.prod(p.shape)) * np.dtype(np_dtype(p.dtype)).itemsize
+                 for p in pending)
+    with profiler.Setup("mx.params.materialize", parameters=len(pending),
+                        bytes=nbytes):
+        recipes = []
         for p in pending:
-            p._finish_deferred_init(p.shape)
-        return
-    for (p, _init), (v, g) in zip(recipes, outs):
-        p._data = NDArray(v)
-        p._deferred_init = None
-        if p._grad_req != "null":
-            p._grad = NDArray(g)
-            autograd.mark_variables([p._data], [p._grad], [p._grad_req])
-        else:
-            p._grad = None
+            init, ctx, default_init = p._deferred_init
+            init = init or p.init or default_init or _initmod.Uniform()
+            if isinstance(init, str):
+                init = _initmod.registry_create(init)
+            recipes.append((p, init))
+
+        def make():
+            outs = []
+            for p, init in recipes:
+                data = _nd_mod.zeros(p.shape, dtype=np_dtype(p.dtype))
+                init(_initmod.InitDesc(p.name, attrs={}), data)
+                g = (jnp.zeros(p.shape, np_dtype(p.dtype))
+                     if p._grad_req != "null" else None)
+                outs.append((data._data, g))
+            return outs
+
+        try:
+            outs = jax.jit(make)()
+        except Exception:
+            for p in pending:
+                p._finish_deferred_init(p.shape)
+            return
+        for (p, _init), (v, g) in zip(recipes, outs):
+            p._data = NDArray(v)
+            p._deferred_init = None
+            if p._grad_req != "null":
+                p._grad = NDArray(g)
+                autograd.mark_variables([p._data], [p._grad], [p._grad_req])
+            else:
+                p._grad = None
 
 
 class Constant(Parameter):

@@ -42,6 +42,8 @@ import os
 import time
 from typing import Any, Dict, Iterable, Optional, Sequence, Tuple
 
+from .. import profiler
+
 __all__ = ["CompileCache", "XLA_COMPILES", "compile_timed",
            "default_compile_cache", "finish_lint", "lint_served_program",
            "resolve_mode", "traced_with_effects"]
@@ -396,7 +398,8 @@ def compile_timed(traced, t_trace: float = 0.0, *,
     hits at zero XLA compiles.
     """
     t0 = time.perf_counter()
-    lowered = traced.lower()
+    with profiler.Setup("mx.step.lower"):
+        lowered = traced.lower()
     t_trace = t_trace + (time.perf_counter() - t0)
     if cache is None:
         cache = default_compile_cache()
@@ -405,13 +408,18 @@ def compile_timed(traced, t_trace: float = 0.0, *,
     if cache is not None:
         key = cache.key_for(lowered, extra=cache_extra)
         times["cache_key"] = key
+        t0 = time.monotonic()
         hit = cache.load(key)
         if hit is not None:
+            profiler.setup_span("mx.step.compile", t0, time.monotonic(),
+                                cache="hit")
             times["cache"] = "hit"
             times["compile"] = 0.0
             return hit, times
     t0 = time.perf_counter()
-    compiled = lowered.compile()
+    with profiler.Setup("mx.step.compile") as span:
+        compiled = lowered.compile()
+        span.args["cache"] = profiler.last_program_cache()
     XLA_COMPILES.bump()
     times["compile"] = time.perf_counter() - t0
     if cache is not None:

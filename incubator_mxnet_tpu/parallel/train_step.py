@@ -9,6 +9,7 @@ collectives via GSPMD sharding propagation.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -17,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import autograd, rng, tracing
+from .. import autograd, profiler, rng, tracing
 from ..ndarray import NDArray
 from ..ops import optimizer_ops as _oops
 from .pipeline import shard_map, spmd_pipeline
@@ -35,6 +36,21 @@ STEP_SCOPES = ("step.forward", "step.cast", "step.guard", "step.unscale",
                "step.update", "step.update.zero", "step.select")
 (_FORWARD, _CAST, _GUARD, _UNSCALE, _UPDATE, _UPDATE_ZERO,
  _SELECT) = STEP_SCOPES
+
+
+#: what ``_ensure_built`` enters once the step is built: no span, no record
+_BUILT = contextlib.nullcontext()
+
+
+def _mesh_axes(mesh):
+    """``dp=4,tp=2`` for a set-up span's arguments; ``-`` without a mesh."""
+    if mesh is None:
+        return "-"
+    return ",".join("%s=%d" % kv for kv in dict(mesh.shape).items())
+
+
+def _nbytes(tree):
+    return sum(int(getattr(v, "nbytes", 0)) for v in jax.tree.leaves(tree))
 
 
 class DynamicLossScale:
@@ -1228,18 +1244,26 @@ class TrainStep:
             # GL004 effects were captured on the base trace the pass
             # pipeline consumed (the rewritten program replays it)
             effects = list(effects) + list(self._pass_effects)
-        if lint_here:
-            self._finish_lint(traced.jaxpr, effects, args)
-        if cost_here:
-            # same trace, one more walk: the cost model's GL201 gate
-            # fires HERE — before lower/compile ever run
-            self._finish_cost(traced.jaxpr, args)
-        if num_here:
-            # same trace, the graftrange walk: GL401-GL405 fire HERE,
-            # before lower/compile — numerics="error" rejects the
-            # program with zero compiles spent
-            self._finish_numerics(traced.jaxpr, args)
         if lint_here or cost_here or num_here:
+            # the walks over the traced jaxpr alone, as a span of the
+            # set-up timeline (opened in line: no frame under the trace)
+            with profiler.Setup("mx.step.lint") as span:
+                findings = 0
+                if lint_here:
+                    findings += len(self._finish_lint(
+                        traced.jaxpr, effects, args).diagnostics)
+                if cost_here:
+                    # same trace, one more walk: the cost model's GL201
+                    # gate fires HERE — before lower/compile ever run
+                    self._finish_cost(traced.jaxpr, args)
+                    findings += len(self.cost_report.diagnostics)
+                if num_here:
+                    # same trace, the graftrange walk: GL401-GL405 fire
+                    # HERE, before lower/compile — numerics="error"
+                    # rejects the program with zero compiles spent
+                    self._finish_numerics(traced.jaxpr, args)
+                    findings += len(self.range_report.diagnostics)
+                span.args["findings"] = findings
             self._linted = True
         return traced
 
@@ -1286,10 +1310,10 @@ class TrainStep:
         extra.extend(check_unsaved_compressor_state(
             self._compression, self.sync,
             where="TrainStep(compression=..., sync='allreduce')"))
-        finish_lint(closed_jaxpr, mode=self.lint, effects=effect_diags,
-                    donated_leaves=donated, extra=extra,
-                    suppress=self.lint_suppress,
-                    what="fused train step", stacklevel=5)
+        return finish_lint(closed_jaxpr, mode=self.lint,
+                           effects=effect_diags, donated_leaves=donated,
+                           extra=extra, suppress=self.lint_suppress,
+                           what="fused train step", stacklevel=5)
 
     # ------------------------------------------------------------------
     # graftcost (analysis/cost_model.py, docs/ANALYSIS.md GL2xx)
@@ -1652,58 +1676,62 @@ class TrainStep:
 
     # ------------------------------------------------------------------
     def _ensure_built(self):
-        if self._gp is None:
-            self._collect()
-            if any(p._data is None for p in self._gp + self._aux):
-                raise RuntimeError("initialize() the net before make_train_step")
-        if self._opt_state is None:
-            pv = [p._data._data for p in self._gp]
-            if self.zero:
-                # state is born PADDED (leading dim a multiple of the dp
-                # axis) so device_put onto the P(dp) shardings slices it
-                # evenly; master weights inherit the zero padding
-                pv = [self._zero_padded(v, pad)
-                      for v, pad in zip(pv, self._zero_pad0)]
-            self._opt_state = self.opt.init(pv)
-        if self._jit is None:
-            self._jit = self._build()
-            from .mesh import spans_processes
+        # the first build is a span of the set-up timeline; every later
+        # call (one a step) finds the step built and records nothing
+        with (profiler.Setup("mx.step.build") if self._jit is None
+              else _BUILT):
+            if self._gp is None:
+                self._collect()
+                if any(p._data is None for p in self._gp + self._aux):
+                    raise RuntimeError("initialize() the net before make_train_step")
+            if self._opt_state is None:
+                pv = [p._data._data for p in self._gp]
+                if self.zero:
+                    # state is born PADDED (leading dim a multiple of the dp
+                    # axis) so device_put onto the P(dp) shardings slices it
+                    # evenly; master weights inherit the zero padding
+                    pv = [self._zero_padded(v, pad)
+                          for v, pad in zip(pv, self._zero_pad0)]
+                self._opt_state = self.opt.init(pv)
+            if self._jit is None:
+                self._jit = self._build()
+                from .mesh import spans_processes
 
-            self._multihost = self.mesh is not None \
-                and spans_processes(self.mesh)
-        if self._key_dev is None or self._key_epoch != rng.epoch():
-            # (re)draw the carried key — also when the user reseeded after
-            # steps already ran (mx.random.seed / rng.set_state must keep
-            # affecting the training stream)
-            self._key_epoch = rng.epoch()
-            self._key_dev = rng.next_key()
-            if self._placed:
-                if self._multihost:
-                    from jax.experimental import multihost_utils as mhu
+                self._multihost = self.mesh is not None \
+                    and spans_processes(self.mesh)
+            if self._key_dev is None or self._key_epoch != rng.epoch():
+                # (re)draw the carried key — also when the user reseeded after
+                # steps already ran (mx.random.seed / rng.set_state must keep
+                # affecting the training stream)
+                self._key_epoch = rng.epoch()
+                self._key_dev = rng.next_key()
+                if self._placed:
+                    if self._multihost:
+                        from jax.experimental import multihost_utils as mhu
 
-                    self._key_dev = mhu.host_local_array_to_global_array(
-                        self._key_dev, self.mesh, self._shardings[4].spec)
-                else:
-                    self._key_dev = jax.device_put(self._key_dev,
-                                                   self._shardings[4])
-        if self._step_dev is None:
-            self._step_dev = jnp.int32(self._step_count)
-        if self._scaler_dev is None:
-            init_scale = self._scale_cfg.init_scale if self._dynamic_scale \
-                else float(self._scale_cfg or 1.0)
-            self._scaler_dev = (jnp.float32(init_scale), jnp.int32(0),
-                                jnp.int32(0))
-        # an async-capable step materializes its service client EAGERLY
-        # so the checkpoint treedef is identical before and after a
-        # policy-ladder degrade (a pre-degrade save must restore into a
-        # post-degrade step and vice versa)
-        if self.sync != "allreduce" and self._svc_client is None \
-                and not self._svc_attaching:
-            self._svc_attaching = True
-            try:
-                self.attach_param_service()
-            finally:
-                self._svc_attaching = False
+                        self._key_dev = mhu.host_local_array_to_global_array(
+                            self._key_dev, self.mesh, self._shardings[4].spec)
+                    else:
+                        self._key_dev = jax.device_put(self._key_dev,
+                                                       self._shardings[4])
+            if self._step_dev is None:
+                self._step_dev = jnp.int32(self._step_count)
+            if self._scaler_dev is None:
+                init_scale = self._scale_cfg.init_scale if self._dynamic_scale \
+                    else float(self._scale_cfg or 1.0)
+                self._scaler_dev = (jnp.float32(init_scale), jnp.int32(0),
+                                    jnp.int32(0))
+            # an async-capable step materializes its service client EAGERLY
+            # so the checkpoint treedef is identical before and after a
+            # policy-ladder degrade (a pre-degrade save must restore into a
+            # post-degrade step and vice versa)
+            if self.sync != "allreduce" and self._svc_client is None \
+                    and not self._svc_attaching:
+                self._svc_attaching = True
+                try:
+                    self.attach_param_service()
+                finally:
+                    self._svc_attaching = False
 
     def _place_state(self, p_vals, aux_vals):
         """One-time placement of params/opt-state on their target shardings
@@ -1712,38 +1740,41 @@ class TrainStep:
         global arrays — dist_sync_device ≡ one GSPMD program over every
         process's devices (SURVEY §5.8)."""
         p_sh, aux_sh, state_sh, _, repl = self._shardings
-        if self._multihost:
-            # every host holds the FULL state value (identical after
-            # seeded init / broadcast); each device fetches its slice of
-            # it through the callback.  NOT host_local_array_to_global:
-            # that treats the local value as this host's SHARD, which
-            # would stack N full copies of a dp-sharded ZeRO-1 state
-            # leaf into an N×-too-tall global array.
-            def _globalize(v, s):
-                host = np.asarray(v)
-                return jax.make_array_from_callback(
-                    host.shape, s, lambda idx: host[idx])
+        with profiler.Setup(
+                "mx.step.place", what="state", mesh=_mesh_axes(self.mesh),
+                bytes=_nbytes((p_vals, aux_vals, self._opt_state))):
+            if self._multihost:
+                # every host holds the FULL state value (identical after
+                # seeded init / broadcast); each device fetches its slice of
+                # it through the callback.  NOT host_local_array_to_global:
+                # that treats the local value as this host's SHARD, which
+                # would stack N full copies of a dp-sharded ZeRO-1 state
+                # leaf into an N×-too-tall global array.
+                def _globalize(v, s):
+                    host = np.asarray(v)
+                    return jax.make_array_from_callback(
+                        host.shape, s, lambda idx: host[idx])
 
-            p_vals = [_globalize(v, s) for v, s in zip(p_vals, p_sh)]
-            aux_vals = [_globalize(v, s) for v, s in zip(aux_vals, aux_sh)]
-            self._opt_state = jax.tree.map(_globalize, self._opt_state,
-                                           state_sh)
-            # carried key/step/scaler must be identical across hosts
-            # (same seed); promote the host-local replicas too
-            self._key_dev = _globalize(self._key_dev, repl)
-            self._step_dev = _globalize(self._step_dev, repl)
-            self._scaler_dev = tuple(_globalize(v, repl)
-                                     for v in self._scaler_dev)
-        else:
-            p_vals = [jax.device_put(v, s) for v, s in zip(p_vals, p_sh)]
-            aux_vals = [jax.device_put(v, s)
-                        for v, s in zip(aux_vals, aux_sh)]
-            self._opt_state = jax.tree.map(
-                jax.device_put, self._opt_state, state_sh)
-            self._key_dev = jax.device_put(self._key_dev, repl)
-            self._step_dev = jax.device_put(self._step_dev, repl)
-            self._scaler_dev = tuple(jax.device_put(v, repl)
-                                     for v in self._scaler_dev)
+                p_vals = [_globalize(v, s) for v, s in zip(p_vals, p_sh)]
+                aux_vals = [_globalize(v, s) for v, s in zip(aux_vals, aux_sh)]
+                self._opt_state = jax.tree.map(_globalize, self._opt_state,
+                                               state_sh)
+                # carried key/step/scaler must be identical across hosts
+                # (same seed); promote the host-local replicas too
+                self._key_dev = _globalize(self._key_dev, repl)
+                self._step_dev = _globalize(self._step_dev, repl)
+                self._scaler_dev = tuple(_globalize(v, repl)
+                                         for v in self._scaler_dev)
+            else:
+                p_vals = [jax.device_put(v, s) for v, s in zip(p_vals, p_sh)]
+                aux_vals = [jax.device_put(v, s)
+                            for v, s in zip(aux_vals, aux_sh)]
+                self._opt_state = jax.tree.map(
+                    jax.device_put, self._opt_state, state_sh)
+                self._key_dev = jax.device_put(self._key_dev, repl)
+                self._step_dev = jax.device_put(self._step_dev, repl)
+                self._scaler_dev = tuple(jax.device_put(v, repl)
+                                         for v in self._scaler_dev)
         self._placed = True
         return p_vals, aux_vals
 
@@ -1823,19 +1854,23 @@ class TrainStep:
                     p._data._data = v
                 for p, v in zip(self._aux, aux_vals):
                     p._data._data = v
-            xv, yv = self._place_batch(xv, yv)
+            with profiler.Setup("mx.step.place", what="batch",
+                                mesh=_mesh_axes(self.mesh),
+                                bytes=_nbytes((xv, yv))):
+                xv, yv = self._place_batch(xv, yv)
         # lint rides THIS trace — no separate lint trace, so the trace/
         # compile split below stays honest (the jaxpr walk is ms-scale)
         from .aot import compile_timed
 
         t0 = _time.perf_counter()
-        self._maybe_apply_passes((p_vals, aux_vals, self._opt_state, xv,
-                                  yv, self._key_dev, self._step_dev,
-                                  self._scaler_dev))
-        traced = self._lint_trace(self._jit,
-                                  (p_vals, aux_vals, self._opt_state, xv,
-                                   yv, self._key_dev, self._step_dev,
-                                   self._scaler_dev))
+        with profiler.Setup("mx.step.trace"):
+            self._maybe_apply_passes((p_vals, aux_vals, self._opt_state, xv,
+                                      yv, self._key_dev, self._step_dev,
+                                      self._scaler_dev))
+            traced = self._lint_trace(self._jit,
+                                      (p_vals, aux_vals, self._opt_state,
+                                       xv, yv, self._key_dev,
+                                       self._step_dev, self._scaler_dev))
         compiled, times = compile_timed(traced, t_trace=_time.perf_counter() - t0,
                                         cache=cache,
                                         cache_extra=self._cache_extra())

@@ -15,8 +15,9 @@ Exit status 1 when any error-severity GL2xx diagnostic fires — with
 autotuner (ROADMAP item 4) uses to reject configs before paying a
 compile.
 
-``--diff profile.json`` diffs the prediction against the measured
-category breakdown ``tools/profile_step.py --out`` writes: a
+``--diff profile.json`` diffs the prediction against a measured
+category breakdown (``{"categories": {<hlo_stats category>:
+{"ms_per_step": ...}}}``, reduced from a device trace): a
 per-category predicted/measured/drift table (the standalone form of
 the autotuner's residual-fit input).  Measured hlo_stats categories
 are folded into the prediction's category space (fusion kinds →
@@ -109,7 +110,7 @@ def _build_model(name, feat=16, layers=4):
     raise SystemExit("unknown --model %r (dense, conv-bn, resnet50)" % name)
 
 
-#: measured hlo_stats category (tools/profile_step.py) -> predicted
+#: measured hlo_stats category (the ``--diff`` file's) -> predicted
 #: CostReport category.  XLA reports fused elementwise/reduction work
 #: as "fusion" kinds, so those fold into elementwise — reduction time
 #: inside a convert_reduce_fusion is indistinguishable in the measured
@@ -248,8 +249,8 @@ def main(argv=None) -> int:
                     choices=["table", "json"])
     ap.add_argument("--diff", default=None, metavar="PROFILE_JSON",
                     help="diff the prediction against a measured "
-                         "category breakdown written by "
-                         "tools/profile_step.py --out; exit 2 when the "
+                         "category breakdown ({\"categories\": {name: "
+                         "{\"ms_per_step\": ...}}}); exit 2 when the "
                          "worst per-category drift exceeds "
                          "--drift-threshold")
     ap.add_argument("--drift-threshold", type=float, default=0.5,
