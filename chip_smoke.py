@@ -3,11 +3,12 @@
 
 One process, one chip, the entry points a user calls: ``vision.resnet50_v1``
 -> ``make_train_step`` -> ``aot_compile`` -> steps, then ``ServeEngine`` +
-``ContinuousBatcher`` under open-loop traffic.  Weights and data come from
-``--seed``.  No number printed here is a result: times are information for
+``ContinuousBatcher`` under open-loop traffic; between them the expert op
+of the decoder families with NaN where its rows end.  Weights and data come
+from ``--seed``.  No number printed here is a result: times are information for
 whoever looks next, the checks are what the run is for.
 
-    python chip_smoke.py              # one chip: device, train, serve
+    python chip_smoke.py              # one chip: device, train, experts, serve
     python chip_smoke.py --multichip  # four chips: dp=4 ZeRO-1 step vs one chip
 
 Contract with the driver: the last line of stdout is
@@ -230,7 +231,74 @@ def train(batch, image_size, steps, platform, passes=None, classes=1000,
 
 
 # ---------------------------------------------------------------------------
-# phase 3: serve
+# phase 3: the expert op past its held rows
+# ---------------------------------------------------------------------------
+
+def experts(rows, hidden, width, held, act, platform, seed=0):
+    """``parallel.moe.moe_experts`` and its backward in bf16 on a buffer of
+    ``rows`` rows of which an eighth (and 37, so that ``n = sum(sizes)``
+    lies inside a block) are in a group.  The op writes nothing past the
+    block of ``n`` (its gate, the gate's transpose and the sum of the two
+    row cotangents stop there) and says that what it returns past ``n`` is
+    not defined; what it must not do is let anything past ``n`` reach what
+    IS read: the grouped products between its passes are the compiler's,
+    the weight gradients among them contract over the rows, and a product
+    that masked by multiplying would carry a NaN across.  So: one program,
+    called on sound arrays and again with ``rows`` and the cotangent NaN
+    from row ``n`` on; ``ys[:n]``, ``d rows[:n]`` and the three weight
+    gradients are finite and bit for bit the same."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from incubator_mxnet_tpu.parallel import moe
+
+    n = rows // 8 + 37
+    sizes = np.random.RandomState(seed % 2 ** 32).multinomial(
+        n, [1.0 / held] * held).astype(np.int32)
+    log("experts: %d x %d rows, %d experts of width %d (%s), n = %d: %s"
+        % (rows, hidden, held, width, act, n, sizes.tolist()))
+    keys = jax.random.split(jax.random.PRNGKey(seed % 2 ** 31), 5)
+    x, g = (jax.random.normal(k, (rows, hidden), jnp.bfloat16)
+            for k in keys[:2])
+    w1, w3 = (jax.random.normal(k, (held, hidden, width), jnp.bfloat16)
+              * hidden ** -0.5 for k in keys[2:4])
+    w2 = jax.random.normal(keys[4], (held, width, hidden),
+                           jnp.bfloat16) * width ** -0.5
+    live = (jnp.arange(rows) < n)[:, None]
+
+    @jax.jit
+    def both(x, g, w1, w3, w2):
+        ys, pull = jax.vjp(
+            lambda *o: moe.moe_experts(*o, jnp.asarray(sizes), act=act),
+            x, w1, w3, w2)
+        d_rows, d_w1, d_w3, d_w2 = pull(g)
+        return ys[:n], d_rows[:n], d_w1, d_w3, d_w2
+
+    names = ("ys[:n]", "d rows[:n]", "d w1", "d w3", "d w2")
+    t = time.time()
+    sound = both(x, g, w1, w3, w2)
+    _check_placed(list(sound), platform, "experts: results")
+    sound = [np.asarray(a.astype(jnp.float32)) for a in sound]
+    poisoned = [np.asarray(a.astype(jnp.float32)) for a in both(
+        *(jnp.where(live, a, jnp.nan) for a in (x, g)), w1, w3, w2)]
+    log("experts: both calls %.1fs; largest |value| %s"
+        % (time.time() - t, " ".join("%s %.3g" % (k, abs(a).max())
+                                     for k, a in zip(names, sound))))
+    for name, a, b in zip(names, sound, poisoned):
+        check(np.isfinite(a).all() and a.any(),
+              "experts: %s of the sound call is not finite, or is zero" % name)
+        check(np.isfinite(b).all(),
+              "experts: NaN past n reached %s: %d values not finite"
+              % (name, (~np.isfinite(b)).sum()))
+        check(np.array_equal(a, b),
+              "experts: %s differs with NaN past n: %d values, by %.3g at "
+              "most" % (name, (a != b).sum(), abs(a - b).max()))
+    return n
+
+
+# ---------------------------------------------------------------------------
+# phase 4: serve
 # ---------------------------------------------------------------------------
 
 class _KeepFutures:
@@ -416,6 +484,9 @@ def main(argv=None):
     else:
         train(batch=256, image_size=224, steps=8, platform="tpu",
               seed=args.seed)
+        # the shapes of smallthinker_21b_train_16k and trinity_mini_train_8k
+        experts(98304, 2560, 768, 8, "relu", platform="tpu", seed=args.seed)
+        experts(65536, 2048, 1024, 16, "silu", platform="tpu", seed=args.seed)
         serve(buckets=(16, 64), image_size=224, n_requests=64, qps=100.0,
               n_check=8, seed=args.seed)
     log("all phases passed")

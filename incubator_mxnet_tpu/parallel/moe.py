@@ -26,15 +26,21 @@ of tokens) and counts the assignments per expert;
 ``_contrib_moe_dispatch`` sorts the assignments that fall on the experts
 held here by expert and gathers their tokens' rows into that order;
 ``_contrib_moe_experts`` is ONE grouped product per matrix over those rows
-(``lax.ragged_dot``: an expert's matrix meets only its own rows);
+(``lax.ragged_dot``: an expert's matrix meets only its own rows) with the
+gate between them;
 ``_contrib_moe_combine`` sums each token's weighted results.  What absent experts would add is left out.
 The row buffer holds every assignment there can be (tokens x top-k), so no
 token is ever dropped, whatever the router does, and what is done with it
 stops at ``n = sum(sizes)``, the rows that are in a group, a number only the
-step itself knows: the grouped product's work follows the groups, and three
-of the four passes that move rows (back to tokens, and both transposes) are
-the Pallas kernels of ``moe_rows.py``, whose grid covers the whole buffer
-and whose steps past ``n`` do nothing.  They take the rows and the tokens
+step itself knows: the grouped product's work follows the groups, and
+everything else but one pass is a Pallas kernel of ``moe_rows.py`` whose
+grid covers the whole buffer and whose steps past ``n`` do nothing: three
+of the four passes that move rows (back to tokens, and both transposes),
+and the expert op's elementwise passes between its products (the gate, in
+the backward pass its transpose and the sum of the two cotangents of the
+rows, which feed two products: JAX's own ``add_any`` cannot be given an
+extent, so the op's backward is written out, ``_experts_bwd``).  The movers
+take the rows and the tokens
 at the model's own width; a row is padded to whole (8, 128) tiles of words
 only where a DMA moves it (a slab: 2,560 bf16 values in 8 KiB), inside the
 kernels, so nothing of the buffer's size is padded or cut around them.  A
@@ -42,13 +48,15 @@ kernel is one op in the device trace whatever its grid does, which a
 ``lax.cond`` between a small buffer and the full one is not (tried on the
 chip, PR 30: 6 % of a step faster, and the ``conditional`` stands in the
 trace as one op OVER its own ops, so that no sum of ops is the step's
-time).  The fourth pass, the rows into
+time).  The one pass left, the rows into
 expert order, stays XLA's gather over every row: the buffer it fills is
 what a caller may take a statistic of (a float8 scale a tensor), so all of
-it is written.  Past ``n`` the product's result and both cotangents hold
-whatever the memory held before, NaN included; nothing reads them
-(``tests/test_moe_window_kernels.py`` sets them to NaN).  Scalars are moved
-between the assignments' order and the rows' by sorts that carry them:
+it is written.  Past ``n`` the gate's and the product's results and every
+cotangent of the op hold whatever the memory held before, NaN included;
+nothing reads them
+(``tests/test_moe_window_kernels.py`` sets them to NaN, and under the
+interpreter ``tests/test_moe_held_rows.py`` finds them NaN).  Scalars are
+moved between the assignments' order and the rows' by sorts that carry them:
 XLA's gather of tokens x top-k scalars takes ten times a sort's time on a
 TPU.
 """
@@ -305,25 +313,94 @@ def moe_dispatch(x, sel, experts_held=(0, 1)):
     return _gather_rows(x, order, row, jnp.sum(sizes)), sizes, row, order
 
 
-#: the gate's activation of a gated feed-forward, by the name a model gives it
-_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+def _gate(act):
+    """``(act, forward(a, b, n), backward(a, b, g, n))``: an activation
+    with ``h = act(a) * b`` and its transpose as passes over the rows below
+    ``n``."""
+    def gate(a, b):
+        return act(a) * b
+
+    @_lowered_once
+    def _gate_rows(a, b, n):
+        h, = moe_rows.on_held_rows(gate, 1, n, a, b, name="moe_gate")
+        return h
+
+    @_lowered_once
+    def _gate_rows_bwd(a, b, g, n):
+        # (g * b * act'(a), g * act(a)) in ONE pass, act' by JAX's own rule
+        return moe_rows.on_held_rows(
+            lambda a, b, g: jax.vjp(gate, a, b)[1](g), 2, n, a, b, g,
+            name="moe_gate_bwd")
+
+    return act, _gate_rows, _gate_rows_bwd
+
+
+#: the gate of a gated feed-forward by the name a model gives its
+#: activation: (the activation, the gate over the held rows, its transpose)
+_GATES = {"silu": _gate(jax.nn.silu), "relu": _gate(jax.nn.relu)}
+
+
+@_lowered_once
+def _sum_rows(x, y, n):
+    total, = moe_rows.on_held_rows(jnp.add, 1, n, x, y, name="moe_row_sum")
+    return total
+
+
+def _grouped(sizes, dtype):
+    # for operands narrower than float32 the precision is pinned: the TPU's
+    # grouped-product kernel refuses them under a process-wide
+    # jax_default_matmul_precision of "highest"
+    return functools.partial(
+        jax.lax.ragged_dot, group_sizes=sizes,
+        precision=None if dtype == jnp.float32
+        else jax.lax.Precision.DEFAULT)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _experts(rows, w1, w3, w2, sizes, act):
+    return _experts_fwd(rows, w1, w3, w2, sizes, act)[0]
+
+
+def _experts_fwd(rows, w1, w3, w2, sizes, act):
+    dot = _grouped(sizes, rows.dtype)
+    a, b = dot(rows, w1), dot(rows, w3)
+    _, gate, _ = _GATES[act]
+    h = gate(a, b, jnp.sum(sizes))
+    return dot(h, w2), (rows, a, b, h, w1, w3, w2, sizes)
+
+
+def _experts_bwd(act, res, dys):
+    # the backward is ours because JAX's own sums the two cotangents of
+    # ``rows`` (it feeds two products) by an ``add_any`` over the whole
+    # buffer, which cannot be given an extent; every product below is the
+    # transpose JAX makes of ``lax.ragged_dot``
+    rows, a, b, h, w1, w3, w2, sizes = res
+    n = jnp.sum(sizes)
+    dot = _grouped(sizes, rows.dtype)
+    dh, dw2 = jax.vjp(dot, h, w2)[1](dys)
+    _, _, gate_bwd = _GATES[act]
+    da, db = gate_bwd(a, b, dh, n)
+    from_a, dw1 = jax.vjp(dot, rows, w1)[1](da)
+    from_b, dw3 = jax.vjp(dot, rows, w3)[1](db)
+    return _sum_rows(from_a, from_b, n), dw1, dw3, dw2, None
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
 
 
 def moe_experts(rows, w1, w3, w2, sizes, act="silu"):
     """Gated feed-forward of each expert over its own rows, ``(act(x W1) *
     (x W3)) W2`` with ``act`` the gate's activation (``"silu"`` or
     ``"relu"``), as grouped products: rows (R, d) sorted by expert,
-    ``sizes`` (G,) rows each; w1, w3: (G, d, f); w2: (G, f, d).  What comes
-    out for the rows past ``sum(sizes)`` is not defined."""
-    # for operands narrower than float32 the precision is pinned: the TPU's
-    # grouped-product kernel refuses them under a process-wide
-    # jax_default_matmul_precision of "highest"
-    dot = functools.partial(
-        jax.lax.ragged_dot, group_sizes=sizes,
-        precision=None if rows.dtype == jnp.float32
-        else jax.lax.Precision.DEFAULT)
-    h = _GATES[act](dot(rows, w1)) * dot(rows, w3)
-    return dot(h, w2)
+    ``sizes`` (G,) rows each; w1, w3: (G, d, f); w2: (G, f, d).  Between
+    the products the gate, and in the backward pass its transpose and the
+    sum of the two cotangents of ``rows``, are passes over the rows below
+    ``n = sum(sizes)`` (``moe_rows.on_held_rows``; float32 inside, one
+    rounding).  All of ``rows`` is read by nobody but the products, which
+    follow the groups; what comes out for the rows past ``n``, here and in
+    the cotangent of ``rows``, is not defined: past the block of ``n`` it
+    is not written."""
+    return _experts(rows, w1, w3, w2, sizes, act)
 
 
 @_lowered_once

@@ -30,6 +30,11 @@ width for a kernel or cuts the result back.
   one stable sort) gathered densely, and summed into their tokens by a
   product with a (token x row) matrix of the weights on the matrix unit,
   float32 throughout.
+* :func:`on_held_rows` is an elementwise ``body`` over the rows below ``n``
+  of one to three ``(rows, w)`` arrays, a dense block of rows a grid step,
+  float32 inside and rounded once at the store (the expert op's gate, its
+  backward, and the sum of its two row cotangents).  The block that holds
+  ``n`` is worked whole; the blocks after it are not written.
 
 On the CPU the kernels run through the Pallas interpreter, which fills what
 a kernel leaves unwritten with NaN.  The package runs with x64 on: every
@@ -38,6 +43,7 @@ index in here is an explicit int32.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import _backend
 
-__all__ = ["to_slabs", "rows_from_tokens", "tokens_from_rows"]
+__all__ = ["to_slabs", "rows_from_tokens", "tokens_from_rows", "on_held_rows"]
 
 _LANES = 128
 _SUB = 8
@@ -57,6 +63,19 @@ _ROWS = 256
 #: at a time
 _TOKENS = 128
 _CHUNK = 128
+#: an elementwise pass: the most rows a grid step covers (a step costs a
+#: third of a microsecond whether it has rows or not), the rows its body is
+#: given at a time, and the VMEM its blocks may take.  A kernel gets 16 MiB
+#: unasked and every block stands there twice (the pipeline's two buffers);
+#: what is left is the body's: the gate's backward keeps up to a dozen
+#: float32 values an element alive, 12 x 32 x 2,560 x 4 B = 3.75 MiB at the
+#: widest row a cell has, so 16 - 4 = 12.  (Blocks of 15 MiB, which 512
+#: rows of five arrays of 1,536 or three of 2,560 bf16 values are, compile
+#: for a described v5e too; 256 rows there cost 64 to 192 more grid steps
+#: a pass, 0.07 ms, and leave the margin.)
+_HELD_ROWS = 512
+_HELD_FEW = 32
+_HELD_VMEM = 12 * 2 ** 20
 #: XLA tiles a one-dimensional int32 array by 1024: the indices reach a
 #: kernel's scalar memory in blocks of a multiple of that
 _INDICES = 1024
@@ -185,6 +204,15 @@ def _last_live(n_ref, rows):
                        _I0)
 
 
+def _live_block(rows):
+    """The index map of a (rows, w) block of a grid over the buffer whose
+    steps past ``n`` name the last live block again."""
+    def block(i, n_ref):
+        return jnp.minimum(i, _last_live(n_ref, rows)), _I0
+
+    return block
+
+
 def _pad(x, rows, cols):
     extra = ((0, rows - x.shape[0]), (0, cols - x.shape[1]))
     return jnp.pad(x, extra) if any(e for _, e in extra) else x
@@ -216,10 +244,7 @@ def to_slabs(x, n):
     pack, w, sub = _geometry(x.shape[1], x.dtype)
     rows = _block(x.shape[0], _ROWS)
     rp = _up(x.shape[0], rows)
-
-    def block(i, n_ref):
-        return jnp.minimum(i, _last_live(n_ref, rows)), _I0
-
+    block = _live_block(rows)
     return pl.pallas_call(
         functools.partial(_slab_kernel, rows=rows, sub=sub, pack=pack),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -272,9 +297,7 @@ def rows_from_tokens(src, tok, n, scale, ys):
     rows = _block(r, _ROWS)
     rp = _up(r, _INDICES if rows == _ROWS else rows)
     per = min(rp, _INDICES) // rows
-
-    def block(i, n_ref):
-        return jnp.minimum(i, _last_live(n_ref, rows)), _I0
+    block = _live_block(rows)
 
     def line(i, n_ref):
         return (jax.lax.div(block(i, n_ref)[0], np.int32(per)),)
@@ -433,3 +456,67 @@ def tokens_from_rows(src, row, n, weights=None):
         name="moe_tokens",
     )(*operands, to_slabs(src, n))
     return out[:t, :width].astype(dtype)
+
+
+# ---------------------------------------------------------------------------
+# an elementwise pass over the rows below n
+# ---------------------------------------------------------------------------
+
+def _held_kernel(n_ref, *refs, rows, ins, body):
+    @pl.when(pl.program_id(0) * np.int32(rows) < n_ref[0])
+    def _():
+        # a few rows at a time: what ``body`` makes on its way is then a few
+        # registers' worth and not a second block in VMEM
+        few = math.gcd(rows, _HELD_FEW)
+
+        def some(i):
+            at = pl.ds(pl.multiple_of(i * np.int32(few), few), few)
+            results = jax.tree.leaves(body(*(
+                x_ref[at, :].astype(jnp.float32) for x_ref in refs[:ins])))
+            for o_ref, v in zip(refs[ins:], results):
+                o_ref[at, :] = v.astype(o_ref.dtype)
+
+        _count(0, rows // few, some)
+
+
+def _held_block(r, w, arrays, itemsize):
+    """Rows a grid step covers of ``arrays`` operands and results of ``r``
+    rows of ``w`` values: a power of two, so that it divides a buffer, and
+    no fewer than a tile of the narrowest dtype holds."""
+    fit = _HELD_VMEM // (2 * arrays * w * itemsize)
+    if fit < _HELD_FEW:
+        raise ValueError(
+            "on_held_rows: %d arrays of width %d leave a block %d rows of "
+            "%d MiB, and the pass works %d at a time"
+            % (arrays, w, fit, _HELD_VMEM >> 20, _HELD_FEW))
+    return _block(r, min(_HELD_ROWS, 1 << fit.bit_length() - 1))
+
+
+def on_held_rows(body, outs, n, *arrays, name):
+    """``body(*arrays)``, elementwise, for the rows below ``n``: ``(rows,
+    d)`` arrays of one shape and dtype in, a tuple of ``outs`` ``(rows, d)``
+    arrays of that dtype out (``body`` returns one array or a tuple).
+    ``body``
+    sees float32 rows and its results are rounded once, at the store.  The
+    rows from ``n`` to the end of ``n``'s block get what ``body`` makes of
+    what the arrays hold there; the blocks after it are not written.  A
+    grid step covers as many rows as keep a block of every operand and
+    result, twice over, inside ``_HELD_VMEM``, ``_HELD_ROWS`` at most."""
+    r, d = arrays[0].shape
+    dtype = arrays[0].dtype
+    carrier = _carrier(dtype)
+    w = _up(d, _LANES)
+    rows = _held_block(r, w, len(arrays) + outs, carrier.itemsize)
+    rp = _up(r, rows)
+    spec = pl.BlockSpec((rows, w), _live_block(rows))
+    results = pl.pallas_call(
+        functools.partial(_held_kernel, rows=rows, ins=len(arrays),
+                          body=body),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rp // rows,),
+            in_specs=[spec] * len(arrays), out_specs=[spec] * outs),
+        out_shape=[jax.ShapeDtypeStruct((rp, w), carrier)] * outs,
+        interpret=_backend.pallas_interpret(),
+        name=name,
+    )(_n(n), *(_pad(x.astype(carrier), rp, w) for x in arrays))
+    return tuple(o[:r, :d].astype(dtype) for o in results)

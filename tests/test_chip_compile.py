@@ -309,6 +309,43 @@ def test_dispatch_and_combine_are_row_movers_that_stop_at_n(
                              text)
 
 
+@pytest.mark.parametrize("rows_,d,f,held,act", [
+    (98304, 2560, 768, 8, "relu"), (65536, 2048, 1024, 16, "silu"),
+    (32768, 2048, 1536, 8, "silu")],
+    ids=["98304x768_relu", "65536x1024_silu", "32768x1536_silu"])
+def test_the_expert_ops_elementwise_passes_are_kernels_that_stop_at_n(
+        one_chip, mosaic, rows_, d, f, held, act):
+    """The expert op and its backward at each decoder cell's shapes (bf16):
+    the gate, its transpose (ReLU or SiLU, ``jax.vjp`` of the activation
+    inside the kernel) and the sum of the two row cotangents are the Pallas
+    kernels ``moe_gate``, ``moe_gate_bwd`` and ``moe_row_sum``
+    (``moe_rows.on_held_rows``), the nine products are the compiler's
+    grouped kernels (the forward products that ``jax.vjp`` traces beside
+    their transposes are dead and gone), and nothing else makes an array of
+    the buffer's rows: no fusion, no ``pad``, no slice, no loop."""
+    from incubator_mxnet_tpu.parallel import moe
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def both(rows, w1, w3, w2, sizes, cot):
+        ys, pull = jax.vjp(
+            lambda *o: moe.moe_experts(*o, sizes, act=act), rows, w1, w3, w2)
+        return ys, pull(cot)
+
+    text = jax.jit(both).lower(
+        shape(rows_, d), shape(held, d, f), shape(held, d, f),
+        shape(held, f, d), shape(held, dtype=jnp.int32),
+        shape(rows_, d)).compile().as_text()
+    kernels = re.findall(r'op_name="[^"]*/(moe_[a-z_]+)/pallas_call"', text)
+    assert sorted(kernels) == ["moe_gate", "moe_gate_bwd", "moe_row_sum"]
+    assert len(re.findall(r'op_name="ragged-dot-none"', text)) == 9
+    assert not re.search(
+        r"= \w+\[%d,\d+\]\S* (fusion|pad|slice|add|multiply|copy)\(" % rows_,
+        text)
+    assert not re.search(r" (while|conditional)\(", text)
+
+
 def test_stem_maxpool_has_no_pallas_form(one_chip, mosaic):
     """The stem max-pool (256,64,112,112) window 3x3 stride 2 is on jnp by
     construction: the argmax-carrying Pallas forward never compiled — W

@@ -44,6 +44,20 @@ def test_serve_phase_tiny_on_cpu():
     assert rep.ok == 12 and rep.recompiles == 0
 
 
+@pytest.mark.parametrize("act", ["relu", "silu"])
+def test_experts_phase_tiny_on_cpu(act, monkeypatch):
+    """Here the interpreter fills what the op's kernels leave unwritten with
+    NaN besides; and the phase does notice a NaN that is let through."""
+    assert chip_smoke.experts(1536, 16, 16, 4, act, platform="cpu") == 229
+    from incubator_mxnet_tpu.parallel import moe
+
+    sound = moe.moe_experts
+    monkeypatch.setattr(moe, "moe_experts", lambda rows, *a, **kw: sound(
+        rows, *a, **kw) + rows[-1, 0])
+    with pytest.raises(chip_smoke.SmokeFailure, match="reached ys"):
+        chip_smoke.experts(1536, 16, 16, 4, act, platform="cpu")
+
+
 def test_multichip_phase_tiny_on_four_virtual_devices():
     # loss_rtol: bf16 rounding noise at this size reaches 7 % (in float32
     # the two first losses agree to 2e-4, see MULTICHIP_LOSS_RTOL)

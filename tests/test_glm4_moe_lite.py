@@ -156,20 +156,50 @@ def test_tiny_model_matches_the_plain_reference_in_float32(tiny,
     assert worst < 2e-5, (leaf, worst)
 
 
-def test_tiny_model_in_bf16_stays_near_the_float32_reference(tiny,
-                                                              tiny_reference):
-    """Tolerance: 64 tokens over 8 experts, two a token.  bf16 activations
-    move a few tokens' second choice across to another expert, and one such
-    token is a thirtieth of an expert's gradient, so the leaves below a
-    router are held to a half and the loss to 2 %; the head, which is above
-    every router, to a fifth.  The published widths are held to tighter
-    limits on the chip (perfbench/configs/glm47_flash.json)."""
-    net, weights, x, y = tiny
-    loss, grads = _model_loss_and_grads(net, weights, x, y, jnp.bfloat16)
-    want, want_grads, _ = tiny_reference
-    assert abs(loss - want) <= 0.02 * want
+def test_tiny_model_in_bf16_stays_near_the_float32_reference(tiny):
+    """Under the step's own routing, as the benchmark compares
+    (``train_tokens.SetUp.reference``): 64 tokens over 8 experts, two a
+    token, and bf16 activations move a few tokens' second choice across to
+    another expert, one token of an expert's ten.  Which tokens follows the
+    rounding (the expert op's gate rounds once where XLA's fusions round at
+    every op), so the net keeps its choices and the float32 reference
+    follows each where it is a top-2 within 0.01 of its own scores.  None
+    may be refused and at most four of the 128 assignments a layer may have
+    moved; then every leaf is held to a tenth (0.053 read), the loss to
+    2 % and the head, which is above every router, to a fifth.  The
+    published widths are held on the chip
+    (perfbench/configs/glm47_flash.json)."""
+    _, weights, x, y = tiny
+    net = _tiny_net(recompute=True, keep_choices=True)
+    names = tt.short_names(net)
+    weights = dict(weights, **{
+        name: jnp.zeros(p.shape, jnp.float32) for p, name in names.items()
+        if name.endswith("_moe_chosen")})
+    trained = [p for p in names if p.grad_req != "null"]
+
+    def whole(vals):
+        # _model_terms' third term, and what the routers wrote beside it
+        fixed = [p for p in names if p.grad_req == "null"]
+        out, tc = pure_forward(
+            net, trained + fixed, [v.astype(jnp.bfloat16) for v in vals]
+            + [weights[names[p]] for p in fixed], x, training=True)
+        chosen = {tt._layer_of(names[p]): v.astype(jnp.int32)
+                  for p, v in zip(*tc.collect_aux())
+                  if names[p].endswith("_moe_chosen")}
+        loss = gluon.loss.MultiTokenCrossEntropyLoss(_LAMBDA)
+        return loss(tuple(map(NDArray, out)), NDArray(y)).mean()._data, chosen
+
+    (loss, chosen), grads = jax.jit(jax.value_and_grad(whole, has_aux=True))(
+        [weights[names[p]] for p in trained])
+    grads = dict(zip([names[p] for p in trained], grads))
+    assert sorted(chosen) == [1, 2, 3]
+    want, want_grads, facts = ref.loss_and_grads(
+        _reference_params(tiny[1]), x, y, _CFG, chosen, 0.01)
+    assert max(float(r[2]) for r in facts["refused"]) == 0
+    assert max(map(float, facts["moved"])) <= 4 / 128
+    assert abs(float(loss) - float(want)) <= 0.02 * float(want)
     worst, leaf = _worst(grads, want_grads)
-    assert worst < 0.5, (leaf, worst)
+    assert worst < 0.1, (leaf, worst)
     head, _ = _worst({"head_weight": grads["head_weight"]}, want_grads)
     assert head < 0.2, head
 
