@@ -93,10 +93,10 @@ def test_flash_attention_fwd_bwd_compiles_for_v5e(one_chip, mosaic):
     assert _kernels(compiled.as_text()) == ["flash_bwd", "flash_fwd"]
 
 
-def _lowered_grad(one_chip, heads, kv_heads, d, tokens=8192, **kw):
+def _grad(one_chip, heads, kv_heads, d, tokens=8192, **kw):
     """The gradient of causal flash attention over 8,192 tokens (or
-    ``tokens``) in bfloat16, lowered for the described chip and not yet
-    compiled."""
+    ``tokens``) in bfloat16, and its arguments' shapes on the described
+    chip."""
     q = jax.ShapeDtypeStruct((1, heads, tokens, d), jnp.bfloat16,
                              sharding=one_chip)
     k = jax.ShapeDtypeStruct((1, kv_heads, tokens, d), jnp.bfloat16,
@@ -106,7 +106,44 @@ def _lowered_grad(one_chip, heads, kv_heads, d, tokens=8192, **kw):
         return flash.flash_attention(q, k, v, causal=True,
                                      **kw).astype(jnp.float32).sum()
 
-    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k)
+    return jax.grad(loss, argnums=(0, 1, 2)), (q, k, k)
+
+
+def _lowered_grad(*args, **kw):
+    """``_grad``'s gradient lowered for the described chip and not yet
+    compiled."""
+    grad, shapes = _grad(*args, **kw)
+    return jax.jit(grad).lower(*shapes)
+
+
+def _compute_bodies(jaxpr):
+    """For each Pallas kernel of a program's jaxpr, its conditional bodies
+    that hold a matrix product, each as whether it masks (makes an iota),
+    sorted."""
+    def eqns(j):
+        for eqn in j.eqns:
+            yield eqn
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) \
+                        else (value,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from eqns(sub)
+
+    def holds(j, name):
+        return any(e.primitive.name == name for e in eqns(j))
+
+    bodies = {}
+    for call in eqns(jaxpr.jaxpr):
+        if call.primitive.name != "pallas_call":
+            continue
+        kernel = call.params["jaxpr"]
+        bodies[call.params["name"]] = sorted(
+            holds(branch.jaxpr, "iota") for cond in kernel.eqns
+            if cond.primitive.name == "cond"
+            for branch in cond.params["branches"]
+            if holds(branch.jaxpr, "dot_general"))
+    return bodies
 
 
 @pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
@@ -224,13 +261,19 @@ def test_flash_at_28_over_4_heads_and_16384_tokens_compiles_for_v5e(
     1024 x 1024, under the window of 4,096 and without one.  The one
     backward kernel holds dq, dk and dv of the sequence in VMEM (24 MiB of
     float32) and asks for what ``_fused_bwd_vmem`` counts from the shapes:
-    61 MiB of the 96 MiB budget."""
+    61 MiB of the 96 MiB budget.  Each kernel has two compute bodies, and
+    both compile: the tile the mask's edge crosses pays for the mask, the
+    tile wholly inside it (120 of 136 full, 42 of 70 under the window) makes
+    no iota."""
     from incubator_mxnet_tpu.gluon.model_zoo import text
 
     assert (text.GroupedAttention.BLOCK_Q,
             text.GroupedAttention.BLOCK_K) == (1024, 1024)
-    lowered = _lowered_grad(one_chip, 28, 4, 128, 16384, window=window,
-                            block_q=1024, block_k=1024, use_pallas=True)
+    grad, shapes = _grad(one_chip, 28, 4, 128, 16384, window=window,
+                         block_q=1024, block_k=1024, use_pallas=True)
+    assert _compute_bodies(jax.make_jaxpr(grad)(*shapes)) == {
+        "flash_fwd": [False, True], "flash_bwd": [False, True]}
+    lowered = jax.jit(grad).lower(*shapes)
     want = flash._fused_bwd_vmem(16384, 16384, 128, 1024, 1024, 7, 2)
     assert _asked(lowered) == {"flash_bwd": want}
     assert 60 * 2 ** 20 < want < 62 * 2 ** 20 < flash._vmem_budget()

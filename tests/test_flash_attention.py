@@ -1,5 +1,7 @@
 """Pallas flash-attention tests (interpret mode on CPU; same code path
 compiles on TPU)."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +11,8 @@ import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import nd
 from incubator_mxnet_tpu.parallel import flash_attention
 from incubator_mxnet_tpu.parallel.ring_attention import attention_reference
+
+fa = importlib.import_module("incubator_mxnet_tpu.parallel.flash_attention")
 
 
 def _qkv(b=2, h=2, s=64, d=16, seed=0):
@@ -157,3 +161,109 @@ def test_flash_attention_long_seq_block_heuristic(monkeypatch):
     fa.flash_attention(q, k, v, causal=True, use_pallas=True,
                        block_q=128, block_k=128)
     assert picked[-1] == (128, 128), picked
+
+
+# ---------------------------------------------------------------------------
+# the mask on the tiles its edge crosses only
+# ---------------------------------------------------------------------------
+
+def _tile_counts(s, block, window):
+    """(tiles wholly inside the mask, tiles the kernels compute) of a causal
+    self-attention over ``s`` tokens in square tiles of ``block``."""
+    n = s // block
+    inside = admitted = 0
+    for qi in range(n):
+        first, last = fa._k_range(qi, block, block, 0, True, window, n)
+        for ki in range(int(first), int(last) + 1):
+            admitted += 1
+            inside += bool(fa._inside(qi, ki, block, block, 0, window))
+    return inside, admitted
+
+
+@pytest.mark.parametrize("s,window,counts", [
+    (16384, None, (120, 136)), (16384, 4096, (42, 70)),
+    (8192, None, (28, 36)), (8192, 2048, (7, 21))],
+    ids=["smallthinker_full", "smallthinker_window", "full_8k",
+         "trinity_window"])
+def test_tiles_wholly_inside_the_mask_at_the_cells_geometry(s, window,
+                                                            counts):
+    """Tiles of 1024 x 1024: of the tiles a causal layer computes, those that
+    pay for no mask (ISSUE 40's counts)."""
+    assert _tile_counts(s, 1024, window) == counts
+
+
+@pytest.mark.parametrize("s,sk,bq,bk,window", [
+    (64, 64, 16, 16, None), (64, 64, 16, 16, 8), (64, 64, 16, 16, 16),
+    (64, 64, 16, 8, 24), (64, 64, 8, 16, 40), (64, 64, 16, 16, 80),
+    (32, 64, 16, 16, None), (32, 64, 8, 16, 20), (64, 32, 16, 16, None),
+    (64, 32, 16, 8, 12)], ids=str)
+def test_inside_is_the_brute_force_mask_over_every_tile(s, sk, bq, bk,
+                                                        window):
+    """``_inside`` against numpy's mask, tile by tile over the whole grid,
+    with the key/value sequence as long as, longer and shorter than the
+    queries' (rows with no admitted key)."""
+    rows = np.arange(s)[:, None] + (sk - s)
+    cols = np.arange(sk)[None, :]
+    keep = cols <= rows
+    if window is not None:
+        keep &= cols > rows - window
+    for qi in range(s // bq):
+        for ki in range(sk // bk):
+            want = keep[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk].all()
+            got = fa._inside(qi, ki, bq, bk, sk - s, window)
+            assert bool(got) == want, (qi, ki)
+
+
+_SPLIT_CASES = {   # s, sk, block_q, block_k, window, heads, kv_heads
+    "causal_full": (64, 64, 16, 16, None, 2, 2),
+    "window_under_tile": (64, 64, 16, 16, 8, 2, 2),
+    "window_is_tile": (64, 64, 16, 16, 16, 2, 2),
+    "window_off_tiles": (64, 64, 16, 8, 24, 2, 2),
+    "window_past_sequence": (64, 64, 16, 16, 80, 2, 2),
+    "keys_past_queries": (32, 64, 16, 16, None, 2, 2),
+    "grouped_heads": (64, 64, 16, 16, 24, 4, 2),
+}
+
+
+def _kernel_results(s, sk, bq, bk, window, heads, kv_heads):
+    """out, lse, dq, dk, dv of the kernels in bfloat16 (lse float32), as
+    bits.  Compiled without XLA:CPU's fusion emitters, which sum a row in
+    another order where a select is fused into the sum (an ulp in lse and
+    out, the interpreter's host code and not the kernels': the chip's
+    compiler is held to the bits by a run on the chip, PERF.md section 5)."""
+    rng = np.random.RandomState(40)
+
+    def draw(rows, n):
+        return jnp.asarray(rng.normal(size=(n, rows, 16)), jnp.bfloat16)
+
+    q, do = draw(s, heads), draw(s, heads)
+    k, v = draw(sk, kv_heads), draw(sk, kv_heads)
+    static = (0.25, True, window, heads, kv_heads, bq, bk, True)
+
+    def both(q, k, v, do):
+        out, lse = fa._fwd(q, k, v, *static)
+        return (out, lse, *fa._bwd(*static, (q, k, v, out, lse), do))
+
+    results = jax.jit(both).lower(q, k, v, do).compile(compiler_options={
+        "xla_cpu_use_fusion_emitters": False})(q, k, v, do)
+    return [np.asarray(x).view(np.uint16 if x.dtype == jnp.bfloat16
+                               else np.uint32) for x in results]
+
+
+@pytest.mark.parametrize("by_tiles", [False, True],
+                         ids=["flash_bwd", "bwd_by_tiles"])
+@pytest.mark.parametrize("case", sorted(_SPLIT_CASES))
+def test_unmasked_tiles_give_the_masked_kernels_bits(monkeypatch, case,
+                                                     by_tiles):
+    """The kernels as they are against the same kernels with every tile
+    taken for one the mask's edge crosses (``_inside`` always false, the
+    form before PR 40): out, lse, dq, dk and dv bit for bit, through the one
+    backward kernel and through the two of the long form."""
+    if by_tiles:
+        monkeypatch.setattr(fa, "_vmem_budget", lambda: 0)
+    split = _kernel_results(*_SPLIT_CASES[case])
+    monkeypatch.setattr(fa, "_inside", lambda *a: False)
+    edge = _kernel_results(*_SPLIT_CASES[case])
+    for name, got, want in zip(("out", "lse", "dq", "dk", "dv"), split,
+                               edge):
+        np.testing.assert_array_equal(got, want, err_msg=name)
