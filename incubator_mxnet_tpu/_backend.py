@@ -7,15 +7,19 @@ Two questions, each with one answer for every caller:
 * :func:`use_compile_cache` — where does JAX keep its persistent
   compilation cache?
 
+and one wrapper every Pallas kernel's caller takes,
+:func:`lowered_once`.
+
 Importing this module initializes no backend.
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
 
-__all__ = ["pallas_interpret", "use_compile_cache"]
+__all__ = ["pallas_interpret", "lowered_once", "use_compile_cache"]
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -33,6 +37,27 @@ def pallas_interpret() -> bool:
     raise RuntimeError(
         "Pallas kernels of this package run compiled on 'tpu' and "
         "interpreted on 'cpu'; the default jax backend is %r" % backend)
+
+
+def lowered_once(mover):
+    """``mover`` under ``jax.jit`` (the interpreter's answer is part of its
+    key).  A ``pallas_call`` is traced and lowered anew wherever it is
+    called, a tenth of a second each time, and a step runs every kernel of
+    a layer forward, recomputed and backward, in every layer, with the
+    same shapes; a jitted function is traced once a process and lowered
+    once a program, and called (the decoder cell's ``setup_s``: +6 s
+    without, PR 33)."""
+    def keyed(interpret, *args):
+        return mover(*args)
+
+    keyed.__name__ = mover.__name__
+    keyed = jax.jit(keyed, static_argnums=0)
+
+    @functools.wraps(mover)
+    def call(*args):
+        return keyed(pallas_interpret(), *args)
+
+    return call
 
 
 def use_compile_cache() -> str:

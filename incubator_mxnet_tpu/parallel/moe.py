@@ -235,26 +235,6 @@ def moe_route(x, router_weight, select_bias, top_k=1, route_norm=True,
     return w * route_scale, sel, jnp.sum(chosen, (0, 1))
 
 
-def _lowered_once(mover):
-    """``mover`` under ``jax.jit`` (the interpreter's answer is part of its
-    key).  A ``pallas_call`` is traced and lowered anew wherever it is
-    called, a tenth of a second each time, and a step runs every mover in
-    every expert layer, forward, recomputed and backward, with the same
-    shapes; a jitted function is traced once a process and lowered once a
-    program, and called (the decoder cell's ``setup_s``: +6 s without)."""
-    def keyed(interpret, *args):
-        return mover(*args)
-
-    keyed.__name__ = mover.__name__
-    keyed = jax.jit(keyed, static_argnums=0)
-
-    @functools.wraps(mover)
-    def call(*args):
-        return keyed(_backend.pallas_interpret(), *args)
-
-    return call
-
-
 def _in_row_order(row, values):
     """(T, K) values by assignment, as (R,) by row: ``row`` is a permutation,
     and a sort by it that carries the values costs a tenth of XLA's gather
@@ -263,7 +243,7 @@ def _in_row_order(row, values):
                         num_keys=1)[1]
 
 
-@_lowered_once
+@_backend.lowered_once
 def _rows_to_tokens(g, row, n):
     # each token's gradient is the sum over ITS held assignments' rows (the
     # transpose of x[token] would be a scatter-add over the rows with
@@ -320,12 +300,12 @@ def _gate(act):
     def gate(a, b):
         return act(a) * b
 
-    @_lowered_once
+    @_backend.lowered_once
     def _gate_rows(a, b, n):
         h, = moe_rows.on_held_rows(gate, 1, n, a, b, name="moe_gate")
         return h
 
-    @_lowered_once
+    @_backend.lowered_once
     def _gate_rows_bwd(a, b, g, n):
         # (g * b * act'(a), g * act(a)) in ONE pass, act' by JAX's own rule
         return moe_rows.on_held_rows(
@@ -340,7 +320,7 @@ def _gate(act):
 _GATES = {"silu": _gate(jax.nn.silu), "relu": _gate(jax.nn.relu)}
 
 
-@_lowered_once
+@_backend.lowered_once
 def _sum_rows(x, y, n):
     total, = moe_rows.on_held_rows(jnp.add, 1, n, x, y, name="moe_row_sum")
     return total
@@ -403,12 +383,12 @@ def moe_experts(rows, w1, w3, w2, sizes, act="silu"):
     return _experts(rows, w1, w3, w2, sizes, act)
 
 
-@_lowered_once
+@_backend.lowered_once
 def _weighted_rows_to_tokens(ys, weights, row, n):
     return moe_rows.tokens_from_rows(ys, row, n, weights)
 
 
-@_lowered_once
+@_backend.lowered_once
 def _weighted_tokens_to_rows(ys, weights, row, order, n, g):
     # each row below n takes its token's gradient times its weight, and in
     # the same pass its product with its own row of ys, which is the

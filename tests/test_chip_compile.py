@@ -412,3 +412,36 @@ def test_stem_maxpool_has_no_pallas_form(one_chip, mosaic):
     assert "tpu_custom_call" not in text
     assert len(re.findall(r" select-and-scatter\(", text)) == 1
     assert not re.search(r" pad\(", text)
+
+
+@pytest.mark.parametrize("shape", [
+    (1, 28, 16384, 128), (1, 4, 16384, 128), (1, 32, 8192, 128),
+    (1, 4, 8192, 128), (1, 20, 8192, 64), (1, 1, 8192, 64)],
+    ids=["28x16384x128", "4x16384x128", "32x8192x128", "4x8192x128",
+         "20x8192x64", "1x8192x64"])
+def test_rotary_is_one_kernel_each_way(one_chip, mosaic, shape):
+    """The rotary op at each decoder cell's q and k (bf16): at head size 128
+    the forward and the backward are each ONE Pallas kernel (``rotary``,
+    ``rotary_bwd``), and no float32 array of the heads or of half of them
+    is left, where XLA's form wrote the ``-x2`` half and both products out
+    as float32; at 64 (``glm47_flash_train_8k``'s rope columns and its
+    shared key) XLA's form stays, faster there alone (PERF.md section 6,
+    PR 41)."""
+    from incubator_mxnet_tpu.ops import decoder
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    heads, s, d = shape[1:]
+    for fn, name in (
+            (lambda x, g: decoder._rotary(x, 1e4), "rotary"),
+            (lambda x, g: jax.vjp(lambda x: decoder._rotary(x, 1e4), x)[1](
+                g)[0], "rotary_bwd")):
+        text = jax.jit(fn).lower(x, x).compile().as_text()
+        kernels = re.findall(r'op_name="[^"]*/(rotary\w*)/pallas_call"', text)
+        if d % 128:
+            assert "tpu_custom_call" not in text
+            continue
+        assert kernels == [name], kernels
+        assert text.count("tpu_custom_call") == 1
+        # the (S, D) tables are the only float32 arrays
+        assert not re.search(r"f32\[(\d+,)*%d,%d\]" % (s, d // 2), text)
+        assert not re.search(r"f32\[(\d+,)*%d,\d+,\d+\]" % heads, text)
