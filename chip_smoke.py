@@ -4,11 +4,14 @@
 One process, one chip, the entry points a user calls: ``vision.resnet50_v1``
 -> ``make_train_step`` -> ``aot_compile`` -> steps, then ``ServeEngine`` +
 ``ContinuousBatcher`` under open-loop traffic; between them the expert op
-of the decoder families with NaN where its rows end.  Weights and data come
+of the decoder families with NaN where its rows end, and the KDA recurrence's
+kernels against the recurrence token by token and, at the model's size,
+against its chunked ``jax.numpy`` form.  Weights and data come
 from ``--seed``.  No number printed here is a result: times are information for
 whoever looks next, the checks are what the run is for.
 
-    python chip_smoke.py              # one chip: device, train, experts, serve
+    python chip_smoke.py              # one chip: device, train, experts, kda,
+                                      # serve
     python chip_smoke.py --multichip  # four chips: dp=4 ZeRO-1 step vs one chip
 
 Contract with the driver: the last line of stdout is
@@ -297,6 +300,124 @@ def experts(rows, hidden, width, held, act, platform, seed=0):
     return n
 
 
+def _recurrence(q, k, v, g, beta):
+    """The KDA recurrence token by token (``lax.scan``), float32 at the
+    highest precision: ``S_t = (I - b k k^T) Diag(exp g) S_{t-1} + b k
+    v^T``, ``o_t = S_t^T q_t``, on the op's flat ``(1, S, H * D)`` arrays
+    and ``beta`` ``(1, S, H)``.  It shares nothing with the kernels."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    mv = functools.partial(jnp.einsum, "hkv,hk->hv",
+                           precision=jax.lax.Precision.HIGHEST)
+    s, h = beta.shape[1:]
+    q, k, v, g = (x[0].astype(jnp.float32).reshape(s, h, -1)
+                  for x in (q, k, v, g))
+
+    def token(S, t):
+        qt, kt, vt, gt, bt = t
+        S = S * jnp.exp(gt)[..., None]
+        u = bt[:, None] * (vt - mv(S, kt))
+        S = S + kt[..., None] * u[:, None, :]
+        return S, mv(S, qt)
+
+    S0 = jnp.zeros((h, q.shape[-1], v.shape[-1]), jnp.float32)
+    o = jax.lax.scan(token, S0, (q, k, v, g, beta[0]))[1]
+    return o.reshape(1, s, -1)
+
+
+def kda(seq, heads, head_dim, platform, seed=0, witness=(1536, 2)):
+    """The KDA recurrence's Pallas kernels (``parallel.delta_rule.kda``),
+    forward and backward, on sequences as the model gives them (q, k, v in
+    bf16, L2-normed q and k, g and beta in float32; log decays ``-A dt`` a
+    channel with A uniform over 1-16 and dt log-uniform over 1e-3-1e-1, so
+    that some channels forget within a token and some hold hundreds):
+
+    * against the recurrence token by token (``_recurrence``) on
+      ``witness`` = (tokens, heads) at the same head size: the output and
+      the five gradients as near as rounding leaves them.  This is the
+      check of the chunk mathematics and of the backward pass;
+    * at ``seq`` tokens of ``heads`` heads, against the same chunk
+      mathematics as plain XLA (``kda_chunked``): a check of the kernels'
+      lowering at the model's size, where the token-by-token witness would
+      take too long."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from incubator_mxnet_tpu.parallel import delta_rule
+
+    def inputs(seq, heads, key):
+        keys = jax.random.split(key, 8)
+        shape = (1, seq, heads, head_dim)
+
+        def unit(k):
+            x = jax.random.normal(k, shape, jnp.float32)
+            x = x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+            return x.reshape(1, seq, -1).astype(jnp.bfloat16)
+
+        q = (unit(keys[0]).astype(jnp.float32)
+             / np.sqrt(head_dim)).astype(jnp.bfloat16)
+        k = unit(keys[1])
+        v = jax.random.normal(keys[2], (1, seq, heads * head_dim),
+                              jnp.bfloat16)
+        a = jax.random.uniform(keys[3], (heads, 1), jnp.float32, 1.0, 16.0)
+        dt = jnp.exp(jax.random.uniform(keys[4], (heads, head_dim),
+                                        jnp.float32, np.log(1e-3),
+                                        np.log(1e-1)))
+        g = -jnp.broadcast_to((a * dt).reshape(-1), v.shape) * jnp.exp(
+            0.3 * jax.random.normal(keys[5], v.shape, jnp.float32))
+        beta = jax.nn.sigmoid(jax.random.normal(keys[6], (1, seq, heads)))
+        do = jax.random.normal(keys[7], v.shape, jnp.bfloat16)
+        return (q, k, v, g, beta), do
+
+    def both(f, do):
+        def run(q, k, v, g, beta):
+            o, pull = jax.vjp(f, q, k, v, g, beta)
+            return (o,) + pull(do.astype(o.dtype))
+        return jax.jit(run)
+
+    def compare(what, got, want):
+        names = ("o", "dq", "dk", "dv", "dg", "dbeta")
+        got = [np.asarray(a.astype(jnp.float32)) for a in got]
+        want = [np.asarray(a.astype(jnp.float32)) for a in want]
+        for name, a, b in zip(names, got, want):
+            scale = float(np.abs(b).max())
+            err = float(np.abs(a - b).max())
+            log("kda: %s largest |value| %.3g, kernels against %s %.3g"
+                % (name, scale, what, err))
+            check(np.isfinite(a).all() and a.any(),
+                  "kda: %s of the kernels is not finite, or is zero" % name)
+            # o, dq, dk and dv leave in bf16, where two nearly equal float32
+            # values may round one unit of the last place apart: 1/128 of
+            # the largest at most
+            check(err <= (1 / 128 if name in names[:4] else 1e-3) * scale,
+                  "kda: %s of the kernels is %.3g from %s (largest |value| "
+                  "%.3g)" % (name, err, what, scale))
+
+    key_w, key = jax.random.split(jax.random.PRNGKey(seed % 2 ** 31))
+    xs, do = inputs(*witness, key_w)
+    log("kda: %d tokens, %d heads of %d, against the recurrence token by "
+        "token" % (witness + (head_dim,)))
+    t = time.time()
+    got = both(delta_rule.kda, do)(*xs)
+    want = both(_recurrence, do)(*(x.astype(jnp.float32) for x in xs))
+    log("kda: kernels and the recurrence %.1fs" % (time.time() - t))
+    compare("the recurrence", got, want)
+
+    xs, do = inputs(seq, heads, key)
+    log("kda: %d tokens, %d heads of %d" % (seq, heads, head_dim))
+    t = time.time()
+    got = both(delta_rule.kda, do)(*xs)
+    _check_placed(list(got), platform, "kda: results")
+    want = both(delta_rule.kda_chunked, do)(*xs)
+    log("kda: kernels and chunked form %.1fs" % (time.time() - t))
+    compare("the chunked form", got, want)
+    return seq
+
+
 # ---------------------------------------------------------------------------
 # phase 4: serve
 # ---------------------------------------------------------------------------
@@ -487,6 +608,8 @@ def main(argv=None):
         # the shapes of smallthinker_21b_train_16k and trinity_mini_train_8k
         experts(98304, 2560, 768, 8, "relu", platform="tpu", seed=args.seed)
         experts(65536, 2048, 1024, 16, "silu", platform="tpu", seed=args.seed)
+        # the shapes of kimi_linear_train_16k's KDA layers
+        kda(16384, 32, 128, platform="tpu", seed=args.seed)
         serve(buckets=(16, 64), image_size=224, n_requests=64, qps=100.0,
               n_check=8, seed=args.seed)
     log("all phases passed")

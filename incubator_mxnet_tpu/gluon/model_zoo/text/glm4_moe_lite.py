@@ -50,7 +50,9 @@ class LatentAttention(HybridBlock):
     ``[ckv | kr] = x Wdkv``, ``Nkv(ckv) Wukv`` gives each head ``[k_nope |
     v]``; rotary embedding on every head's ``q_rope`` and on ``kr``, which
     all the heads share; ``k_h = [k_nope_h | kr]``; softmax scale
-    ``1 / sqrt(nope + rope)``."""
+    ``1 / sqrt(nope + rope)``.  With ``q_lora_rank`` None the query is one
+    projection, ``q = x Wq``; with ``rotary`` False the ``rope`` columns go
+    unrotated (a family whose latent attention has no position at all)."""
 
     #: tiles of the flash kernels at a head size of 256 (cut to the sequence
     #: where it is shorter); read on the chip, PERF.md section 6, PR 35: the
@@ -59,15 +61,20 @@ class LatentAttention(HybridBlock):
     BLOCK_Q, BLOCK_K = 1024, 1024
 
     def __init__(self, hidden, heads, q_lora_rank, kv_lora_rank, nope, rope,
-                 v_head_dim, rope_theta=10000.0, eps=1e-5, **kwargs):
+                 v_head_dim, rope_theta=10000.0, eps=1e-5, rotary=True,
+                 **kwargs):
         super().__init__(**kwargs)
         self._heads, self._latent = heads, kv_lora_rank
         self._nope, self._rope, self._vd = nope, rope, v_head_dim
-        self._theta = rope_theta
+        self._theta = rope_theta if rotary else None
+        self._direct_q = q_lora_rank is None
         with self.name_scope():
-            self.q_a = _linear(q_lora_rank, "q_a_")
-            self.q_a_norm = RMSNorm(eps, prefix="q_a_norm_")
-            self.q_b = _linear(heads * (nope + rope), "q_b_")
+            if q_lora_rank is None:
+                self.q = _linear(heads * (nope + rope), "q_")
+            else:
+                self.q_a = _linear(q_lora_rank, "q_a_")
+                self.q_a_norm = RMSNorm(eps, prefix="q_a_norm_")
+                self.q_b = _linear(heads * (nope + rope), "q_b_")
             self.kv_a = _linear(kv_lora_rank + rope, "kv_a_")
             self.kv_a_norm = RMSNorm(eps, prefix="kv_a_norm_")
             self.kv_b = _linear(heads * (nope + v_head_dim), "kv_b_")
@@ -80,16 +87,22 @@ class LatentAttention(HybridBlock):
     def hybrid_forward(self, F, x):  # noqa: N803
         b, s = x.shape[:2]
         nope, rope, heads = self._nope, self._rope, self._heads
-        q = self._heads_first(self.q_b(self.q_a_norm(self.q_a(x))),
-                              nope + rope)
-        q = F.concat(
-            F.slice_axis(q, axis=-1, begin=0, end=nope),
-            F.contrib.rotary(F.slice_axis(q, axis=-1, begin=nope, end=None),
-                             theta=self._theta), dim=-1)
+        if self._direct_q:
+            q = self._heads_first(self.q(x), nope + rope)
+        else:
+            q = self._heads_first(self.q_b(self.q_a_norm(self.q_a(x))),
+                                  nope + rope)
+        if self._theta is not None:
+            q = F.concat(
+                F.slice_axis(q, axis=-1, begin=0, end=nope),
+                F.contrib.rotary(F.slice_axis(q, axis=-1, begin=nope,
+                                              end=None),
+                                 theta=self._theta), dim=-1)
         kv = self.kv_a(x)
-        kr = F.contrib.rotary(
-            F.slice_axis(kv, axis=-1, begin=self._latent, end=None)
-            .reshape((b, 1, s, rope)), theta=self._theta)
+        kr = F.slice_axis(kv, axis=-1, begin=self._latent, end=None) \
+            .reshape((b, 1, s, rope))
+        if self._theta is not None:
+            kr = F.contrib.rotary(kr, theta=self._theta)
         kv = self._heads_first(self.kv_b(self.kv_a_norm(
             F.slice_axis(kv, axis=-1, begin=0, end=self._latent))),
             nope + self._vd)
@@ -251,10 +264,6 @@ def _build(config, num_layers=None, experts_held=None, vocab_rows=None,
         raise ValueError("routing limited to groups of experts is not built")
     if config["num_key_value_heads"] != config["num_attention_heads"]:
         raise ValueError("latent attention has a key and a value a head")
-    if config["v_head_dim"] != config["qk_nope_head_dim"] \
-            + config["qk_rope_head_dim"]:
-        raise ValueError("the flash kernels take one head size for query, "
-                         "key and value")
     if config["num_nextn_predict_layers"] not in (0, 1):
         raise ValueError("one prediction module or none")
     return Glm4MoeLiteDecoder(

@@ -132,6 +132,8 @@ class Parameter:
     def _init_grad(self):
         if self._grad_req == "null":
             self._grad = None
+            # the data array holds the buffer autograd attached to it
+            autograd.mark_variables([self._data], [None], "null")
             return
         self._grad = _nd_mod.zeros(self.shape, dtype=np_dtype(self.dtype))
         autograd.mark_variables([self._data], [self._grad], [self._grad_req])

@@ -7,6 +7,7 @@ from .mesh import (Mesh, NamedSharding, P, PartitionSpec, global_devices,
                    make_mesh, replicated, shard_along, spans_processes)
 from .train_step import DynamicLossScale, FunctionalOptimizer, TrainStep, make_train_step
 from .flash_attention import flash_attention
+from .delta_rule import kda
 from .pipeline import pipeline_apply, spmd_pipeline, stack_stage_params
 from .moe import load_balancing_loss, moe_ffn, moe_ffn_sharded
 from .checkpoint import (CheckpointError, CheckpointCorruptError,
@@ -23,7 +24,7 @@ from . import distributed
 __all__ = ["Mesh", "NamedSharding", "P", "PartitionSpec", "make_mesh",
            "replicated", "shard_along", "global_devices", "spans_processes",
            "DynamicLossScale", "FunctionalOptimizer", "TrainStep",
-           "make_train_step", "flash_attention", "pipeline_apply",
+           "make_train_step", "flash_attention", "kda", "pipeline_apply",
            "spmd_pipeline", "stack_stage_params", "load_balancing_loss",
            "moe_ffn", "moe_ffn_sharded", "CheckpointError",
            "CheckpointCorruptError", "CheckpointTopologyError",

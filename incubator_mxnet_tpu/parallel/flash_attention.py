@@ -259,6 +259,7 @@ def _geometry(q, k, block_q, block_k, heads, kv_heads, causal, window):
 def _fwd(q, k, v, scale, causal, window, heads, kv_heads, block_q, block_k,
          interpret):
     bh, s, d = q.shape
+    dv = v.shape[-1]
     g = _geometry(q, k, block_q, block_k, heads, kv_heads, causal, window)
     bq, bk = g["bq"], g["bk"]
     kernel = functools.partial(
@@ -270,20 +271,20 @@ def _fwd(q, k, v, scale, causal, window, heads, kv_heads, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, bq, d), g["q_map"]),
             pl.BlockSpec((1, bk, d), g["k_map"]),
-            pl.BlockSpec((1, bk, d), g["k_map"]),
+            pl.BlockSpec((1, bk, dv), g["k_map"]),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), g["q_map"]),
+            pl.BlockSpec((1, bq, dv), g["q_map"]),
             pl.BlockSpec((1, bq, 1), g["q_map"]),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -319,20 +320,24 @@ def _vmem_budget():
     return _VMEM_BYTES * 3 // 4
 
 
-def _fused_bwd_vmem(s, sk, d, bq, bk, group, itemsize):
+def _fused_bwd_vmem(s, sk, d, bq, bk, group, itemsize, dv):
     """Bytes of VMEM ``flash_bwd`` asks for: the float32 sums (dq of the
     sequence; dk and dv of a key tile, or of the sequence where a group of
     query heads shares them), every block twice for the pipeline (lse and
     delta are (bq, 1) float32, which the tiled layout pads to 128 lanes; dq
-    leaves as one block of the sequence), and six float32 (bq, bk) tiles:
+    leaves as one block of the sequence; v, do and dv are ``dv`` wide, the
+    rest ``d``), and six float32 (bq, bk) tiles:
     scores, probabilities, dp, ds, and two for the mask and the copies in
     the compute dtype (the v5e compiler reuses them down to under three at
     8,192 x 128 and 8,192 x 256: it takes 28 MiB at either; the rest is
     the margin).  A head narrower than 128 fills whole lanes all the same,
     and no kernel asks for less than it would get unasked."""
     d = _cdiv(d, 128) * 128
-    sums = 4 * d * (s + 2 * (bk if group == 1 else sk))
-    blocks = 2 * (itemsize * d * (2 * bq + 4 * bk + s) + 2 * 4 * 128 * bq)
+    dv = _cdiv(dv, 128) * 128
+    rows = bk if group == 1 else sk
+    sums = 4 * (d * (s + rows) + dv * rows)
+    blocks = 2 * (itemsize * (d * (bq + 2 * bk + s) + dv * (bq + 2 * bk))
+                  + 2 * 4 * 128 * bq)
     return max(_VMEM_UNASKED, sums + blocks + 6 * 4 * bq * bk)
 
 
@@ -462,6 +467,7 @@ def _bwd(scale, causal, window, heads, kv_heads, block_q, block_k, interpret,
     q, k, v, out, lse = res
     bh, s, d = q.shape
     bkv, sk, _ = k.shape
+    dv = v.shape[-1]
     g = _geometry(q, k, block_q, block_k, heads, kv_heads, causal, window)
     bq, bk, nq, nk = g["bq"], g["bk"], g["nq"], g["nk"]
     group, q_steps = g["group"], g["q_steps"]
@@ -470,7 +476,7 @@ def _bwd(scale, causal, window, heads, kv_heads, block_q, block_k, interpret,
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)             # (bh, s, 1)
     operands = (q, k, v, do, lse[:, :, None], delta)    # lse as (bh, s, 1)
-    vmem = _fused_bwd_vmem(s, sk, d, bq, bk, group, q.dtype.itemsize)
+    vmem = _fused_bwd_vmem(s, sk, d, bq, bk, group, q.dtype.itemsize, dv)
     if vmem > _vmem_budget():
         return _bwd_by_tiles(operands, g, tile, interpret)
 
@@ -494,8 +500,8 @@ def _bwd(scale, causal, window, heads, kv_heads, block_q, block_k, interpret,
         in_specs=[
             pl.BlockSpec((1, bq, d), q_map),
             pl.BlockSpec((1, bk, d), k_map),
-            pl.BlockSpec((1, bk, d), k_map),
-            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bk, dv), k_map),
+            pl.BlockSpec((1, bq, dv), q_map),
             pl.BlockSpec((1, bq, 1), q_map),
             pl.BlockSpec((1, bq, 1), q_map),
         ],
@@ -503,17 +509,17 @@ def _bwd(scale, causal, window, heads, kv_heads, block_q, block_k, interpret,
             pl.BlockSpec((1, s, d), lambda b, h, j, t: (b * group + h, _I0,
                                                         _I0)),
             pl.BlockSpec((1, bk, d), dk_map),
-            pl.BlockSpec((1, bk, d), dk_map),
+            pl.BlockSpec((1, bk, dv), dk_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype),
             jax.ShapeDtypeStruct((bkv, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bkv, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((bkv, sk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((s, d), jnp.float32),
             pltpu.VMEM((k_rows, d), jnp.float32),
-            pltpu.VMEM((k_rows, d), jnp.float32),
+            pltpu.VMEM((k_rows, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         interpret=interpret,
@@ -526,24 +532,25 @@ def _bwd_by_tiles(operands, g, tile, interpret):
     key tiles, dk and dv from one that walks a key tile's query tiles.
     Each makes the tile's probabilities for itself (seven products a tile),
     and neither holds more than tiles."""
-    q, k = operands[:2]
+    q, k, v = operands[:3]
     bh, s, d = q.shape
     bkv, sk, _ = k.shape
+    dv = v.shape[-1]
     bq, bk, nq, nk = g["bq"], g["bk"], g["nq"], g["nk"]
     causal, window = tile["causal"], tile["window"]
     # what flash_bwd would ask for a sequence of one tile: at tiles of
     # 1024 x 1024 more than a kernel gets unasked (31 MiB at head size 128,
     # 36 at 256)
     params = pltpu.CompilerParams(vmem_limit_bytes=_fused_bwd_vmem(
-        bq, bk, d, bq, bk, 1, q.dtype.itemsize))
+        bq, bk, d, bq, bk, 1, q.dtype.itemsize, dv))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, nk=nk, steps=g["k_steps"], **tile),
         grid=(bh, nq, g["k_steps"]),
         in_specs=[
             pl.BlockSpec((1, bq, d), g["q_map"]),
             pl.BlockSpec((1, bk, d), g["k_map"]),
-            pl.BlockSpec((1, bk, d), g["k_map"]),
-            pl.BlockSpec((1, bq, d), g["q_map"]),
+            pl.BlockSpec((1, bk, dv), g["k_map"]),
+            pl.BlockSpec((1, bq, dv), g["q_map"]),
             pl.BlockSpec((1, bq, 1), g["q_map"]),
             pl.BlockSpec((1, bq, 1), g["q_map"]),
         ],
@@ -572,22 +579,22 @@ def _bwd_by_tiles(operands, g, tile, interpret):
         in_specs=[
             pl.BlockSpec((1, bq, d), q_map),
             pl.BlockSpec((1, bk, d), k_map),
-            pl.BlockSpec((1, bk, d), k_map),
-            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bk, dv), k_map),
+            pl.BlockSpec((1, bq, dv), q_map),
             pl.BlockSpec((1, bq, 1), q_map),
             pl.BlockSpec((1, bq, 1), q_map),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), k_map),
-            pl.BlockSpec((1, bk, d), k_map),
+            pl.BlockSpec((1, bk, dv), k_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bkv, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bkv, sk, d), k.dtype),
+            jax.ShapeDtypeStruct((bkv, sk, dv), k.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         compiler_params=params,
         interpret=interpret,
@@ -633,8 +640,9 @@ def _make_attn(scale, causal, block_q, block_k, interpret, window=None,
 def flash_attention(q, k, v, causal=False, scale: Optional[float] = None,
                     block_q=None, block_k=None, interpret=None,
                     use_pallas=None, window: Optional[int] = None):
-    """Flash attention: q is (B, H, S, D), k and v (B, Hkv, Sk, D) with Hkv
-    dividing H (each key/value head serves H / Hkv consecutive query heads).
+    """Flash attention: q and k are (B, H, S, D) and (B, Hkv, Sk, D), v is
+    (B, Hkv, Sk, Dv), with Hkv dividing H (each key/value head serves H / Hkv
+    consecutive query heads); the result is (B, H, S, Dv).
 
     Returns softmax(QKᵀ·scale [+ mask]) V without materializing the score
     matrix.  Differentiable.  ``causal`` masks keys j > i + (Sk - S);
@@ -656,7 +664,7 @@ def flash_attention(q, k, v, causal=False, scale: Optional[float] = None,
     """
     b, h, s, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
-    if h % hkv or k.shape != v.shape:
+    if h % hkv or k.shape[:-1] != v.shape[:-1] or k.shape[-1] != d:
         raise ValueError("flash_attention: %d query heads over key/value of "
                          "shapes %s, %s" % (h, k.shape, v.shape))
     if window is not None and (not causal or window < 1):
@@ -688,7 +696,7 @@ def flash_attention(q, k, v, causal=False, scale: Optional[float] = None,
         block_k = bk_d if block_k is None else block_k
     qf = q.reshape(b * h, s, d)
     kf = k.reshape(b * hkv, sk, d)
-    vf = v.reshape(b * hkv, sk, d)
+    vf = v.reshape(b * hkv, sk, v.shape[-1])
     extra = {}
     if window is not None:
         extra["window"] = int(window)
@@ -696,7 +704,7 @@ def flash_attention(q, k, v, causal=False, scale: Optional[float] = None,
         extra.update(heads=h, kv_heads=hkv)
     _attn = _make_attn(float(scale), bool(causal), int(block_q),
                        int(block_k), bool(interpret), **extra)
-    return _attn(qf, kf, vf).reshape(b, h, s, d)
+    return _attn(qf, kf, vf).reshape(b, h, s, v.shape[-1])
 
 
 # op-registry surface: mx.nd.contrib.flash_attention / mx.sym.contrib...

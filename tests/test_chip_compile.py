@@ -209,7 +209,7 @@ def test_flash_backward_asks_for_its_vmem_from_the_shapes(one_chip, mosaic,
     lowered = _lowered_grad(one_chip, heads, kv_heads, d, block_q=1024,
                             block_k=1024, use_pallas=True)
     want = flash._fused_bwd_vmem(8192, 8192, d, 1024, 1024,
-                                 heads // kv_heads, 2)
+                                 heads // kv_heads, 2, d)
     assert _asked(lowered) == {"flash_bwd": want}
     # 45 and 50 MiB, where the compiler takes 28 and under 40
     assert 40 * 2 ** 20 < want < flash._vmem_budget() * 5 // 8
@@ -274,7 +274,7 @@ def test_flash_at_28_over_4_heads_and_16384_tokens_compiles_for_v5e(
     assert _compute_bodies(jax.make_jaxpr(grad)(*shapes)) == {
         "flash_fwd": [False, True], "flash_bwd": [False, True]}
     lowered = jax.jit(grad).lower(*shapes)
-    want = flash._fused_bwd_vmem(16384, 16384, 128, 1024, 1024, 7, 2)
+    want = flash._fused_bwd_vmem(16384, 16384, 128, 1024, 1024, 7, 2, 128)
     assert _asked(lowered) == {"flash_bwd": want}
     assert 60 * 2 ** 20 < want < 62 * 2 ** 20 < flash._vmem_budget()
     compiled_text = lowered.compile().as_text()
@@ -445,3 +445,67 @@ def test_rotary_is_one_kernel_each_way(one_chip, mosaic, shape):
         # the (S, D) tables are the only float32 arrays
         assert not re.search(r"f32\[(\d+,)*%d,%d\]" % (s, d // 2), text)
         assert not re.search(r"f32\[(\d+,)*%d,\d+,\d+\]" % heads, text)
+
+
+def test_flash_at_192_over_128_heads_and_16384_tokens_compiles_for_v5e(
+        one_chip, mosaic):
+    """The latent attention of ``kimi_linear_train_16k``: 32 heads of 128 +
+    64 query/key columns (no rotary) and 128 value columns, 16,384 tokens,
+    tiles of 1024 x 1024 (``LatentAttention.BLOCK_Q/K``).  The kernels take
+    the value width as its own: v, the output, do and dv are 128 wide, q,
+    k, dq and dk 192; the one backward kernel asks for what
+    ``_fused_bwd_vmem`` counts with the two widths."""
+    from incubator_mxnet_tpu.gluon.model_zoo import text
+
+    assert (text.LatentAttention.BLOCK_Q,
+            text.LatentAttention.BLOCK_K) == (1024, 1024)
+    q = jax.ShapeDtypeStruct((1, 32, 16384, 192), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 32, 16384, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash.flash_attention(
+            q, k, v, causal=True, scale=192 ** -0.5, block_q=1024,
+            block_k=1024, use_pallas=True).astype(jnp.float32).sum()
+
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    shapes = jax.eval_shape(grad, q, q, v)
+    assert [s.shape[-1] for s in shapes] == [192, 192, 128]
+    lowered = jax.jit(grad).lower(q, q, v)
+    want = flash._fused_bwd_vmem(16384, 16384, 192, 1024, 1024, 1, 2, 128)
+    assert _asked(lowered) == {"flash_bwd": want}
+    assert want < flash._vmem_budget()
+    compiled_text = lowered.compile().as_text()
+    assert _kernels(compiled_text) == ["flash_bwd", "flash_fwd"]
+
+
+def test_kda_kernels_compile_for_v5e_at_the_cells_shapes(one_chip, mosaic):
+    """The KDA recurrence of ``kimi_linear_train_16k``: 16,384 tokens of 32
+    heads of 128 (q, k, v in bf16, g and beta in float32), forward and
+    backward: ONE kernel each way, whose chunk mathematics (the sub-chunk
+    triangles, the doubling inverse, the float32 products at the highest
+    precision) Mosaic takes; the state each chunk starts from is the one
+    float32 array of (heads, chunks, 128, 128) the forward pass leaves."""
+    from incubator_mxnet_tpu.parallel import delta_rule
+
+    x = jax.ShapeDtypeStruct((1, 16384, 4096), jnp.bfloat16,
+                             sharding=one_chip)
+    g = jax.ShapeDtypeStruct((1, 16384, 4096), jnp.float32,
+                             sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((1, 16384, 32), jnp.float32,
+                                sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        return delta_rule.kda(q, k, v, g, beta).astype(jnp.float32).sum()
+
+    def kernels(text):
+        return text.count("tpu_custom_call"), sorted(set(re.findall(
+            r'op_name="[^"]*/(kda_\w+)/pallas_call"', text)))
+
+    fwd = jax.jit(delta_rule.kda).lower(x, x, x, g, beta).compile()
+    assert kernels(fwd.as_text()) == (1, ["kda_fwd"])
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, x, x, g, beta).compile().as_text()
+    assert kernels(grad) == (2, ["kda_bwd", "kda_fwd"])
+    assert re.search(r"f32\[1,32,256,128,128\]", grad)

@@ -171,3 +171,41 @@ def test_importing_the_package_initializes_no_backend():
                          env=dict(os.environ, JAX_PLATFORMS="cpu"),
                          capture_output=True, text=True, timeout=120)
     assert run.returncode == 0, run.stderr[-2000:]
+
+
+def test_kda_phase_tiny_on_cpu():
+    """The phase's kernels (interpreted) against the recurrence token by
+    token at two chunks and a part of a third, and against the chunked form
+    at two chunks, two heads of 32."""
+    assert chip_smoke.kda(128, 2, 32, platform="cpu", witness=(150, 2)) == 128
+
+
+def test_kda_phase_witness_catches_what_both_chunked_forms_share(
+        monkeypatch):
+    """A fault in the chunk mathematics that the kernels and the chunked
+    form share (here the backward's beta gradient 10 % high) passes the
+    comparison of the two and is caught by the token-by-token witness."""
+    import jax
+
+    from incubator_mxnet_tpu.parallel import delta_rule
+
+    was = delta_rule._chunk_bwd
+
+    def off(*args):
+        *grads, dbeta, dz = was(*args)
+        return (*grads, dbeta * 1.1, dz)
+
+    def fresh():
+        # the traced kernels and chunked form are cached
+        jax.clear_caches()
+
+    monkeypatch.setattr(delta_rule, "_chunk_bwd", off)
+    fresh()
+    try:
+        with pytest.raises(chip_smoke.SmokeFailure,
+                           match="dbeta of the kernels .* from the "
+                           "recurrence"):
+            chip_smoke.kda(128, 2, 32, platform="cpu", witness=(150, 2))
+    finally:
+        monkeypatch.undo()
+        fresh()

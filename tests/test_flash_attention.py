@@ -267,3 +267,47 @@ def test_unmasked_tiles_give_the_masked_kernels_bits(monkeypatch, case,
     for name, got, want in zip(("out", "lse", "dq", "dk", "dv"), split,
                                edge):
         np.testing.assert_array_equal(got, want, err_msg=name)
+
+
+def _dense_causal(q, k, v, scale):
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    keep = jnp.tril(jnp.ones(s.shape[-2:], bool))
+    return jnp.einsum("bhqk,bhkd->bhqd",
+                      jax.nn.softmax(jnp.where(keep, s, -jnp.inf), -1), v)
+
+
+@pytest.mark.parametrize("by_tiles", [False, True],
+                         ids=["fused_bwd", "two_kernel_bwd"])
+def test_value_heads_narrower_than_query_key_heads(monkeypatch, by_tiles):
+    """Latent attention without rotary has query/key heads of 192 and value
+    heads of 128: the kernels take a value width of its own, forward and
+    both backward forms, against plain causal attention."""
+    rng = np.random.RandomState(7)
+    q, k = (jnp.asarray(rng.uniform(-1, 1, (1, 2, 64, 192)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.uniform(-1, 1, (1, 2, 64, 128)), jnp.float32)
+    if by_tiles:
+        monkeypatch.setattr(fa, "_vmem_budget", lambda: 0)
+    fa._make_attn.cache_clear()
+    scale = 1.0 / np.sqrt(192)
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(f(q, k, v) ** 2)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=scale, block_q=16,
+                               block_k=32, use_pallas=True)
+
+    def dense(q, k, v):
+        return _dense_causal(q, k, v, scale)
+
+    out = flash(q, k, v)
+    assert out.shape == (1, 2, 64, 128)
+    np.testing.assert_allclose(out, dense(q, k, v), rtol=1e-5, atol=1e-5)
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense), (0, 1, 2))(q, k, v)
+    for a, b, n in zip(got, want, "qkv"):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5,
+                                   err_msg="d%s mismatch" % n)
+    fa._make_attn.cache_clear()

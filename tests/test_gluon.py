@@ -16,6 +16,25 @@ def test_parameter():
     np.testing.assert_allclose(p.data().asnumpy(), np.ones((2, 3)))
 
 
+def test_grad_req_null_lets_the_gradient_buffer_go():
+    """Setting ``grad_req`` to ``"null"`` drops the gradient buffer the
+    parameter's data carries for autograd, so that a fused train step that
+    keeps its own gradients does not also hold one zero array a parameter;
+    back to ``"write"`` gives it a buffer again."""
+    import gc
+    import weakref
+
+    p = gluon.Parameter("weight", shape=(2, 3))
+    p.initialize(init=mx.init.Xavier())
+    buffer = weakref.ref(p.grad())
+    p.grad_req = "null"
+    gc.collect()
+    assert buffer() is None and p.data().grad is None
+    assert not autograd.requires_grad(p.data())
+    p.grad_req = "write"
+    assert p.grad().shape == (2, 3) and p.data().grad is p.grad()
+
+
 def test_dense_forward():
     layer = nn.Dense(4, in_units=3)
     layer.initialize()

@@ -236,18 +236,20 @@ def test_what_the_backward_kernel_asks_of_vmem_follows_the_shapes():
     assert _vmem_budget() == 96 * mib == _VMEM_BYTES * 3 // 4
     # 8,192 x (32 over 4 heads of 128), tiles 1024 x 1024: three sums of
     # 4 MiB, blocks 2 x 4.5 MiB, tiles 24 MiB
-    assert _fused_bwd_vmem(8192, 8192, 128, 1024, 1024, 8, 2) == 45 * mib
+    assert _fused_bwd_vmem(8192, 8192, 128, 1024, 1024, 8, 2, 128) == 45 * mib
     # 8,192 x 20 heads of 256, tiles 1024 x 1024: 8 + 2 x 1, 2 x 8, 24 MiB
-    assert _fused_bwd_vmem(8192, 8192, 256, 1024, 1024, 1, 2) == 50 * mib
+    assert _fused_bwd_vmem(8192, 8192, 256, 1024, 1024, 1, 2, 256) == 50 * mib
     longest = {(d, group): max(
         s for s in (2 ** n for n in range(10, 22))
-        if _fused_bwd_vmem(s, s, d, 1024, 1024, group, 2) <= _vmem_budget())
+        if _fused_bwd_vmem(s, s, d, 1024, 1024, group, 2, d)
+        <= _vmem_budget())
         for d, group in ((128, 1), (128, 8), (256, 1))}
     assert longest == {(128, 1): 65536, (128, 8): 32768,
                        (256, 1): 16384}, longest
     # the first that falls back at head size 256, and its tile kernels
-    assert _fused_bwd_vmem(32768, 32768, 256, 1024, 1024, 1, 2) == 98 * mib
-    assert _fused_bwd_vmem(1024, 1024, 256, 1024, 1024, 1, 2) == 36 * mib
+    assert _fused_bwd_vmem(32768, 32768, 256, 1024, 1024, 1, 2,
+                           256) == 98 * mib
+    assert _fused_bwd_vmem(1024, 1024, 256, 1024, 1024, 1, 2, 256) == 36 * mib
 
 
 def test_flash_grouped_heads_without_a_mask_and_with_longer_keys():

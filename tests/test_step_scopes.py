@@ -35,14 +35,27 @@ def _batch(rows=8):
 
 def _compiled(mesh=None, zero=0):
     """``(net, lowered text, op names of the compiled text)`` of the
-    benchmark's kind of step over the tiny net."""
+    benchmark's kind of step over the tiny net.  Compiled with JAX's
+    persistent cache off: scope names are metadata, which the cache's key
+    leaves out, so an executable that an earlier test of the same worker
+    built from a net whose blocks were numbered otherwise (a benchmark
+    runner turns the cache on) would come back under ITS names."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
     net = _net()
     x, y = _batch()
     net(x)
     step = make_train_step(net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
                            momentum=0.9, loss_scale="dynamic",
                            compute_dtype="bfloat16", mesh=mesh, zero=zero)
-    step.aot_compile(x, y)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        step.aot_compile(x, y)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
     args = ([p._data._data for p in step._gp],
             [p._data._data for p in step._aux], step.opt_state,
             *(step._place_batch(x._data, y._data) if mesh is not None

@@ -350,6 +350,7 @@ def _hints():
         "_while_loop": None,
     }
     h.update(_decoder_hints())
+    h.update(_kda_hints())
     return h
 
 
@@ -381,6 +382,32 @@ def _decoder_hints():
              order.astype(np.int32)], {}),
         "_contrib_rms_norm": ([fn(3, 8), fn(8) + 2.0], {"eps": 1e-5}),
         "_contrib_rotary": ([fn(2, 6, 8)], {"theta": 100.0}),
+    }
+
+
+def _kda_hints():
+    """Kimi Delta Attention's recurrence and the ops around it
+    (parallel/delta_rule.py), from a stream of their own: 2 heads of 4
+    over 10 tokens (a chunk padded), decays and write strengths in their
+    ranges."""
+    rng = np.random.RandomState(43)
+
+    def fn(*shape):
+        return rng.normal(0.0, 1.0, shape).astype(np.float32)
+
+    def uniform(lo, hi, *shape):
+        return rng.uniform(lo, hi, shape).astype(np.float32)
+
+    return {
+        "_contrib_kda": ([fn(1, 10, 8) * 0.4, fn(1, 10, 8) * 0.4,
+                          fn(1, 10, 8), -uniform(0.05, 1.0, 1, 10, 8),
+                          uniform(0.1, 0.9, 1, 10, 2)], {}),
+        "_contrib_kda_conv": ([fn(2, 6, 4), fn(4, 4) * 0.5], {}),
+        "_contrib_kda_qk_norm": ([fn(2, 6, 8)], {"heads": 2, "scale": 0.5}),
+        "_contrib_kda_gate": ([fn(2, 6, 8), fn(2, 6, 2), fn(1, 1, 2, 1),
+                               fn(8) * 0.5], {}),
+        "_contrib_kda_out_norm": ([fn(2, 6, 8), fn(2, 6, 8), fn(4) + 2.0],
+                                  {"eps": 1e-5}),
     }
 
 
