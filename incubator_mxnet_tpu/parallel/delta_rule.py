@@ -18,8 +18,8 @@ starts from, the rule unrolls to (the WY/UT form)::
     O = (e^G Q) S0 + A U,   A_ti = sum_c q_tc k_ic e^{G_tc - G_ic}  (i <= t)
     S1 = Diag(e^{G_C}) S0 + (e^{G_C - G} K)^T U
 
-``T`` is a unit lower-triangular inverse, made by forward substitution in
-blocks of ``_SUB`` rows (``_inverse``), 21 products of C x C.  No factor
+``T`` is a unit lower-triangular inverse, made by block doubling
+(``_inverse``): 10 products of C x C in a chain 10 deep.  No factor
 that is computed can overflow: ``B`` and ``A`` (whose factor
 ``e^{G_t - G_i}`` would be ``e^{G_t} e^{-G_i}`` in a plain product, and
 ``e^{-G_i}`` grows without bound where the decay is strong) are made in
@@ -168,29 +168,33 @@ def _pairs_bwd(G, b, lefts):
 
 
 def _inverse(a):
-    """``(I + a)^{-1}`` of a strictly lower-triangular ``(C, C)`` ``a`` by
-    forward substitution in blocks of ``_SUB`` rows: first the inverse of
-    the diagonal blocks, a row of every block a step, then each block of
-    rows from the rows above it.  Every product holds rows of an inverse,
-    which are as bounded as the recurrence.  (A sum of the powers of ``-a``
-    by doubling holds those powers, whose entries grow as binomials of C:
-    where the keys of a chunk are alike and the decay slow they overflow
-    float32.)"""
+    """``(I + a)^{-1}`` of a strictly lower-triangular ``(C, C)`` ``a``, C a
+    power of two, by block doubling.  With ``T_s`` the inverse of ``I + a``
+    kept to its diagonal blocks of ``s`` rows, and ``O_s`` the entries of
+    ``a`` in the lower-left ``s x s`` quadrant of each diagonal block of
+    ``2s`` rows::
+
+        T_2 = I - O_1,    T_2s = T_s - T_s (O_s T_s)
+
+    (``[[M11, 0], [M21, M22]]^{-1} = [[T11, 0], [-T22 M21 T11, T22]]``, for
+    every block at once): ``2 log2(C) - 2`` products, 10 at C = 64, in a
+    chain as deep.  Every product holds blocks of an inverse, which are as
+    bounded as the recurrence.  (A sum of the powers of ``-a`` by doubling
+    holds those powers, whose entries grow as binomials of C: where the
+    keys of a chunk are alike and the decay slow they overflow float32.)"""
     c = a.shape[0]
+    if c & (c - 1):
+        raise ValueError("kda: a chunk of %d rows, not a power of two" % c)
     row, col = _iota((c, c), 0), _iota((c, c), 1)
-    eye = jnp.where(row == col, _F32(1), _F32(0))
     # int32 shifts and masks: the kernels' compiler takes no integer
-    # division
-    log2 = _np.int32(_SUB.bit_length() - 1)
-    block, within = row >> log2, row & _np.int32(_SUB - 1)
-    inner = jnp.where(block == col >> log2, a, _F32(0))
-    d = eye
-    for i in range(1, _SUB):
-        d = jnp.where(within == i, eye - _mm(inner, d, 1, 0), d)
-    outer, t = a - inner, d
-    for r in range(1, c // _SUB):
-        x = jnp.where(block == r, eye - _mm(outer, t, 1, 0), _F32(0))
-        t = jnp.where(block == r, _mm(d, x, 1, 0), t)
+    # division.  Row and column in one block of 2s rows and in different
+    # blocks of s: their indices shifted by log2(s) differ in the last bit
+    def quadrant(lv):
+        return jnp.where(((row >> lv) ^ (col >> lv)) == 1, a, _F32(0))
+
+    t = jnp.where(row == col, _F32(1), _F32(0)) - quadrant(_np.int32(0))
+    for lv in range(1, c.bit_length() - 1):
+        t = t - _mm(t, _mm(quadrant(_np.int32(lv)), t, 1, 0), 1, 0)
     return t
 
 

@@ -123,3 +123,48 @@ def test_the_op_keeps_the_dtype_and_matches_the_kernels_in_bf16():
     want = dr.kda(*half, g, beta)
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(want, np.float32))
+
+
+#: the cases above, and decays slower still under keys alike: the chunk's
+#: system as far from I as a chunk makes it
+_SYSTEMS = dict(_CASES, slowest_decay=((-1e-4, 0.0), (0.99, 1.0)))
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("case", sorted(_SYSTEMS))
+def test_the_chunk_inverse_is_the_inverse_in_float64(case, seed):
+    """``T = (I + diag(beta) B)^{-1}`` of one chunk of 64 tokens and keys of
+    128, ``beta * B`` made as ``_chunk_parts`` makes it, against
+    ``numpy.linalg.inv`` in float64."""
+    (g_lo, g_hi), (b_lo, b_hi) = _SYSTEMS[case]
+    c, d = dr.CHUNK, 128
+    r = np.random.RandomState(seed)
+    k = r.randn(c, d)
+    if case in ("alike_keys", "slowest_decay"):
+        k = r.randn(1, d) + 0.2 * k
+    k = jnp.asarray(k / np.linalg.norm(k, axis=-1, keepdims=True),
+                    jnp.float32)
+    g = jnp.asarray(r.uniform(g_lo, g_hi, (c, d)), jnp.float32)
+    beta = jnp.asarray(r.uniform(b_lo, b_hi, (c, 1)), jnp.float32)
+
+    @jax.jit
+    def system(k, g, beta):
+        G = dr._mm(dr._lower(c, False), g, 1, 0)
+        return beta * dr._pairs(G, k, [k])[0] * dr._lower(c, True)
+
+    a = system(k, g, beta)
+    got = np.asarray(jax.jit(dr._inverse)(a), np.float64)
+    want = np.linalg.inv(np.eye(c) + np.asarray(a, np.float64))
+    scale = np.abs(want).max()
+    assert np.abs(got - want).max() <= 1e-6 * scale
+
+
+def test_the_chunk_inverse_is_ten_products():
+    """Block doubling at C = 64: at most 10 products, so a chain of at most
+    10; a chunk that is not a power of two is refused."""
+    jaxpr = jax.make_jaxpr(dr._inverse)(
+        jnp.zeros((dr.CHUNK,) * 2, jnp.float32))
+    names = [e.primitive.name for e in jaxpr.jaxpr.eqns]
+    assert names.count("dot_general") <= 10
+    with pytest.raises(ValueError, match="power of two"):
+        dr._inverse(jnp.zeros((48, 48), jnp.float32))
